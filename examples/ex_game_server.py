@@ -38,7 +38,9 @@ def main() -> int:
     from ggrs_tpu.net import InMemoryNetwork
     from ggrs_tpu.parallel import BatchedRequestExecutor
     from ggrs_tpu.sessions import SessionBuilder
+    from ggrs_tpu.utils.device import place_compile_cache
 
+    place_compile_cache()
     game = BoxGame(2)
     n_sessions = 2 * args.matches
 

@@ -85,7 +85,8 @@ class Config:
     input_encode   — input -> bytes, the only game data that crosses the wire.
     input_decode   — bytes -> input; must tolerate any input that encode can
                      produce.  Variable-length encodings are fully supported
-                     (fork delta #2: serde-based inputs, CHANGELOG.md:7-11).
+                     (fork delta #2: serde-based inputs, upstream's CHANGELOG.md:7-11
+                     as SURVEY.md records it).
     input_eq       — equality used for misprediction detection; defaults to ==.
     predictor      — InputPredictor strategy, default repeat-last.
     """

@@ -7,12 +7,19 @@ package, and exposes ``encode``/``decode`` with the exact signatures of
 ``ggrs_tpu.net.compression`` — the pure-Python implementations remain the
 fallback whenever a toolchain is unavailable.
 
+A library on disk is trusted only if it carries the digest of the CONTENT
+of today's ``native/*.cpp``/``*.h`` and compiler flags (``ensure_built``);
+anything else — an older build, one copied in from another checkout — is
+rebuilt, never loaded.  When the build or the load fails, ``load_error()``
+says why, compiler output included.
+
 Set GGRS_TPU_NO_NATIVE=1 to force the Python codec.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -47,7 +54,10 @@ _RESOURCE_ERRORS = (-11, -12)
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_load_failed = False
+# why the library is not loaded (build or dlopen failure, compiler output
+# included); None while unattempted or loaded.  Latched: one attempt per
+# process.
+_load_error: Optional[str] = None
 _decode_out = None
 _decode_sizes = None
 
@@ -122,28 +132,70 @@ def _sources() -> List[Path]:
     ]
 
 
-def _source_mtime() -> float:
-    """Newest mtime across the native sources and headers (staleness)."""
-    newest = 0.0
-    for p in list(_native_dir().glob("*.cpp")) + list(
-        _native_dir().glob("*.h")
-    ):
-        newest = max(newest, p.stat().st_mtime)
-    return newest
+def _lib_path() -> Path:
+    return Path(__file__).resolve().parent / _LIB_NAME
 
 
-def _build(lib_path: Path) -> bool:
-    """Compile the native library to ``lib_path``.
+def _compile_flags() -> List[str]:
+    if _SANITIZE == "thread":
+        flags = ["-O1", "-g", "-fsanitize=thread"]
+    elif _SANITIZE:
+        flags = ["-O1", "-g", "-fsanitize=address,undefined",
+                 "-fno-sanitize-recover=all"]
+    else:
+        flags = ["-O2"]
+    return flags + ["-shared", "-fPIC", "-std=c++17"]
+
+
+# codec.cpp compiles this prefix + the -DGGRS_BUILD_DIGEST value into the
+# library (ggrs_build_digest); _is_current looks for it in the file's bytes
+_DIGEST_MARKER = b"ggrs-build-digest:"
+
+
+def _build_digest() -> str:
+    """sha256 over the compiler flags and the name + content of every
+    native source and header: what the library on disk must have been
+    built from to be loaded."""
+    h = hashlib.sha256()
+    h.update("\0".join(_compile_flags()).encode())
+    files = sorted(
+        list(_native_dir().glob("*.cpp")) + list(_native_dir().glob("*.h"))
+    )
+    for p in files:
+        h.update(b"\0" + p.name.encode() + b"\0")
+        h.update(p.read_bytes())
+    return h.hexdigest()
+
+
+def _is_current(lib_path: Path) -> bool:
+    """Does ``lib_path`` carry today's build digest?  Decided from the
+    file's bytes, before any dlopen: staleness by mtime cannot survive a
+    copy of the tree, and a stale library must not be mapped at all (glibc
+    hands back the already-mapped object for the same path afterwards)."""
+    try:
+        blob = lib_path.read_bytes()
+    except OSError:
+        return False
+    return _DIGEST_MARKER + _build_digest().encode() in blob
+
+
+class NativeBuildError(RuntimeError):
+    """The native library could not be compiled; carries g++'s stderr."""
+
+
+def _build(lib_path: Path) -> None:
+    """Compile the native library to ``lib_path``; ``NativeBuildError``
+    (with the compiler's stderr) on failure.
 
     g++ writes to a pid-unique temp beside the target and the result is
     moved in atomically: the module-level ``_lock`` is per-process, so two
     concurrently-starting processes would otherwise race compiler output
-    into the same file and one would dlopen a torn .so (latching
-    ``_load_failed`` and disabling both fast paths for its lifetime).
+    into the same file and one would dlopen a torn .so.
     """
     srcs = _sources()
-    if not all(s.exists() for s in srcs):
-        return False
+    missing = [s.name for s in srcs if not s.exists()]
+    if missing:
+        raise NativeBuildError(f"native sources missing: {missing}")
     # Sweep temps orphaned by hard-killed builds (different pid → never
     # reused).  Age-gated to the 120 s build timeout: a fresh temp from a
     # CONCURRENTLY-building process must survive — unlinking it mid-write
@@ -158,19 +210,10 @@ def _build(lib_path: Path) -> bool:
         except OSError:
             pass  # raced with the owning process: leave it alone
     tmp = lib_path.with_name(f"{lib_path.name}.build.{os.getpid()}")
-    if _SANITIZE == "thread":
-        flags = ["-O1", "-g", "-fsanitize=thread"]
-    elif _SANITIZE:
-        flags = ["-O1", "-g", "-fsanitize=address,undefined",
-                 "-fno-sanitize-recover=all"]
-    else:
-        flags = ["-O2"]
     cmd = [
         "g++",
-        *flags,
-        "-shared",
-        "-fPIC",
-        "-std=c++17",
+        *_compile_flags(),
+        f'-DGGRS_BUILD_DIGEST="{_build_digest()}"',
         "-o",
         str(tmp),
     ] + [str(s) for s in srcs]
@@ -179,42 +222,48 @@ def _build(lib_path: Path) -> bool:
             cmd, check=True, capture_output=True, timeout=120
         )
         tmp.replace(lib_path)
-        return True
-    except (subprocess.SubprocessError, OSError):
+    except subprocess.CalledProcessError as e:
         tmp.unlink(missing_ok=True)
-        return False
+        stderr = e.stderr.decode(errors="replace").strip()
+        raise NativeBuildError(
+            f"g++ exited {e.returncode}:\n{stderr[-4000:]}"
+        ) from None
+    except (subprocess.SubprocessError, OSError) as e:
+        tmp.unlink(missing_ok=True)
+        raise NativeBuildError(f"{type(e).__name__}: {e}") from None
+
+
+def ensure_built(lib_path: Optional[Path] = None) -> Path:
+    """The path of a native library built from today's sources and flags,
+    compiling it first unless the one on disk already carries their digest.
+    ``NativeBuildError`` on failure.  Does not dlopen — also the build
+    entry point of scripts/build_sanitized.sh."""
+    lib_path = _lib_path() if lib_path is None else lib_path
+    if not _is_current(lib_path):
+        _build(lib_path)
+    return lib_path
+
+
+def load_error() -> Optional[str]:
+    """Why the native library is not in use after a failed attempt (the
+    compiler's stderr, a dlopen error), else None.  ``GGRS_TPU_NO_NATIVE``
+    is reported by the callers that honour it, not here."""
+    return _load_error
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _load_failed
+    global _lib, _load_error
     if _lib is not None:
         return _lib
-    if _load_failed or os.environ.get("GGRS_TPU_NO_NATIVE"):
+    if _load_error is not None or os.environ.get("GGRS_TPU_NO_NATIVE"):
         return None
     with _lock:
-        if _lib is not None or _load_failed:
+        if _lib is not None or _load_error is not None:
             return _lib
-        lib_path = Path(__file__).resolve().parent / _LIB_NAME
         try:
-            stale = (
-                not lib_path.exists()
-                or _source_mtime() > lib_path.stat().st_mtime
-            )
-            if stale and not _build(lib_path):
-                _load_failed = True
-                return None
-            lib = ctypes.CDLL(str(lib_path))
-            if not hasattr(lib, "ggrs_ep_new"):
-                # library predates the endpoint datapath: try a rebuild —
-                # _build is atomic (temp + replace), so a prebuilt .so
-                # without sources/toolchain is never destroyed; on failure we
-                # keep serving the codec symbols and simply leave the
-                # endpoint fast path disabled (endpoint_lib() returns None)
-                if _build(lib_path):
-                    del lib
-                    lib = ctypes.CDLL(str(lib_path))  # new inode: fresh load
-        except OSError:
-            _load_failed = True
+            lib = ctypes.CDLL(str(ensure_built()))
+        except (NativeBuildError, OSError) as e:
+            _load_error = f"{type(e).__name__}: {e}"
             return None
 
         lib.ggrs_codec_encode_bound.restype = ctypes.c_size_t
@@ -258,11 +307,6 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_size_t),
         ]
         # ---- endpoint datapath (native/endpoint.cpp) ----
-        # may be absent when a prebuilt pre-endpoint library is in use and
-        # no toolchain is available; the codec fast path still works then
-        if not hasattr(lib, "ggrs_ep_new"):
-            _lib = lib
-            return _lib
         lib.ggrs_ep_new.restype = ctypes.c_void_p
         lib.ggrs_ep_new.argtypes = [
             ctypes.c_char_p, ctypes.c_size_t,

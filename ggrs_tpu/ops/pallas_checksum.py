@@ -16,8 +16,9 @@ Bit-for-bit identical to the XLA path by construction: the same per-word
 formulas in the same mod-2^32 integer arithmetic — every lane is a
 commutative sum of per-word terms, so block order cannot change the result.
 ``tests/test_pallas_checksum.py`` asserts equality on the interpreter
-(CPU) and the TPU path is asserted by ``bench.py``'s desync gates whenever
-the kernel is enabled.
+(CPU; ``interpret=True`` is a test-only argument) and ``chip_smoke.py``'s
+``pallas`` leg asserts it compiled by Mosaic on the chip, on a 256 MiB leaf
+and a ragged one.
 
 Enablement: ``leaf_digest_pallas`` is opt-in via ``use_pallas_checksums`` /
 the ``GGRS_TPU_PALLAS_CHECKSUM`` env var ("on"/"off", default off) and only
@@ -35,13 +36,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:  # pallas is part of jax.experimental; gate anyway for exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # lane constants — imported from ops.checksum so the kernel's per-word terms
 # and the XLA formulas can never drift apart
@@ -109,11 +105,6 @@ def leaf_digest_pallas(words: jax.Array, interpret: bool = False) -> jax.Array:
     right index offset, which is exact because every lane is a commutative
     mod-2^32 sum.
     """
-    if not HAVE_PALLAS:
-        raise RuntimeError(
-            "pallas is unavailable in this jax build; use the XLA digest "
-            "(ops.checksum._leaf_digest) instead"
-        )
     n = words.shape[0]
     per_block = _BLOCK_ROWS * _LANES
     blocks = n // per_block
@@ -158,8 +149,6 @@ def use_pallas_checksums(enable: Optional[bool]) -> None:
 
 
 def pallas_enabled() -> bool:
-    if not HAVE_PALLAS:
-        return False
     if _override is not None:
         return _override
     return os.environ.get("GGRS_TPU_PALLAS_CHECKSUM", "off").lower() in (

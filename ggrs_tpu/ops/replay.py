@@ -22,7 +22,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -96,7 +96,7 @@ def _store_input(ring: DeviceStateRing, inputs: Any, frame: jax.Array, inp: Any)
 
 def build_scrub_program(
     advance: AdvanceFn,
-    donate: Optional[bool] = None,
+    donate: bool = True,
     unroll: int = 4,
 ):
     """Compile the confirmed-only playback program: advance N frames in ONE
@@ -111,9 +111,6 @@ def build_scrub_program(
     stacks the window's per-frame inputs on the leading axis; state and
     inputs stay in HBM for the whole window, exactly like ``run_steady``.
     """
-    if donate is None:
-        donate = jax.default_backend() == "tpu"
-
     def scrub(state: Any, stacked_inputs: Any) -> Any:
         def body(st: Any, inp: Any) -> Tuple[Any, None]:
             return advance(st, inp), None
@@ -129,7 +126,7 @@ def build_replay_programs(
     ring_length: int,
     check_distance: int,
     checksum: ChecksumFn = checksum_device,
-    donate: Optional[bool] = None,
+    donate: bool = True,
     unroll_resim: bool = False,
     unroll_ticks: int = 4,
 ) -> ReplayPrograms:
@@ -140,9 +137,10 @@ def build_replay_programs(
     AdvanceFrame request (/root/reference/src/lib.rs:183-189).
     ``ring_length`` must exceed ``check_distance`` so the rollback target is
     still in the ring, mirroring ``max_prediction + 1`` cells in the reference.
-    ``donate``: donate the carry buffers to each dispatch (in-place HBM update);
-    defaults to on for TPU, off elsewhere (CPU/interpret donation is a no-op
-    that only produces warnings).
+    ``donate``: donate the carry buffers to each dispatch (in-place HBM
+    update).  On by default on every backend, so the CPU tests run the
+    program the chip runs; callers that re-wrap ``scan_*`` in their own jit
+    (``BatchedSessions``) or hand the same carry to two programs pass False.
     ``unroll_resim``/``unroll_ticks``: loop unrolling for the inner (resim)
     and outer (tick) scans.  Defaults were retuned in round 4 under
     completion-fenced timing: the ROLLED inner resim loop measures ~1.3x
@@ -155,8 +153,6 @@ def build_replay_programs(
     assert ring_length > check_distance, "ring must cover the rollback window"
     ring = DeviceStateRing(ring_length)
     d = check_distance
-    if donate is None:
-        donate = jax.default_backend() == "tpu"
 
     def warmup_tick(carry: Any, inp: Any, frame: jax.Array) -> Any:
         # [Save, Advance] — the pre-rollback request pattern

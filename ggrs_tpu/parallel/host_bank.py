@@ -1,10 +1,11 @@
 """Host session pool: step B P2P sessions' per-tick protocol + sync
 mechanism in ONE ctypes crossing per pool tick.
 
-The round-5 capacity knee was ~90% host bookkeeping, and the per-operation
-native cores measured perf-neutral because ~200 ctypes crossings per
-session-tick hand back what the C++ saves (docs/ROUND5.md §4).  This module
-is the located fix: ``HostSessionPool`` drives every pooled session's tick —
+Per-session host bookkeeping dominated the pooled tick, and the
+per-operation native cores measured perf-neutral because ~200 ctypes
+crossings per session-tick hand back what the C++ saves (DESIGN.md §11).
+This module is the located fix: ``HostSessionPool`` drives every pooled
+session's tick —
 input enqueue, prediction/confirmation watermarks, endpoint timers, ack
 trim, outbound InputMessage assembly — through ``native/session_bank.cpp``
 off a single packed command buffer per tick.
@@ -19,8 +20,10 @@ per-session observables (``current_frame``, ``last_confirmed_frame``,
 FALLBACK: when the native library is unavailable (``GGRS_TPU_NO_NATIVE``,
 no toolchain) or any session's shape is outside the bank's mechanism
 (sparse saving, lockstep, spectators, desync detection, handshake,
-variable-size inputs), the pool transparently drives ordinary per-session
-``P2PSession`` objects — the untouched semantic reference.  Parity between
+variable-size inputs), the pool drives ordinary per-session ``P2PSession``
+objects — the untouched semantic reference — and ``native_active`` /
+``native_reason`` say so and why (a measurement asserts the tier; it does
+not infer it from timing).  Parity between
 the two paths is pinned by tests/test_session_bank.py: bit-identical wire
 bytes, frames, and events under seeded loss/dup/reorder traffic.
 
@@ -701,6 +704,8 @@ class HostSessionPool:
         self._builders: List[Tuple[Any, Any]] = []
         self._finalized = False
         self._native_active = False
+        # why the pool is (not) on the native bank; set by _finalize
+        self._native_reason = "not finalized"
         self._bank = None
         self._lib = None
         self._mirrors: List[_SessionMirror] = []
@@ -1032,19 +1037,32 @@ class HostSessionPool:
             self._m_slot_state.labels(state=SLOT_NATIVE).inc(
                 len(self._builders)
             )
-        lib = None if os.environ.get("GGRS_TPU_NO_NATIVE") else (
-            _native.bank_lib()
-        )
+        # every way off the native bank records its reason
+        # (``native_reason``): callers assert the tier, they do not infer
+        # it from timing
+        reason = None
+        if os.environ.get("GGRS_TPU_NO_NATIVE"):
+            lib = None
+            reason = "GGRS_TPU_NO_NATIVE is set"
+        else:
+            lib = _native.bank_lib()
+            if lib is None:
+                reason = "native library unavailable: " + (
+                    _native.load_error() or "it lacks the bank entry points"
+                )
         if lib is not None and hasattr(lib, "ggrs_bank_hdr_stride"):
             if int(lib.ggrs_bank_hdr_stride()) != _HDR_DTYPE.itemsize:
                 # library/driver layout skew (a newer .so than this
                 # driver): we cannot parse its header table, so degrade
                 # like every other layout mismatch — per-session Python
                 # sessions, never a half-initialized bank
+                reason = (
+                    f"bank header stride {int(lib.ggrs_bank_hdr_stride())} "
+                    f"!= {_HDR_DTYPE.itemsize} (library/driver skew)"
+                )
                 _logger.warning(
-                    "bank header stride %d != %d (library/driver skew); "
-                    "pool falls back to per-session Python sessions",
-                    int(lib.ggrs_bank_hdr_stride()), _HDR_DTYPE.itemsize,
+                    "%s; pool falls back to per-session Python sessions",
+                    reason,
                 )
                 lib = None
         if lib is not None and hasattr(lib, "ggrs_bank_req_stride"):
@@ -1055,13 +1073,16 @@ class HostSessionPool:
                 int(lib.ggrs_bank_req_stride()) != _REQ_DTYPE.itemsize
                 or int(lib.ggrs_bank_stage_stride()) != _STAGE_DTYPE.itemsize
             ):
+                reason = (
+                    f"bank descriptor strides (req "
+                    f"{int(lib.ggrs_bank_req_stride())}, stage "
+                    f"{int(lib.ggrs_bank_stage_stride())}) != driver "
+                    f"({_REQ_DTYPE.itemsize}, {_STAGE_DTYPE.itemsize}) "
+                    f"(library/driver skew)"
+                )
                 _logger.warning(
-                    "bank descriptor strides (req %d, stage %d) != driver "
-                    "(%d, %d) (library/driver skew); pool falls back to "
-                    "per-session Python sessions",
-                    int(lib.ggrs_bank_req_stride()),
-                    int(lib.ggrs_bank_stage_stride()),
-                    _REQ_DTYPE.itemsize, _STAGE_DTYPE.itemsize,
+                    "%s; pool falls back to per-session Python sessions",
+                    reason,
                 )
                 lib = None
         # The bank runs every session's timers off ONE clock read per tick
@@ -1088,12 +1109,27 @@ class HostSessionPool:
             and lib is not None
             and hasattr(lib, "ggrs_bank_attach_spectator")
         )
-        eligible = lib is not None and same_timebase() and all(
-            _bank_eligible(b, hub_active=hub_active)
-            and hasattr(s, "receive_all_datagrams")
-            for b, s in self._builders
-        )
-        if not eligible:
+        if lib is not None:
+            if not same_timebase():
+                reason = (
+                    "no sessions" if not self._builders
+                    else "builders' clocks are on different timebases"
+                )
+            else:
+                outside = [
+                    i for i, (b, s) in enumerate(self._builders)
+                    if not (
+                        _bank_eligible(b, hub_active=hub_active)
+                        and hasattr(s, "receive_all_datagrams")
+                    )
+                ]
+                if outside:
+                    reason = (
+                        f"{len(outside)} session(s) outside the bank's scope "
+                        f"(first: index {outside[0]}; see _bank_eligible)"
+                    )
+        if reason is not None:
+            self._native_reason = reason
             for builder, socket in self._builders:
                 self._sessions.append(builder.start_p2p_session(socket))
             self._stagers = [
@@ -1106,6 +1142,7 @@ class HostSessionPool:
         if not self._bank:
             raise MemoryError("ggrs_bank_new failed")
         self._native_active = True
+        self._native_reason = "native bank engaged"
         # the broadcast command/output layout is spoken whenever the
         # library carries the entry points — spectator tables may be empty
         self._has_spec = hasattr(lib, "ggrs_bank_attach_spectator")
@@ -1827,6 +1864,16 @@ class HostSessionPool:
         if not self._finalized:
             self._finalize()
         return self._native_active
+
+    @property
+    def native_reason(self) -> str:
+        """Why ``native_active`` is what it is: "native bank engaged", or
+        what sent the pool to per-session Python sessions (kill switch,
+        the native build's error with the compiler's output, a layout
+        skew, mismatched clocks, sessions outside the bank's scope)."""
+        if not self._finalized:
+            self._finalize()
+        return self._native_reason
 
     def __len__(self) -> int:
         return len(self._builders)

@@ -15,6 +15,8 @@ import contextlib
 import logging
 from typing import Iterator
 
+from jax.profiler import TraceAnnotation
+
 _ROOT = "ggrs_tpu"
 
 
@@ -39,10 +41,5 @@ def enable_tracing(level: int = logging.DEBUG) -> None:
 @contextlib.contextmanager
 def trace_span(name: str) -> Iterator[None]:
     """Named range in jax profiler traces; no-op overhead when not profiling."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except ImportError:  # pragma: no cover - ancient jax
-        yield
-        return
     with TraceAnnotation(name):
         yield

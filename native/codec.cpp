@@ -20,7 +20,19 @@
 
 using namespace ggrs;
 
+// Build provenance.  The loader (_native.py ensure_built) passes the sha256
+// of every native source + the compiler flags and loads a library from disk
+// only if it finds this marker carrying the digest it computes now; a
+// hand-built library (no -D) is therefore rebuilt, not trusted.
+#ifndef GGRS_BUILD_DIGEST
+#define GGRS_BUILD_DIGEST "unset"
+#endif
+
 extern "C" {
+
+const char* ggrs_build_digest() {
+  return "ggrs-build-digest:" GGRS_BUILD_DIGEST;
+}
 
 // Upper bound on the encoded size for a given total payload.
 size_t ggrs_codec_encode_bound(size_t total_input_bytes, size_t n_inputs) {

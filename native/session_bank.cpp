@@ -3,7 +3,7 @@
 //
 // Round 5 made the per-operation mechanisms native (native/sync_core.cpp,
 // native/endpoint.cpp) and measured them perf-neutral: ~200 ctypes crossings
-// per session-tick hand back the ~13% the C++ saves (docs/ROUND5.md §4).
+// per session-tick hand back the ~13% the C++ saves (docs/DESIGN.md §11).
 // This module composes those SAME mechanisms — it calls their extern "C"
 // APIs, it does not reimplement them — into a bank of B sessions, and
 // ggrs_bank_tick() walks all of them off one packed command buffer:

@@ -56,13 +56,12 @@ if [ ! -e "$asan_rt" ]; then
     exit 0
 fi
 
-out=ggrs_tpu/net/_ggrs_codec_san.so
-echo "=== ASan+UBSan leg: building $out ==="
-g++ -O1 -g -shared -fPIC -std=c++17 \
-    -fsanitize=address,undefined -fno-sanitize-recover=all \
-    -o "$out" \
-    native/codec.cpp native/endpoint.cpp native/sync_core.cpp \
-    native/session_bank.cpp native/net_batch.cpp
+# Built by the loader's own ensure_built (same flags, plus the source digest
+# it checks before trusting a library), WITHOUT the sanitizer runtime
+# preloaded into the compiler.
+echo "=== ASan+UBSan leg: building _ggrs_codec_san.so ==="
+GGRS_NATIVE_SANITIZE=1 JAX_PLATFORMS=cpu python -c \
+    "from ggrs_tpu.net._native import ensure_built; print(ensure_built())"
 
 # detect_leaks=0: CPython itself "leaks" interned objects at exit, which is
 # noise here — the target is heap corruption / UB in the native cores while
@@ -106,12 +105,9 @@ if [ ! -e "$tsan_rt" ]; then
     exit 0
 fi
 
-out=ggrs_tpu/net/_ggrs_codec_tsan.so
-echo "=== TSan leg: building $out ==="
-g++ -O1 -g -shared -fPIC -std=c++17 -fsanitize=thread \
-    -o "$out" \
-    native/codec.cpp native/endpoint.cpp native/sync_core.cpp \
-    native/session_bank.cpp native/net_batch.cpp
+echo "=== TSan leg: building _ggrs_codec_tsan.so ==="
+GGRS_NATIVE_SANITIZE=thread JAX_PLATFORMS=cpu python -c \
+    "from ggrs_tpu.net._native import ensure_built; print(ensure_built())"
 
 # The TSan leg targets the concurrency surface: the kernel-batched
 # socket datapath (GIL released around recvmmsg/sendmmsg), the

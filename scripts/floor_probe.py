@@ -1,4 +1,4 @@
-"""Floor probe (VERDICT r4 item 2): what does a BARE lax.scan(advance) cost?
+"""Floor probe: what does a BARE lax.scan(advance) cost?
 
 Measures, on the same chip with the same completion fence as bench.py:
   1. bare      — jit(lax.scan(advance)) alone: no ring, no digest, no history
@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import enter_honest_timing_mode, REPEATS
+from bench import REPEATS
+from ggrs_tpu.utils.device import place_compile_cache, require_chip
 from ggrs_tpu.games import BoxGame
 from ggrs_tpu.ops.checksum import checksum_device, CHECKSUM_LANES
 from ggrs_tpu.ops.ring import DeviceStateRing
@@ -44,6 +45,8 @@ STEPS_PER_DISPATCH = (D + 1) * TICKS_PER_DISPATCH
 
 
 def main() -> None:
+    require_chip()  # a floor measured on the CPU backend is not the floor
+    place_compile_cache()
     game = BoxGame(PLAYERS)
     init = game.init_state()
     rng = np.random.default_rng(7)
@@ -90,10 +93,7 @@ def main() -> None:
     )
     tick_inps = staged_inputs(TICKS_PER_DISPATCH)
 
-    # ---- honest mode FIRST, then warm up with real fences ------------------
-    # (deferring the first D2H past a pile of enqueued warmup work makes the
-    # eventual fence surface async errors far from their source)
-    enter_honest_timing_mode()
+    # ---- warm up (compile) every variant ----------------------------------
     jax.block_until_ready(bare(st0, inps))
     jax.block_until_ready(digest((st0, acc0), inps))
     jax.block_until_ready(ringp((st0, rbufs0), (inps, frames)))

@@ -28,7 +28,6 @@ def _xla_lanes(words: jnp.ndarray) -> np.ndarray:
     return np.asarray(jnp.stack([lane0, lane1, lane2, lane3]))
 
 
-@pytest.mark.skipif(not pc.HAVE_PALLAS, reason="pallas unavailable")
 @pytest.mark.parametrize(
     "n",
     [
@@ -48,7 +47,6 @@ def test_kernel_matches_xla_lanes(n):
     np.testing.assert_array_equal(got, _xla_lanes(words))
 
 
-@pytest.mark.skipif(not pc.HAVE_PALLAS, reason="pallas unavailable")
 def test_ragged_tail_folds_at_correct_offset():
     # all-zero words: lanes 0-2 are 0, lane3 is sum(idx*B) — index-dependent,
     # so a tail folded at the wrong global offset (or dropped) would differ
@@ -61,7 +59,6 @@ def test_ragged_tail_folds_at_correct_offset():
         np.testing.assert_array_equal(got, _xla_lanes(words))
 
 
-@pytest.mark.skipif(not pc.HAVE_PALLAS, reason="pallas unavailable")
 def test_leaf_digest_routing_unchanged_when_disabled(monkeypatch):
     # default-off policy: _leaf_digest must not engage pallas unless enabled
     # AND on TPU AND the leaf is large enough
@@ -79,7 +76,6 @@ def test_leaf_digest_routing_unchanged_when_disabled(monkeypatch):
         pc.use_pallas_checksums(None)
 
 
-@pytest.mark.skipif(not pc.HAVE_PALLAS, reason="pallas unavailable")
 def test_words_view_of_mixed_dtypes_roundtrip():
     # the pallas path consumes the same _as_u32_words stream as XLA; a mixed
     # pytree digest must be invariant to which implementation digests leaves

@@ -1,4 +1,4 @@
-"""Session-level adversarial fuzz (VERDICT r3 item 5).
+"""Session-level adversarial fuzz.
 
 The codec and message layers are property-tested in isolation
 (tests/test_compression.py, tests/test_messages.py); this module attacks the

@@ -1,6 +1,6 @@
 """Speculative rollback wired into the live P2P path.
 
-BASELINE config 3's integration contract (VERDICT round 1, item 1): a P2P
+BASELINE config 3's integration contract: a P2P
 rollback is fulfilled by a branch hit with no replay dispatch; a miss falls
 back to the fused replay; states stay bit-identical to a non-speculative peer
 either way.  The replay loop being replaced is the reference's rollback hot

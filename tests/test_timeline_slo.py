@@ -549,9 +549,10 @@ class TestBenchReport:
             rounds, self.mod.trajectory(rounds), [], 0.15)
 
     def test_repo_bench_files_all_parse(self):
-        # the real rounds: every file loads, r05 (rc=124) is data-less
+        # the real rounds (r03..r11): every file loads, r05 (rc=124) is
+        # data-less
         rounds = self.mod.load_rounds(str(REPO))
-        assert len(rounds) >= 11
+        assert len(rounds) >= 9
         by_n = {r["round"]: r for r in rounds}
         assert by_n[5]["records"] == [] and by_n[5]["rc"] == 124
         assert sum(len(r["records"]) for r in rounds) > 40
