@@ -5,7 +5,7 @@ One process owns the chip from start to end and drives the main path once
 through the entry points a user calls, at a deployment's size, checking
 every answer against a plain reference:
 
-    python chip_smoke.py              # the TPU v5e machine; exit 0 + JSON
+    python chip_smoke.py              # the TPU v5e machine; exit 0 + verdict
     python chip_smoke.py --chips 4    # pool leg sharded over a 4-chip host
 
 Legs (each raises on failure; nothing is caught and survived):
@@ -32,10 +32,15 @@ Legs (each raises on failure; nothing is caught and survived):
 The fence leg's pre-read sample is the process's first device work (it has
 to precede every device->host read); the pool leg is the first leg run.
 
+The last line of standard output is the verdict and nothing else:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``,
+the device as JAX reports it.  The line before it (``summary: {...}``, also
+written to ``chiprun_out/chip_smoke.json``) carries every leg's facts.
+
 Without a TPU of a known kind the script exits nonzero and prints no
 result.  ``--rehearse-cpu`` (tiny sizes, Pallas interpreted) and ``--legs``
 (a subset) exist for debugging; both label their output and neither can
-print the passing verdict.
+print the passing verdict (``"ok": false``, exit 10).
 """
 
 from __future__ import annotations
@@ -659,7 +664,6 @@ def main(argv: List[str]) -> int:
     if unknown or not legs:
         ap.error(f"unknown legs {unknown}; one of {LEGS}")
     rehearsal = args.rehearse_cpu
-    tag = "REHEARSAL (not a chip result) " if rehearsal else ""
 
     if rehearsal:
         device = device_record()
@@ -675,6 +679,22 @@ def main(argv: List[str]) -> int:
             # no chip, no result: one line on stderr, nothing on stdout
             sys.exit(f"chip_smoke: FAIL: {e}")
         peak_tflops = float(device_peaks(device["kind"])["bf16_tflops"])
+    try:
+        complete = run_legs(args, legs, rehearsal, device, peak_tflops)
+    except BaseException:
+        # a failed leg: the verdict line, then the exception, not survived
+        print(json.dumps({"ok": False, "device": device}), flush=True)
+        raise
+    # the contract's last line: exactly these keys, the device as JAX reports it
+    print(json.dumps({"ok": complete, "device": device}), flush=True)
+    return 0 if complete else NOT_A_CHIP_RESULT
+
+
+def run_legs(args: argparse.Namespace, legs: List[str], rehearsal: bool,
+             device: Dict[str, Any], peak_tflops: float) -> bool:
+    """Run ``legs`` in order, print each leg's facts and the summary, and
+    return whether this was the complete chip run.  A failed check raises."""
+    tag = "REHEARSAL (not a chip result) " if rehearsal else ""
     size = REHEARSAL if rehearsal else REAL
 
     def version(dist: str) -> str:
@@ -772,8 +792,8 @@ def main(argv: List[str]) -> int:
         (out_dir / "chip_smoke.json").write_text(
             json.dumps(summary, indent=1) + "\n"
         )
-    print(json.dumps(summary), flush=True)
-    return 0 if complete else NOT_A_CHIP_RESULT
+    print(f"{tag}summary: " + json.dumps(summary), flush=True)
+    return complete
 
 
 if __name__ == "__main__":

@@ -51,8 +51,16 @@ class TestChipSmoke:
         r = _smoke("--rehearse-cpu", tmp_path=tmp_path)
         assert r.returncode not in (0, 1), (r.returncode, r.stderr[-2000:])
         lines = r.stdout.strip().splitlines()
-        summary = json.loads(lines[-1])
+        # the last line is the verdict, with exactly the contract's keys
+        verdict = json.loads(lines[-1])
+        assert list(verdict) == ["ok", "device"] and verdict["ok"] is False
+        assert list(verdict["device"]) == ["platform", "kind", "count"]
+        assert verdict["device"]["platform"] == "cpu"
+        assert isinstance(verdict["device"]["kind"], str)
+        assert type(verdict["device"]["count"]) is int
+        summary = json.loads(lines[-2].partition("summary: ")[2])
         assert summary["ok"] is False and summary["rehearsal"] is True
+        assert summary["device"] == verdict["device"]
         assert summary["claim"] is None
         assert list(summary)[-1] == "claim"
         assert [name for name in summary["legs"]] == [
@@ -68,6 +76,23 @@ class TestChipSmoke:
             l.startswith("REHEARSAL") for l in lines[:-1]
         ), [l[:40] for l in lines[:-1]]
         assert '"ok": true' not in r.stdout
+
+    def test_failed_leg_ends_on_a_false_verdict_and_is_not_survived(
+            self, tmp_path):
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO))
+        env.pop("XLA_FLAGS", None)
+        r = subprocess.run(
+            [sys.executable, "-c",
+             "import chip_smoke as c\n"
+             "def boom(*a): c.check(False, 'games: made to fail')\n"
+             "c.leg_games = boom\n"
+             "raise SystemExit(c.main(['--rehearse-cpu', '--legs', 'games']))"],
+            cwd=tmp_path, env=env, capture_output=True, text=True, timeout=600,
+        )
+        assert r.returncode == 1, (r.returncode, r.stderr[-2000:])
+        assert "SmokeFailure: games: made to fail" in r.stderr
+        verdict = json.loads(r.stdout.strip().splitlines()[-1])
+        assert list(verdict) == ["ok", "device"] and verdict["ok"] is False
 
 
 class TestDeviceModule:
