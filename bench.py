@@ -1956,8 +1956,8 @@ def run_host_bank_capacity() -> None:
 
     # ---- per-phase attribution at B=512 (PR 5 in-crossing timers plus
     # the §21 `staging` phase: stage_inputs time accrued outside the tick
-    # window rides the same trace tail; the traced pool uses the legacy
-    # parse by design, the native phase split is decode-independent) ----
+    # window rides the same trace tail; a traced pool decodes like any
+    # other, so the split prices the served path) ----
     from ggrs_tpu.obs import Tracer
 
     host, schedules, pool = _bank_matches_setup(

@@ -53,7 +53,12 @@ from .registry import (
     default_registry,
 )
 from .recorder import ChecksumHistory, FlightRecorder
-from .trace import NULL_TRACER, Tracer, validate_chrome_trace
+from .trace import (
+    NULL_TRACER,
+    Tracer,
+    default_tracer,
+    validate_chrome_trace,
+)
 from .forensics import (
     DesyncReport,
     build_desync_report,
@@ -119,6 +124,7 @@ __all__ = [
     "Tracer",
     "build_desync_report",
     "default_registry",
+    "default_tracer",
     "first_divergent_frame",
     "first_occurrence_order",
     "fleet_metrics_digest",

@@ -9,6 +9,23 @@ child spans of the crossing — and exports the window in the Chrome
 trace-event JSON format, so one ``tracer.write(path)`` produces a file that
 loads directly in ``chrome://tracing`` or https://ui.perfetto.dev.
 
+One tracer serves the hosted path: :func:`default_tracer` is what
+``HostSessionPool`` and ``BatchedRequestExecutor`` use when handed none.  It
+records while an operator switched it on (:meth:`Tracer.switch`) **or**
+while a ``jax.profiler`` trace is active, and while a profile is being
+taken every span is also a ``jax.profiler.TraceAnnotation`` named
+``ggrs.<span>`` with its args as metadata, so it lies in the profile beside
+the device's operations by construction.  A root span's annotation carries
+``perf_ns`` (its own ``perf_counter_ns`` start): the anchor between this
+module's clock and the profiler's (:func:`profile_clock_offset_ns`).
+
+Every span records its parent's name and the pool tick number in ``args``
+(``parent``, ``tick``), so the seven-field ring event the fleet ships
+between processes (``import_spans``) keeps its shape and a reader can build
+the tree per tick: a layer's self time is its span minus what its children
+cover.  Tracing never chooses the path: a traced pool runs the same decoder
+and the same device program as an untraced one.
+
 Design constraints, shared with the rest of ``ggrs_tpu.obs``:
 
 - **Compiles out.**  ``Tracer(enabled=False)`` hands back a shared no-op
@@ -34,10 +51,18 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import (
+    Any, Deque, Dict, Iterable, List, Optional, Sequence, Tuple,
+)
 
-__all__ = ["Tracer", "NULL_TRACER", "chrome_trace_events",
-           "validate_chrome_trace"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "NULL_TRACER", "default_tracer", "chrome_trace_events",
+           "validate_chrome_trace", "profile_clock_offset_ns",
+           "spans_by_tick", "span_stats", "ANNOTATION_PREFIX"]
+
+# a span named ``pool.tick`` is the profile event ``ggrs.pool.tick``
+ANNOTATION_PREFIX = "ggrs."
 
 # event phases on the ring (Chrome trace-event "ph" values)
 _PH_COMPLETE = "X"
@@ -56,31 +81,71 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """Context manager recording one complete ("X") event on exit: name,
+    start, duration, and in ``args`` the enclosing span's name (``parent``)
+    and the pool tick it belongs to (``tick``, inherited from the parent
+    when not given).  While a profile is being taken it is a
+    ``TraceAnnotation`` too, entered after the clock read and left before
+    it, so the ring's span contains the profile's."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_stack",
+                 "_ann", "_late", "_anchor")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Optional[Dict[str, Any]]) -> None:
+                 args: Dict[str, Any], anchor: bool = False) -> None:
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        self._anchor = anchor
+        self._ann = None
+        self._late: Optional[Dict[str, Any]] = None
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter_ns()
+        tracer = self._tracer
+        args = self._args
+        stack = self._stack = tracer._thread_stack()
+        if stack:
+            parent, tick = stack[-1]
+            args["parent"] = parent
+            if tick is not None:
+                args.setdefault("tick", tick)
+        stack.append((self._name, args.get("tick")))
+        t0 = self._t0 = time.perf_counter_ns()
+        if tracer._annotate:
+            meta = dict(args, perf_ns=t0) if self._anchor else args
+            ann = self._ann = TraceAnnotation(
+                ANNOTATION_PREFIX + self._name, **meta)
+            ann.__enter__()
         return self
 
+    def set(self, **args) -> None:
+        """Counts known only at the span's end (bytes built, rows decoded):
+        they join ``args``, and the annotation's metadata."""
+        self._args.update(args)
+        if self._ann is not None:
+            self._late = {**(self._late or {}), **args}
+
     def __exit__(self, *exc) -> bool:
+        ann = self._ann
+        if ann is not None:
+            if self._late:
+                ann.set_metadata(**self._late)
+            ann.__exit__(None, None, None)
+        t1 = time.perf_counter_ns()
+        self._stack.pop()
         t0 = self._t0
         self._tracer._append(
-            _PH_COMPLETE, self._name, self._cat, t0,
-            time.perf_counter_ns() - t0, self._args,
+            _PH_COMPLETE, self._name, self._cat, t0, t1 - t0,
+            self._args or None,
         )
         return False
 
@@ -104,11 +169,48 @@ class Tracer:
     """
 
     def __init__(self, capacity: int = 4096, enabled: bool = True) -> None:
+        # `enabled` is what every call site reads (one attribute load); it
+        # only changes in switch() and, for a tracer that follows the
+        # profiler, in refresh() at a root span
         self.enabled = enabled
+        self._switched_on = enabled
+        self._follow_profiler = False  # the default tracer's alone
+        self._annotate = False  # a jax.profiler trace was active at refresh
         self.capacity = capacity
         # (ph, name, cat, start_ns, dur_ns, tid, args)
         self._ring: Deque[Tuple] = deque(maxlen=capacity)
         self.recorded = 0  # total ever recorded (ring drops the oldest)
+        self._cleared = 0  # of those, how many clear() threw away
+        self._local = threading.local()  # per-thread stack of open spans
+
+    # ------------------------------------------------------------------
+    # state
+    # ------------------------------------------------------------------
+
+    def switch(self, on: bool) -> None:
+        """The operator's switch: record from now on (or stop, unless a
+        profile is being taken and this tracer follows the profiler)."""
+        self._switched_on = bool(on)
+        self.refresh()
+
+    def refresh(self) -> bool:
+        """Re-read whether a ``jax.profiler`` trace is active (about 20 ns):
+        spans become annotations while one is, and a tracer that follows
+        the profiler records for as long.  Called by ``root_span`` where no
+        span is open, so once or twice a pool tick; pools read ``enabled``
+        and arm or disarm the bank's phase timers when it changed."""
+        profiling = TraceAnnotation.is_enabled()
+        on = self._switched_on or (self._follow_profiler and profiling)
+        self._annotate = profiling and on
+        self.enabled = on
+        return on
+
+    def _thread_stack(self) -> List[Tuple[str, Optional[int]]]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
 
     # ------------------------------------------------------------------
     # recording
@@ -118,7 +220,18 @@ class Tracer:
         """Context manager timing one span; no-op when disabled."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name, cat, args or None)
+        return _Span(self, name, cat, args)
+
+    def root_span(self, name: str, cat: str = "py", **args):
+        """A span that may be a tick's outermost (``hosted.tick``,
+        ``pool.tick``, ``device.fence``): where no span is open on this
+        thread it first re-reads the profiler's state (:meth:`refresh`),
+        and its annotation carries ``perf_ns``, the clock anchor."""
+        if self.enabled and self._thread_stack():
+            return _Span(self, name, cat, args)
+        if not self.refresh():
+            return _NULL_SPAN
+        return _Span(self, name, cat, args, anchor=True)
 
     def add_complete(self, name: str, start_ns: int, dur_ns: int,
                      cat: str = "native",
@@ -127,6 +240,27 @@ class Tracer:
         (the native timing tail's re-emission path)."""
         if self.enabled:
             self._append(_PH_COMPLETE, name, cat, start_ns, dur_ns, args)
+
+    def add_sequence(self, spans: Sequence[Tuple[str, int]], start_ns: int,
+                     cat: str = "native",
+                     args: Optional[Dict[str, Any]] = None) -> None:
+        """Record ``(name, dur_ns)`` spans laid end to end from
+        ``start_ns``, all with the same ``args`` (one shared dict: never
+        mutated here).  A zero duration takes no event.  How durations
+        that were accumulated, not observed as intervals, are shown — the
+        native bank's per-phase timers."""
+        if not self.enabled:
+            return
+        tid = threading.get_ident()
+        ring = self._ring
+        off = start_ns
+        n = 0
+        for name, dur in spans:
+            if dur:
+                ring.append((_PH_COMPLETE, name, cat, off, dur, tid, args))
+                n += 1
+            off += dur
+        self.recorded += n
 
     def add_instant(self, name: str, cat: str = "py", **args) -> None:
         """Record an instant event (faults, desyncs, evictions)."""
@@ -185,7 +319,7 @@ class Tracer:
 
     @property
     def dropped(self) -> int:
-        return self.recorded - len(self._ring)
+        return self.recorded - self._cleared - len(self._ring)
 
     def events(self, last: int = 0) -> List[Tuple]:
         """The retained raw events, oldest first; ``last`` > 0 keeps only
@@ -196,6 +330,8 @@ class Tracer:
         return out
 
     def clear(self) -> None:
+        """Empty the ring; what is cleared does not count as dropped."""
+        self._cleared += len(self._ring) + self.dropped
         self._ring.clear()
 
     def chrome_trace(self, last: int = 0) -> Dict[str, Any]:
@@ -220,18 +356,10 @@ class Tracer:
         """Per-span-name totals over the window: count and total/max
         duration in microseconds — the quick textual digest chaos runs
         print alongside the full export."""
-        out: Dict[str, Dict[str, float]] = {}
-        for ph, name, _cat, _t0, dur, _tid, _args in self._ring:
-            if ph != _PH_COMPLETE:
-                continue
-            s = out.setdefault(name, {"count": 0, "total_us": 0.0,
-                                      "max_us": 0.0})
-            s["count"] += 1
-            us = dur / 1000.0
-            s["total_us"] += us
-            if us > s["max_us"]:
-                s["max_us"] = us
-        return out
+        return {
+            name: {k: st[k] for k in ("count", "total_us", "max_us")}
+            for name, st in span_stats(self._ring).items()
+        }
 
 
 def chrome_trace_events(events: List[Tuple]) -> List[Dict[str, Any]]:
@@ -341,6 +469,63 @@ def validate_chrome_trace(trace: Any, eps_us: float = 0.001) -> List[str]:
     return problems
 
 
-# The shared disabled tracer: sessions and pools default to this so the
-# hot path pays one attribute load + one no-op call when nobody is tracing.
+def spans_by_tick(events: List[Tuple]) -> Dict[int, List[Tuple]]:
+    """Complete events grouped by the pool tick in their ``args`` (ring
+    order kept); events that name no tick are left out."""
+    ticks: Dict[int, List[Tuple]] = {}
+    for ev in events:
+        args = ev[6]
+        if ev[0] == _PH_COMPLETE and args and args.get("tick") is not None:
+            ticks.setdefault(args["tick"], []).append(ev)
+    return ticks
+
+
+def span_stats(events: Iterable[Tuple]) -> Dict[str, Dict[str, Any]]:
+    """Per span name over ``events``: its parent's name, how many ticks'
+    worth were seen, and the median, worst and total duration (µs) — what
+    a span tree is printed from (``scripts/profile_tick.py``)."""
+    durs: Dict[str, List[int]] = {}
+    parents: Dict[str, Optional[str]] = {}
+    for ph, name, _cat, _t0, dur, _tid, args in events:
+        if ph != _PH_COMPLETE:
+            continue
+        durs.setdefault(name, []).append(dur)
+        parents.setdefault(name, (args or {}).get("parent"))
+    out: Dict[str, Dict[str, Any]] = {}
+    for name, ds in durs.items():
+        ds.sort()
+        out[name] = {
+            "parent": parents[name], "count": len(ds),
+            "p50_us": ds[len(ds) // 2] / 1e3, "max_us": ds[-1] / 1e3,
+            "total_us": sum(ds) / 1e3,
+        }
+    return out
+
+
+def profile_clock_offset_ns(anchors: List[Tuple[int, int]]) -> Optional[int]:
+    """The offset that carries a ``perf_counter_ns`` time onto the
+    profiler's clock (``profile_ns = perf_ns + offset``), from the root
+    spans' annotations: pairs of (the annotation's ``perf_ns`` metadata, its
+    start on the profile's clock).  Every pair is the same constant plus the
+    microsecond between the clock read and the annotation's start; the
+    smallest is the tightest.  Ring events that have no annotation (the
+    native bank's phases) are placed in a profile with it."""
+    if not anchors:
+        return None
+    return min(int(start) - int(perf) for perf, start in anchors)
+
+
+# The hard no-op: for those who pass it explicitly.  It never wakes.
 NULL_TRACER = Tracer(capacity=1, enabled=False)
+
+# The process's tracer (as default_registry() is for counters): what the
+# hosted path uses when handed none.  Off, a span is one attribute load and
+# the shared no-op context manager; it wakes while switched on or while a
+# jax.profiler trace is active.
+_DEFAULT_TRACER = Tracer(capacity=4096, enabled=False)
+_DEFAULT_TRACER._follow_profiler = True
+
+
+def default_tracer() -> Tracer:
+    """The process-wide tracer of the served path."""
+    return _DEFAULT_TRACER

@@ -150,12 +150,12 @@ from ..obs.recorder import (
     FlightRecorder,
 )
 from ..obs.registry import Registry, default_registry
-from ..obs.trace import NULL_TRACER, Tracer
+from ..obs.trace import Tracer, default_tracer
 from ..obs.forensics import DesyncReport, build_desync_report
 # timeline event name (DESIGN.md §28) — aliased: the flight recorder
 # above already owns the bare EV_* namespace in this module
 from ..obs.timeline import EV_DEMOTE_LOCKSTEP as TL_DEMOTE_LOCKSTEP
-from ..utils.tracing import get_logger, trace_span
+from ..utils.tracing import get_logger
 from ..sessions.p2p import (
     MAX_EVENT_QUEUE_SIZE,
     MIN_RECOMMENDATION,
@@ -695,7 +695,6 @@ class HostSessionPool:
         )
         self._drain_hist = [0] * (len(_native.IO_BATCH_BUCKETS) + 1)
         self.drain_crossings = 0  # ggrs_net_recv_table invocations
-        self.drain_ns = 0  # wall ns in _drain_inbound (profiling split)
         self._send_flags: List[int] = []  # per-slot NET_SEND_FIELDS flags
         self._gso_totals = {"gso_sends": 0, "gso_segments": 0}
         self._gro_on = False  # UDP_GRO armed on >=1 covered hub (§23d)
@@ -735,8 +734,6 @@ class HostSessionPool:
         # harvest); _vectorized: classify slots from that table and
         # fast-path the quiet ones (GGRS_TPU_NO_FASTPATH=1 forces the
         # legacy per-slot parse — the parity fuzz's reference leg).
-        # Tracing uses the legacy parse too: the per-slot spans ARE the
-        # point of a traced tick.
         self._has_hdr = False
         self._hdr_stride = 0
         self._vectorized = False
@@ -781,13 +778,17 @@ class HostSessionPool:
         self._flight_capacity = flight_recorder_size
         self._recorders: List[Optional[FlightRecorder]] = []
         # ---- tracing (DESIGN.md §14) ----
-        # tracer: tick -> crossing -> slot spans on the Python side; when
-        # the library carries ggrs_bank_set_timing, the native per-phase
-        # timings ride the tick output's timing tail (zero extra crossings)
-        # and are re-emitted as child spans of the crossing.  The shared
-        # NULL_TRACER default keeps the hot path at one no-op call per tick.
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        # tracer: stage / tick -> build_cmd, crossing, decode, supervise
+        # spans on the Python side; when the library carries
+        # ggrs_bank_set_timing, the native per-phase timings ride the tick
+        # output's timing tail (zero extra crossings) and are re-emitted as
+        # child spans of the crossing.  Handed none, the pool uses the
+        # process's default tracer: off, a span is one attribute load and a
+        # shared no-op; it wakes while switched on or under jax.profiler.
+        self.tracer = tracer if tracer is not None else default_tracer()
         self._trace_native = False  # timing tail armed on the loaded bank
+        self._has_timing = False  # the library carries ggrs_bank_set_timing
+        self._stage_end_ns = 0  # traced stage_inputs: end of the native call
         self._phase_totals: Optional[Tuple[int, Dict[str, int]]] = None
         self._last_phase_ns: Optional[Dict[str, int]] = None
         # /healthz source: last completed pool tick on time.monotonic()
@@ -1164,12 +1165,13 @@ class HostSessionPool:
         if self._has_req:
             self._req_stride = int(lib.ggrs_bank_req_stride())
             self._has_stage = True
-        # arm the in-crossing phase timers only when someone is tracing:
+        # arm the in-crossing phase timers only while someone is tracing:
         # disarmed, the tick performs zero clock reads and emits the exact
-        # pre-timing output layout (the on/off wire pin rides on this)
-        if self.tracer.enabled and hasattr(lib, "ggrs_bank_set_timing"):
-            lib.ggrs_bank_set_timing(self._bank, 1)
-            self._trace_native = True
+        # pre-timing output layout (the on/off wire pin rides on this).
+        # advance_all re-arms on the tracer's transitions, never per tick.
+        self._has_timing = hasattr(lib, "ggrs_bank_set_timing")
+        if self.tracer.enabled:
+            self._arm_timing(True)
         from ..core.types import Remote, Spectator
 
         for builder, socket in self._builders:
@@ -1932,6 +1934,16 @@ class HostSessionPool:
         staging for that slot (both sides discard it in lockstep)."""
         if not self._finalized:
             self._finalize()
+        tracer = self.tracer
+        if not tracer.enabled:
+            self._stage_inputs(items)
+            return
+        if not hasattr(items, "__len__"):
+            items = list(items)
+        with tracer.span("pool.stage", items=len(items)):
+            self._stage_inputs(items)
+
+    def _stage_inputs(self, items) -> None:
         if not (self._native_active and self._has_stage):
             stagers = self._stagers
             for index, handle, value in items:
@@ -1984,6 +1996,9 @@ class HostSessionPool:
         rc = self._lib.ggrs_bank_stage_inputs(
             self._bank, desc.ctypes.data, n, payload, len(payload)
         )
+        if self._trace_native:
+            # where the bank's own staging time (next tick's tail) ends
+            self._stage_end_ns = time.perf_counter_ns()
         if rc < 0:
             # should be unreachable after the validation above (a native
             # reject means this builder drifted from the bank): drop the
@@ -2011,10 +2026,127 @@ class HostSessionPool:
         self._check_valid()
         self._tick_no += 1
         self._m_ticks.inc()
+        with self.tracer.root_span("pool.tick", tick=self._tick_no):
+            return self._advance_all_native()
+
+    def _arm_timing(self, on: bool) -> None:
+        """Arm or disarm the bank's in-crossing phase timers: one
+        ``ggrs_bank_set_timing`` call where the tracer's state changed."""
+        if self._has_timing and self._native_active:
+            self._lib.ggrs_bank_set_timing(self._bank, int(on))
+            self._trace_native = on
+
+    def _advance_all_native(self) -> List[List[GgrsRequest]]:
         tracer = self.tracer
         tracing = tracer.enabled
-        t_tick = tracer.now_ns() if tracing else 0
+        if tracing != self._trace_native:
+            self._arm_timing(tracing)
+        with tracer.span("pool.build_cmd") as span:
+            cmd, ticked = self._build_cmd()
+            if tracing:
+                span.set(cmd_bytes=len(cmd))
 
+        self.crossings += 1
+        self._m_cross_tick.inc()
+        # the pump is the tick crossing plus native socket I/O for
+        # attached slots — still exactly ONE crossing per pool tick
+        crossing = (
+            self._lib.ggrs_bank_pump if self._use_pump
+            else self._lib.ggrs_bank_tick
+        )
+        with tracer.span("bank.crossing", cat="native") as span:
+            t_cross = tracer.now_ns() if tracing else 0
+            rc = crossing(
+                self._bank, self._clock(), cmd, len(cmd),
+                self._out_buf, len(self._out_buf),
+                ctypes.byref(self._out_len),
+            )
+            if rc == _native.BANK_ERR_BUFFER_TOO_SMALL:
+                # kErrBufferTooSmall: the tick RAN and its output is
+                # retained natively — grow and fetch (the one case that
+                # costs a second crossing, e.g. a stalled peer's
+                # whole-window volley)
+                self._out_buf = ctypes.create_string_buffer(
+                    max(self._out_len.value, 2 * len(self._out_buf))
+                )
+                rc = self._lib.ggrs_bank_fetch_out(
+                    self._bank, self._out_buf, len(self._out_buf),
+                    ctypes.byref(self._out_len),
+                )
+            if tracing:
+                dur_cross = tracer.now_ns() - t_cross
+                span.set(out_bytes=self._out_len.value)
+        if tracing and self._trace_native and rc == 0:
+            self._trace_phases(t_cross, dur_cross)
+        if rc != 0:
+            # the only whole-bank failure left is a malformed command stream
+            # (a bug in THIS builder, no per-session blame possible)
+            self._invalid = f"ggrs_bank_tick failed: {rc}"
+            raise RuntimeError(self._invalid)
+        # decode: the descriptor plane's lazy RequestPlan by default
+        # (DESIGN.md §21 — classification AND request programs read from
+        # the two flat tables, request objects only materialized on
+        # demand); the legacy sequential parse on pre-descriptor libraries
+        # and under GGRS_TPU_NO_FASTPATH (the parity fuzz's reference leg).
+        # A traced pool decodes like any other: tracing never picks the path
+        if self._vectorized and self._has_req:
+            with tracer.span("pool.decode") as span:
+                fast0 = self.fast_slot_ticks
+                request_lists, retire_mask = self._parse_output_plan(ticked)
+                if tracing:
+                    span.set(
+                        fast=self.fast_slot_ticks - fast0,
+                        eager=len(request_lists.eager_rows),
+                        resim=len(request_lists.resim_rows),
+                        save_only=len(request_lists.save_only_rows),
+                        slots=len(self._mirrors),
+                    )
+            self._plan = request_lists
+        else:
+            request_lists = self._parse_output(ticked)
+            retire_mask = None
+            self._plan = None
+        with tracer.span("pool.supervise"):
+            self._supervise(request_lists, retire_mask)
+        self.last_tick_at = time.monotonic()
+        return request_lists
+
+    def _trace_phases(self, t_cross: int, dur: int) -> None:
+        """The native per-phase timings as children of the crossing span:
+        durations the bank accumulated across its slots, laid end to end
+        from the crossing's own entry time on the bank's clock
+        (``steady_clock``, which is ``perf_counter``'s here; were it not,
+        the entry would fall outside the measured window and they start at
+        the window's start).  The gap to the crossing span is ctypes
+        overhead."""
+        tracer = self.tracer
+        tick = self._tick_no
+        phases, t0 = self._parse_timing_tail()
+        # staging accrued OUTSIDE the tick window (the stage_inputs
+        # crossings since the last tick): a child of pool.stage ending
+        # where its native call returned, never nested in the crossing —
+        # the in-crossing phases still sum to the measured crossing time
+        in_crossing = [(f"bank.{name}", ns) for name, ns in phases
+                       if name != "staging"]
+        busy = sum(ns for _, ns in in_crossing)
+        tracer.add_sequence(
+            in_crossing,
+            t0 if t_cross <= t0 <= t_cross + dur - busy else t_cross,
+            cat="native.phase",
+            args={"parent": "bank.crossing", "tick": tick},
+        )
+        self._last_phase_ns = by_name = dict(phases)
+        staging = by_name.get("staging")
+        if staging:
+            end = self._stage_end_ns or t_cross
+            tracer.add_complete(
+                "bank.staging", end - staging, staging, cat="native.phase",
+                args={"parent": "pool.stage", "tick": tick},
+            )
+
+    def _build_cmd(self) -> Tuple[bytes, List[bool]]:
+        """The tick's command stream and which slots the bank steps: input
+        validation, the batched inbound drain, the per-slot sections."""
         pack = struct.pack
         # validate EVERY bank-resident session's staged inputs before any
         # destructive step (ctrl-op swap, socket drain): raising mid-build
@@ -2066,9 +2198,8 @@ class HostSessionPool:
         # fd-backed socket BEFORE the tick snapshot — a fatal recv errno
         # faults the owning slot(s) here, so they skip this tick cleanly
         if self._drain_ok:
-            _dt0 = time.perf_counter_ns()
-            drained = self._drain_inbound()
-            self.drain_ns += time.perf_counter_ns() - _dt0
+            with self.tracer.span("pool.drain"):
+                drained = self._drain_inbound()
         else:
             drained = None
         # snapshot which slots the bank steps this tick: the parse below
@@ -2130,104 +2261,26 @@ class HostSessionPool:
                 for sp_idx, data in spec_datagrams:
                     cmd_parts.append(pack("<HI", sp_idx, len(data)))
                     cmd_parts.append(data)
-        cmd = b"".join(cmd_parts)
+        return b"".join(cmd_parts), ticked
 
-        self.crossings += 1
-        self._m_cross_tick.inc()
-        t_cross = tracer.now_ns() if tracing else 0
-        # the pump is the tick crossing plus native socket I/O for
-        # attached slots — still exactly ONE crossing per pool tick
-        crossing = (
-            self._lib.ggrs_bank_pump if self._use_pump
-            else self._lib.ggrs_bank_tick
-        )
-        rc = crossing(
-            self._bank, self._clock(), cmd, len(cmd),
-            self._out_buf, len(self._out_buf), ctypes.byref(self._out_len),
-        )
-        if rc == _native.BANK_ERR_BUFFER_TOO_SMALL:
-            # kErrBufferTooSmall: the tick RAN and its output is
-            # retained natively — grow and fetch (the one case that costs a
-            # second crossing, e.g. a stalled peer's whole-window volley)
-            self._out_buf = ctypes.create_string_buffer(
-                max(self._out_len.value, 2 * len(self._out_buf))
-            )
-            rc = self._lib.ggrs_bank_fetch_out(
-                self._bank, self._out_buf, len(self._out_buf),
-                ctypes.byref(self._out_len),
-            )
-        if tracing:
-            # the crossing span, then the native per-phase timings laid
-            # end-to-end inside it (they were measured inside this very
-            # window, so they nest under it and sum to the in-crossing
-            # time; the gap to the crossing span is pure ctypes overhead)
-            dur = tracer.now_ns() - t_cross
-            tracer.add_complete("bank.crossing", t_cross, dur, cat="native",
-                                args={"tick": self._tick_no})
-            if self._trace_native and rc == 0:
-                off = t_cross
-                phases = self._parse_timing_tail()
-                for name, ns in phases:
-                    if name == "staging":
-                        # staging accrued OUTSIDE the tick window (the
-                        # stage_inputs crossings since the last tick): a
-                        # sibling span ending at the crossing start, never
-                        # nested inside it — the in-crossing phases still
-                        # sum to the measured crossing time
-                        if ns:
-                            tracer.add_complete(
-                                "bank.staging", t_cross - ns, ns,
-                                cat="native",
-                            )
-                        continue
-                    if ns:
-                        tracer.add_complete(
-                            f"bank.{name}", off, ns, cat="native"
-                        )
-                    off += ns
-                self._last_phase_ns = dict(phases)
-        if rc != 0:
-            # the only whole-bank failure left is a malformed command stream
-            # (a bug in THIS builder, no per-session blame possible)
-            self._invalid = f"ggrs_bank_tick failed: {rc}"
-            raise RuntimeError(self._invalid)
-        # decode: the descriptor plane's lazy RequestPlan by default
-        # (DESIGN.md §21 — classification AND request programs read from
-        # the two flat tables, request objects only materialized on
-        # demand); the legacy sequential parse under tracing (the
-        # per-slot spans ARE the point), on pre-descriptor libraries, and
-        # under GGRS_TPU_NO_FASTPATH (the parity fuzz's reference leg)
-        if self._vectorized and self._has_req and not tracing:
-            request_lists, retire_mask = self._parse_output_plan(ticked)
-            self._plan = request_lists
-        else:
-            request_lists = self._parse_output(ticked)
-            retire_mask = None
-            self._plan = None
-        self._supervise(request_lists, retire_mask)
-        if tracing:
-            tracer.add_complete("pool.tick", t_tick,
-                                tracer.now_ns() - t_tick, cat="py")
-        self.last_tick_at = time.monotonic()
-        return request_lists
-
-    def _parse_timing_tail(self) -> List[Tuple[str, int]]:
+    def _parse_timing_tail(self) -> Tuple[List[Tuple[str, int]], int]:
         """The tick output's timing tail: ``(phase, ns)`` pairs in bank
-        order.  The count byte sits LAST so the tail parses from the end
-        of the buffer, independent of the session records before it."""
+        order, and the crossing's entry time on the bank's clock.  The
+        count byte sits LAST so the tail parses from the end of the
+        buffer, independent of the session records before it."""
         end = self._out_len.value
         n_ph = self._out_buf[end - 1][0]
         vals = struct.unpack_from(
-            f"<{n_ph}Q", self._out_buf, end - 1 - 8 * n_ph
+            f"<{n_ph + 1}Q", self._out_buf, end - 9 - 8 * n_ph
         )
-        return list(zip(_phase_names(n_ph), vals))
+        return list(zip(_phase_names(n_ph), vals[:n_ph])), vals[n_ph]
 
     def _parse_output(self, ticked: List[bool]) -> List[List[GgrsRequest]]:
         """Legacy sequential parse: every slot's body record, in order.
         The reference decoder (the vectorized path is pinned
-        bit-identical to it by tests/test_policy_plane.py) and the
-        tracing-mode parse — per-slot spans are the point of a traced
-        tick."""
+        bit-identical to it by tests/test_policy_plane.py); it runs under
+        ``GGRS_TPU_NO_FASTPATH`` and on pre-descriptor libraries, and only
+        there does a traced tick carry per-slot ``pool.slot`` spans."""
         buf = memoryview(self._out_buf).cast("B")[: self._out_len.value]
         n = len(self._mirrors)
         pos = n * (
@@ -2238,12 +2291,10 @@ class HostSessionPool:
         tracing = tracer.enabled
         # parallel decode plane (§24): with the header table's rec_len
         # jump chain every slot's byte range is known up front, so the
-        # NO_FASTPATH/legacy path fans ALL slots across the DecodePool.
-        # A TRACED pool stays on the interleaved reference decoder —
-        # per-slot spans are the point of tracing, and fanning the byte
-        # walk out would destroy that attribution.
+        # NO_FASTPATH/legacy path fans ALL slots across the DecodePool,
+        # traced or not (a slot's span then times its apply step).
         decs = None
-        if not tracing and self._has_hdr and n > 1:
+        if self._has_hdr and n > 1:
             hdr = np.frombuffer(self._out_buf, dtype=_HDR_DTYPE, count=n)
             offs = np.empty(n, np.int64)
             offs[0] = pos
@@ -2268,7 +2319,9 @@ class HostSessionPool:
             if tracing:
                 tracer.add_complete(
                     "pool.slot", t_slot, tracer.now_ns() - t_slot,
-                    cat="py", args={"slot": idx, "frame": current},
+                    cat="py", args={"parent": "pool.tick",
+                                    "tick": self._tick_no,
+                                    "slot": idx, "frame": current},
                 )
         return request_lists
 
@@ -3423,8 +3476,12 @@ class HostSessionPool:
         synchronized) still propagate to the caller."""
         self._tick_no += 1
         self._m_ticks.inc()
-        tracer = self.tracer
-        t_tick = tracer.now_ns() if tracer.enabled else 0
+        with self.tracer.root_span("pool.tick", tick=self._tick_no):
+            out = self._advance_sessions()
+        self.last_tick_at = time.monotonic()
+        return out
+
+    def _advance_sessions(self) -> List[List[GgrsRequest]]:
         # validate every live session's preconditions BEFORE any session
         # advances: a contract raise mid-loop would discard earlier
         # sessions' already-generated request lists (the native path makes
@@ -3471,10 +3528,6 @@ class HostSessionPool:
                 self._maybe_retire(i, s._remote_endpoints and all(
                     not ep.is_running() for ep in s._remote_endpoints
                 ))
-        if tracer.enabled:
-            tracer.add_complete("pool.tick", t_tick,
-                                tracer.now_ns() - t_tick, cat="py")
-        self.last_tick_at = time.monotonic()
         return out
 
     def _maybe_retire(self, index: int, match_over) -> None:
@@ -4680,7 +4733,7 @@ class HostSessionPool:
         steady-state allocation) — copy what you need to keep."""
         if not self._finalized:
             self._finalize()
-        with trace_span("ggrs.obs.scrape"), self.tracer.span("pool.scrape"):
+        with self.tracer.root_span("pool.scrape"):
             if self._native_active:
                 stats = self._bank_stats()
             else:

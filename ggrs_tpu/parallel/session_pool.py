@@ -49,7 +49,7 @@ from ..core.types import (
     SaveGameState,
 )
 from ..obs.registry import default_registry
-from ..obs.trace import NULL_TRACER
+from ..obs.trace import default_tracer
 from ..ops.checksum import CHECKSUM_LANES, checksum_device, checksum_to_u128
 
 # obs (DESIGN.md §12): device-dispatch accounting for the pooled executor —
@@ -218,9 +218,11 @@ class BatchedRequestExecutor:
             )
         self._input_dtype: Optional[np.dtype] = None
         self._input_shape: Optional[Tuple[int, ...]] = None
-        # tracing (DESIGN.md §14): device dispatch + fence spans; assign a
-        # live Tracer (or let HostedPool share the host pool's) to light up
-        self.tracer = NULL_TRACER
+        # tracing (DESIGN.md §14): device dispatch (fill + launch) and
+        # fence spans on the process's default tracer, as the host pool's
+        # are; assign another Tracer (or NULL_TRACER) to change that
+        self.tracer = default_tracer()
+        self._dispatched_tick = 0  # pool tick of the newest dispatch
         # set on a failed run(): once a tick aborts mid-parse, fulfilled
         # cells reference slots that were never written — every later use
         # must fail loudly instead of serving stale state
@@ -246,22 +248,30 @@ class BatchedRequestExecutor:
             save_mask: jax.Array,  # [max_burst]
             save_frame: jax.Array,  # [max_burst]
         ):
+            # the scopes name the program's parts in a device profile
+            # (metadata only: the lowered operations are the same)
             def write(ring, frame, st, pred):
-                cs = checksum_device(st) if with_checksums else zero_cs
+                with jax.named_scope("digest"):
+                    cs = checksum_device(st) if with_checksums else zero_cs
                 return dring.save_where(ring, frame, st, cs, pred)
 
-            ring = write(ring, pre_frame, live, pre_save)
-            st = _tree_where(do_load, dring.load(ring, load_frame), live)
+            with jax.named_scope("ring.pre_save"):
+                ring = write(ring, pre_frame, live, pre_save)
+            with jax.named_scope("ring.load"):
+                st = _tree_where(do_load, dring.load(ring, load_frame), live)
             # sparse saving can save the just-loaded state before any advance
             # (reference: p2p_session.rs:666-672 — the min_confirmed save)
-            ring = write(ring, postload_frame, st, postload_save)
+            with jax.named_scope("ring.save"):
+                ring = write(ring, postload_frame, st, postload_save)
 
             def step(carry, xs):
                 st, ring = carry
                 j, inp, smask, sframe = xs
                 act = j < n_adv
-                st = _tree_where(act, advance(st, inp), st)
-                ring = write(ring, sframe, st, act & smask)
+                with jax.named_scope("advance"):
+                    st = _tree_where(act, advance(st, inp), st)
+                with jax.named_scope("ring.save"):
+                    ring = write(ring, sframe, st, act & smask)
                 return (st, ring), None
 
             (st, ring), _ = jax.lax.scan(
@@ -592,7 +602,6 @@ class BatchedRequestExecutor:
         rows = plan.quiet_rows
         eager = list(plan.eager_rows)
         vector = self._raw_inputs is not None and plan.uniform
-        desc = self._reset_desc()
         any_work = bool(
             rows.size or plan.resim_rows or plan.save_only_rows
         ) or any(plan.lists[b] for b in eager)
@@ -600,7 +609,8 @@ class BatchedRequestExecutor:
             _OBS_EMPTY_TICKS.inc()
             return
         try:
-            with self.tracer.span("device.dispatch"):
+            with self.tracer.span("device.fill") as fill:
+                desc = self._reset_desc()
                 if vector and rows.size:
                     frames = plan.quiet_frames
                     desc["pre_save"][rows] = True
@@ -633,13 +643,27 @@ class BatchedRequestExecutor:
                     reqs = plan[b]
                     if reqs:
                         self._parse(b, reqs, desc)
-                _OBS_DISPATCHES.inc()
-                _OBS_ROLLBACK_LOADS.inc(int(desc["do_load"].sum()))
-                _OBS_BURST_DEPTH.observe(int(desc["n_adv"].max()))
-                self._carry = self._tick(self._carry, desc)
+                self._count_dispatch(desc, fill)
+            self._launch(desc)
         except BaseException as e:  # incl. KeyboardInterrupt mid-fill
             self._invalid = f"{type(e).__name__}: {e}"
             raise
+
+    def _count_dispatch(self, desc: Dict[str, Any], fill) -> None:
+        """The filled descriptor's counts, on the registry and on the
+        ``device.fill`` span that ends here."""
+        loads = int(desc["do_load"].sum())
+        max_burst = int(desc["n_adv"].max())
+        _OBS_DISPATCHES.inc()
+        _OBS_ROLLBACK_LOADS.inc(loads)
+        _OBS_BURST_DEPTH.observe(max_burst)
+        fill.set(loads=loads, max_burst=max_burst)
+
+    def _launch(self, desc: Dict[str, Any]) -> None:
+        """The call of the tick program: transfer of the descriptors and
+        enqueue; in a closed loop the runtime's back-pressure too."""
+        with self.tracer.span("device.launch"):
+            self._carry = self._tick(self._carry, desc)
 
     def run(self, request_lists: Sequence[List[GgrsRequest]]) -> None:
         """Fulfill all B sessions' request lists — ONE device dispatch (zero
@@ -655,13 +679,20 @@ class BatchedRequestExecutor:
                 f"run() got {len(request_lists)} request lists for a pool of "
                 f"{self.batch_size} sessions"
             )
-        if getattr(request_lists, "quiet_rows", None) is not None:
-            self._run_plan(request_lists)
-            return
+        tick = getattr(request_lists, "tick_no", None)
+        self._dispatched_tick = (
+            tick if tick is not None else self._dispatched_tick + 1
+        )
+        with self.tracer.span("device.dispatch"):
+            if getattr(request_lists, "quiet_rows", None) is not None:
+                self._run_plan(request_lists)
+            else:
+                self._run_lists(request_lists)
+
+    def _run_lists(self, request_lists: Sequence[List[GgrsRequest]]) -> None:
         if all(not reqs for reqs in request_lists):
             _OBS_EMPTY_TICKS.inc()
             return
-        desc = self._reset_desc()
         # parse fulfills cells eagerly (the ring-capacity guard needs this
         # tick's pre-saves visible in device order — see _parse); if any
         # session's list fails to parse, or the dispatch itself fails,
@@ -669,14 +700,13 @@ class BatchedRequestExecutor:
         # tick never wrote, so the pool is unusable: poison it loudly rather
         # than let a caller that caught the error keep running on stale loads
         try:
-            with self.tracer.span("device.dispatch"):
+            with self.tracer.span("device.fill") as fill:
+                desc = self._reset_desc()
                 for b, reqs in enumerate(request_lists):
                     if reqs:
                         self._parse(b, reqs, desc)
-                _OBS_DISPATCHES.inc()
-                _OBS_ROLLBACK_LOADS.inc(int(desc["do_load"].sum()))
-                _OBS_BURST_DEPTH.observe(int(desc["n_adv"].max()))
-                self._carry = self._tick(self._carry, desc)
+                self._count_dispatch(desc, fill)
+            self._launch(desc)
         except BaseException as e:  # incl. KeyboardInterrupt mid-parse
             self._invalid = f"{type(e).__name__}: {e}"
             raise
@@ -744,7 +774,7 @@ class BatchedRequestExecutor:
         return checksum_to_u128(lanes)
 
     def block_until_ready(self) -> None:
-        with self.tracer.span("device.fence"):
+        with self.tracer.root_span("device.fence", tick=self._dispatched_tick):
             jax.block_until_ready(self._carry)
 
 
@@ -771,27 +801,24 @@ class HostedPool:
             )
         self.host = host_pool
         self.executor = executor
-        # one trace per hosted pool: the device dispatch/fence spans join
-        # the host pool's tick -> crossing -> slot timeline
-        host_tracer = getattr(host_pool, "tracer", None)
-        if (
-            host_tracer is not None and host_tracer.enabled
-            and not executor.tracer.enabled
-        ):
-            executor.tracer = host_tracer
 
     def tick(self, local_inputs: Sequence[Tuple[int, int, Any]]) -> None:
         """One pool tick: stage ``(session_index, handle, value)`` local
         inputs (ONE batched native call on the descriptor plane, §21),
         advance every session, fulfill every request list."""
-        stage = getattr(self.host, "stage_inputs", None)
-        if stage is not None:
-            stage(local_inputs)
-        else:
-            add = self.host.add_local_input
-            for index, handle, value in local_inputs:
-                add(index, handle, value)
-        self.executor.run(self.host.advance_all())
+        host = self.host
+        # the root span of the served tick (DESIGN.md §14): the host pool's
+        # and the executor's spans nest in it when they share its tracer,
+        # which the process's default tracer makes so
+        with host.tracer.root_span("hosted.tick", tick=host._tick_no + 1):
+            stage = getattr(host, "stage_inputs", None)
+            if stage is not None:
+                stage(local_inputs)
+            else:
+                add = host.add_local_input
+                for index, handle, value in local_inputs:
+                    add(index, handle, value)
+            self.executor.run(host.advance_all())
 
     def block_until_ready(self) -> None:
         self.executor.block_until_ready()

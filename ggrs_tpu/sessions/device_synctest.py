@@ -25,9 +25,9 @@ import jax
 import jax.numpy as jnp
 
 from ..core.errors import InvalidRequest, MismatchedChecksum
+from ..obs.trace import default_tracer
 from ..ops.checksum import checksum_device
 from ..ops.replay import ReplayPrograms, build_replay_programs
-from ..utils.tracing import trace_span
 
 _I32_MAX = np.iinfo(np.int32).max
 
@@ -133,7 +133,7 @@ class DeviceSyncTestSession:
         n_warm = self._programs.split_at_warmup(self._ticks_run, n)
         if n_warm:
             head = jax.tree_util.tree_map(lambda a: a[:n_warm], inputs)
-            with trace_span("ggrs:synctest_warmup"):
+            with default_tracer().root_span("synctest.warmup", ticks=n_warm):
                 self._carry = self._programs.run_warmup(self._carry, head)
         if n > n_warm:
             # avoid a per-call device slice when the whole batch is steady
@@ -142,7 +142,7 @@ class DeviceSyncTestSession:
                 if n_warm == 0
                 else jax.tree_util.tree_map(lambda a: a[n_warm:], inputs)
             )
-            with trace_span("ggrs:synctest_steady"):
+            with default_tracer().root_span("synctest.steady", ticks=n - n_warm):
                 self._carry = self._programs.run_steady(self._carry, tail)
         self._ticks_run += n
         if check:

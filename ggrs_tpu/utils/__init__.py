@@ -11,12 +11,11 @@ save/load ring lacks (device sessions expose it as
 """
 
 from .checkpoint import load_pytree, save_pytree
-from .tracing import enable_tracing, get_logger, trace_span
+from .tracing import enable_tracing, get_logger
 
 __all__ = [
     "enable_tracing",
     "get_logger",
     "load_pytree",
     "save_pytree",
-    "trace_span",
 ]

@@ -4,18 +4,13 @@ Host-side events (protocol state changes, rollback decisions, oversized
 packets) log through the ``ggrs_tpu`` logger hierarchy — the analog of the
 reference's ``tracing`` crate spans (e.g. rollback decisions at
 /root/reference/src/sessions/p2p_session.rs:679-682, packet warnings at
-/root/reference/src/network/udp_socket.rs:54-59).  Device dispatches can be
-wrapped in ``trace_span`` so they appear as named ranges in ``jax.profiler``
-traces (TensorBoard / Perfetto) without any cost when profiling is off.
+/root/reference/src/network/udp_socket.rs:54-59).  Timed spans, and their
+place in ``jax.profiler`` traces, are ``ggrs_tpu.obs.trace``'s.
 """
 
 from __future__ import annotations
 
-import contextlib
 import logging
-from typing import Iterator
-
-from jax.profiler import TraceAnnotation
 
 _ROOT = "ggrs_tpu"
 
@@ -36,10 +31,3 @@ def enable_tracing(level: int = logging.DEBUG) -> None:
         )
         logger.addHandler(handler)
     logger.setLevel(level)
-
-
-@contextlib.contextmanager
-def trace_span(name: str) -> Iterator[None]:
-    """Named range in jax profiler traces; no-op overhead when not profiling."""
-    with TraceAnnotation(name):
-        yield

@@ -1640,7 +1640,9 @@ int ggrs_bank_set_timing(void* ptr, int enabled) {
 //     players * u8 blank_flag, players * input_size bytes  [journal tap]
 // After the last session record, ONLY when ggrs_bank_set_timing armed the
 // phase timers (DESIGN.md §14):
-//   kNumPhases * u64 phase_ns, u8 n_phases   [timing tail; count byte
+//   kNumPhases * u64 phase_ns, u64 tick_t0_ns, u8 n_phases   [timing tail:
+//     tick_t0_ns is this crossing's entry on steady_clock, which places
+//     the phases on the tracer's timeline; count byte
 //     last so the caller parses it from the END of the buffer]
 // Returns 0, kErrBufferTooSmall (retry with a bigger out), or kBankErrCmd
 // (malformed command stream — the one remaining whole-bank failure).
@@ -2147,8 +2149,9 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
   if (pt.on) {
     // timing tail (count byte LAST so Python can parse from the end
     // without knowing the phase count up front): kNumPhases u64 ns then
-    // u8 kNumPhases.  "other" closes the books: phases sum exactly to the
-    // measured in-crossing time.
+    // the crossing's entry time (steady_clock ns: the tracer places the
+    // phases by it), then u8 kNumPhases.  "other" closes the books: phases
+    // sum exactly to the measured in-crossing time.
     uint64_t total = mono_ns() - tick_t0;
     uint64_t sum = 0;
     for (int i = 0; i < kPhOther; ++i) sum += pt.ns[i];
@@ -2163,6 +2166,7 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
       bank->phase_total[i] += pt.ns[i];
       put_u64(&bank->out, pt.ns[i]);
     }
+    put_u64(&bank->out, tick_t0);
     put_u8(&bank->out, static_cast<uint8_t>(kNumPhases));
   }
   if (bank->out.size() > out_cap) {

@@ -131,7 +131,7 @@ from ggrs_tpu.obs.timeline import (  # noqa: E402
 
 def _fleet_trace_artifact(artifact_dir, name: str, tracer):
     """Write one scenario's Perfetto export beside its JSON artifact and
-    return ``{"trace_path":..., "trace_spans":..., "trace_problems":...}``
+    return ``{"trace_path":..., "trace_events":..., "trace_problems":...}``
     for embedding (DESIGN.md §18).  The export is schema-validated here
     (eps widened for imported cross-process spans) so a torn trace shows
     up in CI, not in a ui.perfetto.dev tab weeks later."""
@@ -150,7 +150,7 @@ def _fleet_trace_artifact(artifact_dir, name: str, tracer):
               "schema-valid)")
     return {
         "trace_path": str(path),
-        "trace_spans": len(trace["traceEvents"]),
+        "trace_events": len(trace["traceEvents"]),
         "trace_problems": problems[:8],
     }
 

@@ -154,7 +154,8 @@ class TestTracingObservational:
         """The whole tracing layer — Python spans, the armed native phase
         timers, the timing tail — must not move a wire byte or add a tick
         crossing: identical fault-injected runs with the tracer on vs
-        off."""
+        off — and it must not choose the path: a traced pool decodes through
+        the descriptor plane (``plan_ticks``) like the untraced one."""
         on = drive_chaos(160, n_matches=2, seed=11, metrics=Registry(),
                          tracer=Tracer(), inject=_inject_at_60)
         off = drive_chaos(160, n_matches=2, seed=11, metrics=Registry(),
@@ -172,6 +173,8 @@ class TestTracingObservational:
         # scrape budget untouched (one stats crossing from the final
         # scrape, one harvest for the eviction — same as the off leg)
         assert on["pool"].crossings == off["pool"].crossings == 160
+        assert on["pool"].plan_ticks == off["pool"].plan_ticks == 160
+        assert on["pool"].fast_slot_ticks == off["pool"].fast_slot_ticks > 0
         assert on["pool"].harvests == off["pool"].harvests
         assert on["pool"].stat_crossings == off["pool"].stat_crossings
 
@@ -188,7 +191,14 @@ class TestTracingObservational:
         assert crossings, "no crossing spans recorded"
         phase_names = {f"bank.{n}" for n in _native.BANK_PHASES}
         seen = {e[1] for e in events}
-        assert "pool.tick" in seen and "pool.slot" in seen
+        assert "pool.tick" in seen and "pool.decode" in seen
+        # per-slot spans belong to the legacy decoder, which a traced pool
+        # no longer takes: the plan decode carries the counts instead
+        assert "pool.slot" not in seen
+        decode = [e for e in events if e[1] == "pool.decode"][-1]
+        assert decode[6]["slots"] == len(run["states"])
+        assert decode[6]["parent"] == "pool.tick"
+        assert 0 < decode[6]["fast"] <= decode[6]["slots"]
         assert seen & phase_names, "no native phase spans recorded"
         # last tick: phases nest inside the last crossing and sum <= dur
         _, _, _, c_start, c_dur, _, _ = crossings[-1]
