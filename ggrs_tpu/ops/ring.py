@@ -3,10 +3,21 @@
 The reference keeps ``max_prediction + 1`` saved states in a ring of
 host-memory cells indexed ``frame % len`` (/root/reference/src/sync_layer.rs:144-166).
 The TPU equivalent stacks every saved state into one pytree with a leading ring
-axis that lives in HBM for the whole session: *save* is a
-``dynamic_update_index_in_dim`` write, *load* is a gather, and neither moves a
-byte to the host.  Checksums for each slot are kept in a parallel ``(R, 4)``
-uint32 array so desync/synctest comparisons are device-side too.
+axis that lives in HBM for the whole session, and neither *save* nor *load*
+moves a byte to the host.  Checksums for each slot are kept in a parallel
+``(R, 4)`` uint32 array so desync/synctest comparisons are device-side too.
+
+Two forms of write, by who indexes (docs/DESIGN.md §3):
+
+- ``save`` / ``save_many`` write one slot at a dynamic index: an in-place
+  slice update when the index is ONE scalar shared by the whole batch (the
+  replay path, ``ops/replay.py``).
+- ``save_where`` selects over the whole ring axis and holds no dynamic index:
+  the form for sessions batched under ``vmap`` at frames of their own (the
+  served pool), where an indexed write would be a scatter that XLA:TPU runs
+  as a serial loop over the sessions.
+
+*load* is a dynamic slice (a gather under a per-session frame).
 """
 
 from __future__ import annotations
@@ -89,22 +100,36 @@ class DeviceStateRing:
         """Predicated ``save``: the slot keeps its current contents where
         ``pred`` (scalar bool) is false.  This is the masked form batched
         heterogeneous fulfillment needs — under ``vmap`` each session decides
-        independently whether this tick's write happens."""
-        i = self.slot(frame)
+        independently whether this tick's write happens, at a frame of its
+        own.
+
+        Written as ONE select over the ring axis, with no dynamic index: a
+        slot index that differs per session turns ``save``'s
+        dynamic-update-slice into a scatter under ``vmap``, which XLA:TPU
+        runs as a serial loop over the sessions, once per buffer (DESIGN §3
+        "Per-session slots").  The select is elementwise over ``[B, R, ...]``
+        and fuses into the caller's scan body.  A negative ``frame`` (an idle
+        descriptor row's -1) matches no slot.
+
+        The price is that every call rewrites the whole ring instead of one
+        slot: 512 sessions x 10 slots x 40 B = 205 KB a write for
+        ``boxgame-2p``, 256 x 18 x 3,104 B = 14.3 MB for ``ecs-4p`` (0.66 ms
+        of 19 writes a tick at 819 GB/s, against 92 ms for the scatter).
+        That is the wrong trade once B x R x state bytes reaches gigabytes
+        (ROADMAP B2/M7): choose the write there from those three numbers,
+        which this method can see."""
+        hit = (
+            jnp.arange(self.length, dtype=jnp.int32) == self.slot(frame)
+        ) & pred
 
         def upd(buf: jax.Array, leaf: Any) -> jax.Array:
-            cur = jax.lax.dynamic_index_in_dim(buf, i, axis=0, keepdims=False)
-            val = jnp.where(pred, jnp.asarray(leaf, buf.dtype), cur)
-            return jax.lax.dynamic_update_index_in_dim(buf, val, i, axis=0)
+            mask = hit.reshape((self.length,) + (1,) * (buf.ndim - 1))
+            return jnp.where(mask, jnp.asarray(leaf, buf.dtype)[None, ...], buf)
 
         return {
-            "states": jax.tree_util.tree_map(
-                lambda buf, leaf: upd(buf, leaf), ring["states"], state
-            ),
+            "states": jax.tree_util.tree_map(upd, ring["states"], state),
             "checksums": upd(ring["checksums"], checksum),
-            "frames": ring["frames"].at[i].set(
-                jnp.where(pred, jnp.asarray(frame, jnp.int32), ring["frames"][i])
-            ),
+            "frames": upd(ring["frames"], frame),
         }
 
     def save_many(

@@ -107,6 +107,152 @@ class TestDeviceStateRing:
         assert int(ring.frame_at(buf, jnp.int32(0))) == 4
 
 
+# -- save_where: the served pool's predicated write -------------------------
+#
+# Each scenario is steps of (frames, preds), one entry a session.  The plain
+# semantics it is held to: fold ``save`` over the steps where ``pred`` holds,
+# the identity where not.
+
+_SW_R = 4  # ring length of the save_where cases
+_SW_B = 4  # sessions in the batched mode
+
+
+def _sw_template():
+    """Leaves of rank 0-3 in the four dtypes the games use."""
+    return {
+        "s": jnp.zeros((), jnp.float32),
+        "v": jnp.zeros((5,), jnp.uint8),
+        "m": jnp.zeros((2, 3), jnp.int32),
+        "t": jnp.zeros((2, 2, 3), jnp.uint32),
+    }
+
+
+def _sw_value(rng):
+    """One session's state and checksum row, drawn bit-wise at random."""
+    state = {
+        "s": np.float32(rng.normal()),
+        "v": rng.integers(0, 256, (5,), dtype=np.uint8),
+        "m": rng.integers(-(2**31), 2**31, (2, 3)).astype(np.int32),
+        "t": rng.integers(0, 2**32, (2, 2, 3), dtype=np.uint64).astype(np.uint32),
+    }
+    cs = rng.integers(0, 2**32, (4,), dtype=np.uint64).astype(np.uint32)
+    return state, cs
+
+
+_SW_SCENARIOS = {
+    # every session walks past the ring's end twice, each from its own frame
+    "wraparound": [
+        ([f + 3 * b for b in range(_SW_B)], [True] * _SW_B)
+        for f in range(2 * _SW_R + 3)
+    ],
+    # a populated ring, then saves that are all refused: the reference folds
+    # nothing over them, so every buffer has to come back bit-identical
+    "all_false": [([b, b + 1, b + 2, b + 3], [True] * _SW_B) for b in range(_SW_R)]
+    + [([7 + b, 2, 13, 5], [False] * _SW_B) for b in range(5)],
+    # what an idle descriptor row carries: frame -1 (and 0), never saved;
+    # sessions 0 and 3 have to end as they were initialised
+    "idle_frame_minus_one": [
+        ([-1, 5, -1, 0], [False, True, False, False]),
+        ([-1, -1, 9, 0], [False, False, True, False]),
+        ([-1, 6, -1, -1], [False, True, False, False]),
+    ],
+    # frames f and f + R share a slot: the later save and its tag win,
+    # a refused one between them changes nothing
+    "same_slot": [
+        ([1, 2, 3, 0], [True] * _SW_B),
+        ([1 + _SW_R, 2 + _SW_R, 3 + _SW_R, _SW_R], [False, True, False, True]),
+        ([1 + 2 * _SW_R, 2, 3 + 3 * _SW_R, 0], [True, False, True, False]),
+    ],
+    # a frame and a pred of each session's own, drawn at random
+    "mixed": [
+        (list(fr), list(pr))
+        for fr, pr in zip(
+            np.random.default_rng(7).integers(0, 5 * _SW_R, (12, _SW_B)),
+            np.random.default_rng(8).random((12, _SW_B)) < 0.6,
+        )
+    ],
+}
+
+
+def _sw_bits(tree):
+    return [np.asarray(leaf).tobytes() for leaf in jax.tree_util.tree_leaves(tree)]
+
+
+class TestSaveWhere:
+    @pytest.mark.parametrize("mode", ["unbatched", "vmap"])
+    @pytest.mark.parametrize("scenario", sorted(_SW_SCENARIOS))
+    def test_matches_fold_of_plain_save(self, scenario, mode):
+        """``save_where`` == ``save`` where ``pred``, identity where not:
+        every states leaf, ``checksums`` row and ``frames`` tag bit for bit,
+        one session at a time and under ``vmap`` with a frame and a ``pred``
+        of each session's own."""
+        ring = DeviceStateRing(_SW_R)
+        steps = _SW_SCENARIOS[scenario]
+        sessions = _SW_B if mode == "vmap" else 1
+        rng = np.random.default_rng(len(scenario))
+        values = [[_sw_value(rng) for _ in range(sessions)] for _ in steps]
+
+        # the reference: plain ``save`` at a concrete slot, session by session
+        want = [ring.init(_sw_template()) for _ in range(sessions)]
+        for (frames, preds), vals in zip(steps, values):
+            for b in range(sessions):
+                if preds[b]:
+                    state, cs = vals[b]
+                    want[b] = ring.save(want[b], jnp.int32(frames[b]), state, cs)
+
+        if mode == "unbatched":
+            got = ring.init(_sw_template())
+            fn = jax.jit(ring.save_where)
+            for (frames, preds), vals in zip(steps, values):
+                state, cs = vals[0]
+                got = fn(got, jnp.int32(frames[0]), state, cs, jnp.bool_(preds[0]))
+            got = [got]
+        else:
+            stack = lambda trees: jax.tree_util.tree_map(
+                lambda *ls: jnp.stack([jnp.asarray(l) for l in ls]), *trees
+            )
+            got = stack([ring.init(_sw_template()) for _ in range(sessions)])
+            fn = jax.jit(jax.vmap(ring.save_where))
+            for (frames, preds), vals in zip(steps, values):
+                got = fn(
+                    got,
+                    jnp.asarray(frames, jnp.int32),
+                    stack([v[0] for v in vals]),
+                    stack([v[1] for v in vals]),
+                    jnp.asarray(preds, bool),
+                )
+            got = [
+                jax.tree_util.tree_map(lambda l: l[b], got) for b in range(sessions)
+            ]
+
+        for b in range(sessions):
+            assert (
+                jax.tree_util.tree_structure(got[b])
+                == jax.tree_util.tree_structure(want[b])
+            )
+            assert _sw_bits(got[b]) == _sw_bits(want[b]), (scenario, mode, b)
+
+    def test_holds_no_dynamic_index(self):
+        """The write is a select over the ring axis: no scatter, gather or
+        dynamic slice in its jaxpr, batched or not."""
+        ring = DeviceStateRing(_SW_R)
+        buf = ring.init(_sw_template())
+        state, cs = _sw_value(np.random.default_rng(0))
+        one = jax.make_jaxpr(ring.save_where)(
+            buf, jnp.int32(1), state, cs, jnp.bool_(True)
+        )
+        batched = jax.vmap(ring.save_where, in_axes=(None, 0, None, None, 0))
+        many = jax.make_jaxpr(batched)(
+            buf, jnp.arange(3, dtype=jnp.int32), state, cs, jnp.ones((3,), bool)
+        )
+        for jp in (one, many):
+            names = {eq.primitive.name for eq in jp.jaxpr.eqns}
+            assert not {
+                n for n in names
+                if "scatter" in n or "gather" in n or "dynamic" in n
+            }, names
+
+
 class _CounterGame:
     """Trivial deterministic game: state {count, acc}; input (1,) int32."""
 

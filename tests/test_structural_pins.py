@@ -12,11 +12,20 @@ tests/test_spec_integration.py's dispatch pins:
   inner resim window) with a bounded equation count (catches fusion
   structure loss, runaway unrolling, and graph blowup).
 
+- primitive pins: the served pool's tick program (``session_tick`` under
+  ``vmap``, each session at a frame of its own) holds no ``scatter`` and no
+  more ``gather`` / ``dynamic_slice`` than its loads need.  A per-session
+  slot index under ``vmap`` IS visible to primitive counts: the write
+  becomes a ``scatter`` and the read a ``gather``, and XLA:TPU runs each
+  scatter as a serial loop over the sessions (PERF §6, PR 26: 67 of a 68 ms
+  tick).
+
 Known limitation, measured while building these: the ~30x
-shared-vs-per-session ring-index regression (ReplayPrograms docstring) is
-invisible to primitive counts — both forms produce identical jaxprs up to
-the VALUES feeding the scatter indices — so that property stays covered by
-its behavioral test and the bench deltas, not by these pins.
+shared-vs-per-session ring-index regression of the REPLAY path
+(ReplayPrograms docstring) is invisible to primitive counts — there both
+forms produce identical jaxprs up to the VALUES feeding the scatter
+indices — so that property stays covered by its behavioral test and the
+bench deltas, not by these pins.
 """
 
 from __future__ import annotations
@@ -29,9 +38,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from ggrs_tpu.games import EcsWorld
 from ggrs_tpu.games.boxgame import BoxGame
 from ggrs_tpu.ops.replay import build_replay_programs
 from ggrs_tpu.parallel.batch import BatchedSessions, make_mesh
+from ggrs_tpu.parallel.session_pool import BatchedRequestExecutor
 from ggrs_tpu.sessions.device_synctest import DeviceSyncTestSession
 
 
@@ -164,3 +175,50 @@ class TestBatchedSessionsPins:
         )
         # collectives: exactly the two stat reductions ride the mesh
         assert txt.count("all_reduce") <= 2, "unexpected extra collectives"
+
+
+def _indexing(counts: Counter) -> Counter:
+    return Counter({
+        name: n for name, n in counts.items()
+        if "scatter" in name or "gather" in name or "dynamic" in name
+    })
+
+
+class TestPoolTickProgramPins:
+    """The served pool's one tick program (``BatchedRequestExecutor._tick``):
+    every ring write is a select over the ring axis, so nothing in it is
+    indexed by a per-session slot but ``ring.load``."""
+
+    @pytest.mark.parametrize(
+        "make_game,players,ring_length,max_burst",
+        [
+            (lambda: BoxGame(2), 2, 10, 9),  # boxgame-2p: 3 state leaves
+            (lambda: EcsWorld(4, 8), 4, 18, 17),  # ecs-4p's shape: 5 leaves
+        ],
+        ids=["boxgame-2p", "ecs-4p"],
+    )
+    def test_no_scatter_and_only_the_loads_gather(
+        self, make_game, players, ring_length, max_burst
+    ):
+        game = make_game()
+        ex = BatchedRequestExecutor(
+            game.advance, game.init_state(),
+            lambda inputs: np.zeros((players,), np.uint8),
+            batch_size=4, ring_length=ring_length, max_burst=max_burst,
+        )
+        example = np.zeros((players,), np.uint8)
+        ex.warmup(example)
+        got = _indexing(_walk_primitives(
+            jax.make_jaxpr(ex._tick)(ex._carry, ex._blank_desc())
+        ))
+        # what the game's own step indexes (BoxGame's direction tables),
+        # counted once: the burst scan's body appears once in the jaxpr
+        own = _indexing(_walk_primitives(
+            jax.make_jaxpr(game.advance)(game.init_state(), jnp.asarray(example))
+        ))
+        leaves = len(jax.tree_util.tree_leaves(game.init_state()))
+        assert not [n for n in got if "scatter" in n], got
+        assert got["dynamic_update_slice"] == own["dynamic_update_slice"], got
+        reads = lambda c: c["gather"] + c["dynamic_slice"]
+        # ring.load: one gather per state leaf, and nothing else
+        assert reads(got) <= reads(own) + leaves, (got, own, leaves)
