@@ -55,6 +55,7 @@ from ggrs_tpu.parallel import (  # noqa: E402
     BatchedRequestExecutor,
     HostedPool,
     HostSessionPool,
+    make_mesh,
 )
 from ggrs_tpu.sessions import SessionBuilder  # noqa: E402
 from ggrs_tpu.utils.device import place_compile_cache, require_chip  # noqa: E402
@@ -153,7 +154,7 @@ class Pool:
     of match ``m``."""
 
     def __init__(self, config: Dict[str, Any], traffic: Dict[str, Any],
-                 matches: int, seed: int) -> None:
+                 matches: int, seed: int, chips: int = 1) -> None:
         adapter = importlib.import_module(f"benchmark.adapters.{config['adapter']}")
         players = int(config["players"])
         self.matches, self.players = matches, players
@@ -179,12 +180,15 @@ class Pool:
                     builder = builder.add_player(who, j)
                 self.host.add_session(builder, self.net.socket(f"m{m}p{k}"))
         game = adapter.make_game(config)
+        # a cell on several chips shards the session axis over a mesh of them
+        across = {"mesh": make_mesh(chips)} if chips > 1 else {}
         self.executor = BatchedRequestExecutor(
             game.advance, game.init_state(), adapter.inputs_to_array,
             batch_size=self.sessions,
             ring_length=int(config["ring_length"]),
             max_burst=int(config["max_burst"]),
             raw_inputs_to_array=adapter.raw_inputs_to_array,
+            **across,
         )
         # the cell's one tick program and the slot probe, and nothing else
         self.executor.warmup(adapter.example_inputs(config))
@@ -308,11 +312,26 @@ def closed_loop(pool: Pool, inputs: Inputs, seconds: Optional[float],
     t_issued = time.perf_counter()
     pool.fence()
     t1 = time.perf_counter()
-    periods = np.diff([t0] + fences)  # a slow stretch of the window shows here
-    return {"ticks": n, "window_s": t1 - t0, "drain_s": t1 - t_issued,
-            "bubble_s": sum(bubbles), "fences": len(bubbles),
-            "fence_period_s": {"min": float(periods.min()), "max": float(periods.max())}
-            if len(periods) else {}}
+    periods = np.diff([t0] + fences)
+    out = {"ticks": n, "window_s": t1 - t0, "drain_s": t1 - t_issued,
+           "bubble_s": sum(bubbles), "fences": len(bubbles),
+           "fence_period_s": periods.tolist(),
+           # what follows the last fence is no period: a stall there shows here
+           "tail": {"ticks": n % fence_every if fence_every else n,
+                    "s": t1 - (fences[-1] if fences else t0)}}
+    if len(periods):
+        # was the run disturbed from inside: the host's speed wanders over
+        # some ten seconds (the quarters), a stall makes a few long periods
+        p0, p25, p50, p75, p100 = np.percentile(periods, [0, 25, 50, 75, 100])
+        out["fence_period_quartiles_s"] = {
+            "min": float(p0), "p25": float(p25), "p50": float(p50),
+            "p75": float(p75), "max": float(p100)}
+        out["tick_ms_by_quarter"] = [
+            1e3 * float(q.sum()) / (len(q) * fence_every)
+            for q in np.array_split(periods, 4) if len(q)]
+        out["long_periods"] = [[int(i), float(periods[i])]
+                               for i in np.flatnonzero(periods > 1.5 * p50)][:8]
+    return out
 
 
 def open_loop(pool: Pool, inputs: Inputs, ticks: int, rate_hz: float,
@@ -506,7 +525,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                     warm_ticks + reach + trace_ticks + hold + 1,
                     int(config["input_delay"]))
     t_built = time.perf_counter()
-    pool = Pool(config, traffic, matches, seed)
+    pool = Pool(config, traffic, matches, seed, int(cell["chips"]))
     t_pool = time.perf_counter()
     ref = importlib.import_module(f"benchmark.reference.{config['adapter']}")
     spans = Spans(pool, ref.state_bytes(config)) if trace else None
@@ -531,7 +550,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         f"{cache_dir}, {pool.sessions} sessions, native bank: "
         f"{pool.host.native_reason}")
 
+    collections0 = [g["collections"] for g in gc.get_stats()]
     window = run_loop(pool, inputs, traffic, float(seconds), None)
+    window["gc_collections"] = [
+        g["collections"] - c for g, c in zip(gc.get_stats(), collections0)]
     compiles_in_window = meter.compiles - compiles0
     loads = (registry.value("ggrs_executor_rollback_loads_total") or 0.0) - loads0
     window_ticks = window["ticks"]
@@ -567,7 +589,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     tick_ms = window.get("tick_ms", [])
     facts: Dict[str, Any] = {
         "peaks": peaks,
-        "series": {"tick_ms": tick_ms, "late_ms": window.get("late_ms", [])},
+        "series": {"tick_ms": tick_ms, "late_ms": window.get("late_ms", []),
+                   "fence_period_s": window.get("fence_period_s", [])},
         "counts": {
             "setup_s": setup_s,
             "window_s": window["window_s"],
@@ -613,7 +636,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         result["breakdown"] = {"device_ops": sliced["device_ops"],
                                "idle_gaps": sliced["idle_gaps"]}
     result["window"] = {k: v for k, v in window.items()
-                        if k not in ("tick_ms", "late_ms")}
+                        if k not in ("tick_ms", "late_ms", "fence_period_s")}
     if tick_ms:  # which ticks overran their frame: a stall shows as a run of them
         frame_ms = 1e3 / float(traffic["rate_hz"])
         result["window"]["late_ticks"] = [

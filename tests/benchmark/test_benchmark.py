@@ -3,8 +3,8 @@ every cell rehearses on the CPU at a tiny size with ``correct`` true, a
 broken timed path and the control come out not correct, and the trace
 reduction and the roofline's byte count give hand-computed numbers.
 
-The chip requirement is stubbed HERE, never in the benchmark: ``run.py``
-itself has no CPU path.  No test describes a TPU topology.
+The chip requirement is stubbed in ``conftest.py``, never in the benchmark:
+``run.py`` itself has no CPU path.  No test describes a TPU topology.
 """
 
 from __future__ import annotations
@@ -25,7 +25,6 @@ if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
 from benchmark import control, generator, roofline, run, trace_reduce  # noqa: E402
-from ggrs_tpu.utils.device import device_record  # noqa: E402
 
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -33,17 +32,6 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 SEED = 2**31 + 77  # the driver's seeds are large
-
-
-@pytest.fixture
-def no_chip_needed(monkeypatch):
-    """Stand in for the chip: the CPU device's record and the v5e's peaks."""
-    monkeypatch.setattr(run, "require_chip", lambda chips=1: device_record())
-    monkeypatch.setattr(run, "peaks_for", lambda kind: {"hbm_gbs": 819.0})
-    # four matches on this CPU tick faster than any mix reckons the chip can
-    load = generator.load_traffic
-    monkeypatch.setattr(generator, "load_traffic",
-                        lambda path: dict(load(path), max_ticks_per_s=8000))
 
 
 def rehearse(cell: str, trace: bool = False, **kwargs):

@@ -21,9 +21,8 @@ REPO = Path(__file__).resolve().parents[2]
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
-from benchmark import generator, program_spans, run  # noqa: E402
+from benchmark import program_spans, run  # noqa: E402
 from ggrs_tpu.obs import default_tracer  # noqa: E402
-from ggrs_tpu.utils.device import device_record  # noqa: E402
 
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 QUANTITIES = ["bank_crossing_ms_p50", "bank_python_ms_p50",
@@ -185,12 +184,7 @@ def test_each_quantity_has_one_file_and_two_entries(quantity):
     assert run._metric_file(REPO, f"{quantity}.sat") == spec
 
 
-def test_the_traced_rehearsal_reports_all_six_paced_quantities(ring, monkeypatch):
-    monkeypatch.setattr(run, "require_chip", lambda chips=1: device_record())
-    monkeypatch.setattr(run, "peaks_for", lambda kind: {"hbm_gbs": 819.0})
-    load = generator.load_traffic
-    monkeypatch.setattr(generator, "load_traffic",
-                        lambda path: dict(load(path), max_ticks_per_s=8000))
+def test_the_traced_rehearsal_reports_all_six_paced_quantities(ring, no_chip_needed):
     result = run.run_cell("boxgame-2p.wan-60hz", SEED, 0.25, True, matches=4)
     assert result["correct"] is True, result["checks"]
     assert result["checks"]["plan_ticks_off_ticks"]["value"] == 0
