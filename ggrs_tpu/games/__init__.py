@@ -20,6 +20,7 @@ from .boxgame import (
 )
 from .chipvm import ChipVM
 from .ecs_world import EcsWorld
+from .particles import ParticleWorld
 from .rtscmd import RtsCmd, RtsCmdGame, decode_commands, encode_commands
 
 __all__ = [
@@ -30,6 +31,7 @@ __all__ = [
     "BoxGame",
     "ChipVM",
     "EcsWorld",
+    "ParticleWorld",
     "RtsCmd",
     "RtsCmdGame",
     "boxgame_config",

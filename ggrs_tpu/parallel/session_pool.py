@@ -66,6 +66,15 @@ _OBS_ROLLBACK_LOADS = default_registry().counter(
     "ggrs_executor_rollback_loads_total",
     "sessions that carried a LoadGameState (rollback) into a pooled tick",
 )
+_OBS_STATE_BYTES = default_registry().gauge(
+    "ggrs_executor_state_bytes",
+    "bytes of one session's state in the newest pooled executor",
+)
+_OBS_RING_RESIDENT_BYTES = default_registry().gauge(
+    "ggrs_executor_ring_resident_bytes",
+    "bytes the newest pooled executor's carry holds on its fullest device: "
+    "ring slots, live states, kept digests and frames",
+)
 _OBS_BURST_DEPTH = default_registry().histogram(
     "ggrs_executor_burst_depth_frames",
     "deepest per-session advance burst (replay depth) per dispatched tick",
@@ -216,6 +225,16 @@ class BatchedRequestExecutor:
             self._carry = jax.tree_util.tree_map(
                 lambda l: jax.device_put(l, self._sharding), self._carry
             )
+        # what the executor holds on the device, for a ledger line to tell
+        # the ring's own bytes from the lowering's copies (DESIGN.md §14);
+        # the session axis shards evenly, so a device's share is 1/size
+        _OBS_STATE_BYTES.set(
+            sum(l.nbytes for l in jax.tree_util.tree_leaves(state0))
+        )
+        _OBS_RING_RESIDENT_BYTES.set(
+            sum(l.nbytes for l in jax.tree_util.tree_leaves(self._carry))
+            // (mesh.devices.size if mesh is not None else 1)
+        )
         self._input_dtype: Optional[np.dtype] = None
         self._input_shape: Optional[Tuple[int, ...]] = None
         # tracing (DESIGN.md §14): device dispatch (fill + launch) and

@@ -316,15 +316,26 @@ def tracer_ab(pool, inputs, tracer, hz, segment_ticks, segments):
 # ---------------------------------------------------------------------------
 
 SCOPES = ("ring.pre_save", "ring.load", "ring.save", "advance", "digest")
+_STRUCTURE = ("while", "body", "cond")
 
 
 def scope_of(op_name: str) -> str:
     """``jit(tick)/vmap(ring.save)/while/body/digest/mul`` -> ``ring.save >
-    digest``: the named scopes of ``session_tick`` on an operation's path."""
+    digest``: the named scopes of ``session_tick`` on an operation's path,
+    and under ``advance`` the game's own outermost scope, whatever its name
+    (``.../vmap(advance)/spawn/select_n`` -> ``advance > spawn``): a bare
+    part that is neither the operation at the path's end nor a loop's."""
     found = []
-    for part in op_name.replace("(", "/").replace(")", "/").split("/"):
-        if part in SCOPES and part not in found:
-            found.append(part)
+    parts = op_name.split("/")
+    for i, raw in enumerate(parts):
+        part = raw[raw.find("(") + 1:].rstrip(")")
+        if part in SCOPES:
+            if part not in found:
+                found.append(part)
+        elif (found and found[-1] == "advance" and raw == part
+              and i < len(parts) - 1 and raw not in _STRUCTURE
+              and not raw.startswith("branch")):
+            found.append(raw)
     return " > ".join(found) if found else "-"
 
 
