@@ -22,6 +22,10 @@ fixed-shape descriptor —
 — padded with masked no-ops, so heterogeneous ticks (one session rolling
 back 8 frames, another advancing once, a third skipping on prediction
 threshold) are ONE program with per-session predication, not B programs.
+The burst loop runs as many steps as the deepest plan of the batch asks
+(read on the device from the ``n_adv`` column), not ``max_burst``: a tick
+in which no session rolls back runs one step, and a shallower session
+idles through the steps of the deepest by its mask.
 Grammar parity: the same ``Save | Load (Adv Save?)* | Adv`` request shapes
 ``ops.DeviceRequestExecutor`` executes (/root/reference/src/lib.rs:170-195).
 
@@ -266,6 +270,9 @@ class BatchedRequestExecutor:
             inputs: Any,  # [max_burst, ...]
             save_mask: jax.Array,  # [max_burst]
             save_frame: jax.Array,  # [max_burst]
+            # what the whole batch asks (unbatched):
+            n_steps: jax.Array,  # its deepest n_adv
+            any_postload: jax.Array,  # whether any session saves after its load
         ):
             # the scopes name the program's parts in a device profile
             # (metadata only: the lowered operations are the same)
@@ -279,34 +286,48 @@ class BatchedRequestExecutor:
             with jax.named_scope("ring.load"):
                 st = _tree_where(do_load, dring.load(ring, load_frame), live)
             # sparse saving can save the just-loaded state before any advance
-            # (reference: p2p_session.rs:666-672 — the min_confirmed save)
+            # (reference: p2p_session.rs:666-672 — the min_confirmed save);
+            # a batch in which no session does skips the write and its
+            # digest: the ring passes through the conditional uncopied
+            # (PERF.md section 5, PR 30)
             with jax.named_scope("ring.save"):
-                ring = write(ring, postload_frame, st, postload_save)
+                ring = jax.lax.cond(
+                    any_postload,
+                    lambda ring: write(ring, postload_frame, st, postload_save),
+                    lambda ring: ring,
+                    ring,
+                )
 
-            def step(carry, xs):
+            # the burst: as many trips as the deepest plan of the batch asks
+            # (1 on a quiet tick, 2 with a one-frame rollback, max_burst at
+            # most), not max_burst whatever the plans hold.  A step beyond
+            # that is a no-op for every session (act false: the advance
+            # discarded, the save's predicate false), so leaving it out
+            # changes no byte.  The counter is the batch's, so the descriptor
+            # columns are read at ONE index (a slice, not a gather); a
+            # session whose plan is shallower idles through the rest by act.
+            def step(j, carry):
                 st, ring = carry
-                j, inp, smask, sframe = xs
+                inp, smask, sframe = (
+                    jax.lax.dynamic_index_in_dim(col, j, 0, keepdims=False)
+                    for col in (inputs, save_mask, save_frame)
+                )
                 act = j < n_adv
                 with jax.named_scope("advance"):
                     st = _tree_where(act, advance(st, inp), st)
                 with jax.named_scope("ring.save"):
                     ring = write(ring, sframe, st, act & smask)
-                return (st, ring), None
+                return st, ring
 
-            (st, ring), _ = jax.lax.scan(
-                step,
-                (st, ring),
-                (
-                    jnp.arange(max_burst, dtype=jnp.int32),
-                    inputs,
-                    save_mask,
-                    save_frame,
-                ),
-            )
-            return st, ring
+            return jax.lax.fori_loop(jnp.int32(0), n_steps, step, (st, ring))
 
         def tick(carry: Dict[str, Any], desc: Dict[str, Any]) -> Dict[str, Any]:
-            live, ring = jax.vmap(session_tick)(
+            # under shard_map both are each shard's own: no collective
+            n_steps = jnp.max(desc["n_adv"])
+            any_postload = jnp.any(desc["postload_save"])
+            live, ring = jax.vmap(
+                lambda *session: session_tick(*session, n_steps, any_postload)
+            )(
                 carry["live"],
                 carry["ring"],
                 desc["pre_save"],
@@ -676,7 +697,10 @@ class BatchedRequestExecutor:
         _OBS_DISPATCHES.inc()
         _OBS_ROLLBACK_LOADS.inc(loads)
         _OBS_BURST_DEPTH.observe(max_burst)
-        fill.set(loads=loads, max_burst=max_burst)
+        # max_burst is the tick program's trip count, burst_cap the most it
+        # can be: their ratio says how much of the burst loop the traffic
+        # left out (benchmark: burst_steps_share)
+        fill.set(loads=loads, max_burst=max_burst, burst_cap=self.max_burst)
 
     def _launch(self, desc: Dict[str, Any]) -> None:
         """The call of the tick program: transfer of the descriptors and

@@ -213,3 +213,71 @@ class TestBatchedSessions:
         stats = batch.run_ticks(_random_inputs((B, 5, 2), seed=2))
         assert stats["mismatches"] >= 1
         assert stats["first_bad"] == 9
+
+
+class TestPooledTickOverTheMesh:
+    def test_shards_with_different_deepest_plans_equal_one_device(self):
+        """``BatchedRequestExecutor``'s burst loop runs as many steps as the
+        deepest plan asks, and under ``shard_map`` that is each shard's own
+        maximum (no collective): shards whose loops differ in length, and of
+        which one alone takes the post-load save, give the bytes the
+        single-device program gives."""
+        from ggrs_tpu.parallel import BatchedRequestExecutor
+
+        if len(jax.devices()) < 8:
+            pytest.skip("needs the 8-device virtual mesh")
+        game = BoxGame(2)
+        B, R, D = 8, 10, 9  # one session a shard
+
+        def pool(mesh):
+            ex = BatchedRequestExecutor(
+                game.advance, game.init_state(),
+                lambda pairs: np.asarray([p[0] for p in pairs], np.uint8),
+                batch_size=B, ring_length=R, max_burst=D, mesh=mesh,
+            )
+            ex.warmup(np.zeros((2,), np.uint8))
+            return ex
+
+        single, sharded = pool(None), pool(make_mesh(8))
+        descs = []
+        for f in range(D):  # quiet ticks: frames 0..8 saved in every ring
+            desc = single._blank_desc()
+            desc["pre_save"][:] = True
+            desc["pre_frame"][:] = f
+            desc["n_adv"][:] = 1
+            desc["inputs"][:] = _random_inputs(desc["inputs"].shape, seed=f)
+            descs.append(desc)
+        # frame 9: shard b's one session rolls back depth[b] - 1 frames
+        # (0: idle; 1: a quiet tick), so the shards' trip counts differ
+        desc = single._blank_desc()
+        desc["inputs"][:] = _random_inputs(desc["inputs"].shape, seed=D)
+        for b, depth in enumerate([0, 1, 2, 3, 5, 9, 1, 2]):
+            desc["n_adv"][b] = depth
+            if depth == 1:
+                desc["pre_save"][b] = True
+                desc["pre_frame"][b] = D
+            elif depth > 1:
+                desc["do_load"][b] = True
+                desc["load_frame"][b] = D - (depth - 1)
+                desc["save_mask"][b, : depth - 1] = True
+                desc["save_frame"][b, : depth - 1] = (
+                    D - (depth - 1) + 1 + np.arange(depth - 1)
+                )
+        # and one shard alone saves the state it loaded (sparse saving):
+        # the conditional around that write is each shard's own too
+        desc["postload_save"][3] = True
+        desc["postload_frame"][3] = desc["load_frame"][3]
+        descs.append(desc)
+        for desc in descs:
+            for ex in (single, sharded):
+                # a copy each: the CPU backend may alias a host buffer
+                ex._carry = ex._tick(
+                    ex._carry, {k: v.copy() for k, v in desc.items()}
+                )
+        want, got = jax.device_get((single._carry, sharded._carry))
+        for w, g in zip(jax.tree_util.tree_leaves(want),
+                        jax.tree_util.tree_leaves(got)):
+            np.testing.assert_array_equal(g, w)
+        # the rollbacks wrote: the deepest shard's resimulation saved frames
+        # 2..9, the last of them into the slot no quiet tick had filled
+        assert sorted(got["ring"]["frames"][5].tolist()) == list(range(10))

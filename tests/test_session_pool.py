@@ -9,8 +9,11 @@ saving.
 """
 
 import random
+import sys
+from pathlib import Path
 
 import numpy as np
+import pytest
 
 import jax
 
@@ -18,8 +21,16 @@ from ggrs_tpu.core import DesyncDetection, Local, Remote
 from ggrs_tpu.games import BoxGame, boxgame_config
 from ggrs_tpu.net import InMemoryNetwork
 from ggrs_tpu.ops import DeviceRequestExecutor, ExecutorPrograms
+from ggrs_tpu.ops.checksum import checksum_to_u128
 from ggrs_tpu.parallel import BatchedRequestExecutor
 from ggrs_tpu.sessions import SessionBuilder
+
+REPO = Path(__file__).resolve().parents[1]
+if str(REPO) not in sys.path:
+    sys.path.insert(0, str(REPO))
+
+# the benchmark's plain NumPy copy of the digest: imports nothing of the program
+from benchmark.reference import digest as reference_digest  # noqa: E402
 
 
 def _to_arr(pairs):
@@ -419,3 +430,127 @@ class TestBatchedRequestExecutor:
         assert calls["n"] == 20
         pool.run([[] for _ in range(6)])
         assert calls["n"] == 20, "an all-empty tick must not dispatch"
+
+
+# ---------------------------------------------------------------------------
+# the burst loop ends at the deepest plan of the batch (PERF §6, PR 30)
+# ---------------------------------------------------------------------------
+
+_R, _D, _B = 10, 9, 6  # boxgame-2p's ring and burst; six sessions
+_PRELUDE = 9  # quiet ticks before the descriptor under test: frames 0..8 saved
+
+
+class _NumpySession:
+    """One session's tick as the descriptor grammar states it, in plain
+    NumPy: ``[pre-save] [load [post-load save]] (advance, save?)*``."""
+
+    def __init__(self, game):
+        self.game = game
+        self.live = game.init_state_np()
+        self.slots = [dict(self.live) for _ in range(_R)]
+        self.digests = [0] * _R
+        self.frames = [-1] * _R
+
+    def save(self, frame, state):
+        s = int(frame) % _R
+        self.slots[s] = {k: v.copy() for k, v in state.items()}
+        self.digests[s] = reference_digest.u128(state)
+        self.frames[s] = int(frame)
+
+    def tick(self, desc, b):
+        st = self.live
+        if desc["pre_save"][b]:
+            self.save(desc["pre_frame"][b], st)
+        if desc["do_load"][b]:
+            st = self.slots[int(desc["load_frame"][b]) % _R]
+            if desc["postload_save"][b]:
+                self.save(desc["postload_frame"][b], st)
+        for j in range(int(desc["n_adv"][b])):
+            st = self.game.advance_np(st, desc["inputs"][b, j])
+            if desc["save_mask"][b, j]:
+                self.save(desc["save_frame"][b, j], st)
+        self.live = st
+
+
+def _plan(desc, b, rng, frame, depth, *, postload=False):
+    """Fill row ``b`` as a session at ``frame`` would: depth 0 a save-only
+    tick, 1 a quiet tick (pre-save + advance), d >= 2 a rollback of d - 1
+    frames (load, d advances, a save after each but the last)."""
+    desc["inputs"][b] = rng.integers(0, 16, desc["inputs"][b].shape)
+    if depth <= 1:
+        desc["pre_save"][b] = True
+        desc["pre_frame"][b] = frame
+        desc["n_adv"][b] = depth
+        return
+    lf = frame - (depth - 1)
+    desc["do_load"][b] = True
+    desc["load_frame"][b] = lf
+    if postload:  # sparse saving's save of the just-loaded state
+        desc["postload_save"][b] = True
+        desc["postload_frame"][b] = lf
+    desc["n_adv"][b] = depth
+    desc["save_mask"][b, : depth - 1] = True
+    desc["save_frame"][b, : depth - 1] = lf + 1 + np.arange(depth - 1)
+
+
+_DEPTH_CASES = {
+    # name: (depth per session; None leaves the row idle), post-load save
+    "save_only": ([0] * _B, False),
+    "quiet": ([1] * _B, False),
+    "one_frame_rollback": ([2] * _B, False),
+    "max_burst": ([_D] * _B, False),
+    "mixed": ([None, 0, 1, 2, 5, _D], False),
+    "mixed_deepest_first": ([_D, 2, 1, 1, 0, None], False),
+    "mixed_shallow_postload": ([1, 2, 3, 1, None, 2], True),
+}
+
+
+def _prelude_and_case(pool, depths, postload, seed):
+    """The descriptors of ``_PRELUDE`` quiet ticks, then the one under
+    test."""
+    rng = np.random.default_rng(seed)
+    descs = []
+    for f in range(_PRELUDE):
+        desc = pool._blank_desc()
+        for b in range(pool.batch_size):
+            _plan(desc, b, rng, f, 1)
+        descs.append(desc)
+    desc = pool._blank_desc()
+    for b, depth in enumerate(depths):
+        if depth is not None:
+            _plan(desc, b, rng, _PRELUDE, depth, postload=postload)
+    descs.append(desc)
+    return descs
+
+
+class TestBurstLoopEndsAtTheDeepestPlan:
+    @pytest.mark.parametrize("case", sorted(_DEPTH_CASES))
+    def test_tick_equals_the_numpy_replay(self, case):
+        """Live state, every ring slot, its digest and its frame tag, bit
+        for bit, whatever the deepest plan of the batch is."""
+        depths, postload = _DEPTH_CASES[case]
+        game = BoxGame(2)
+        pool = BatchedRequestExecutor(
+            game.advance, game.init_state(), _to_arr,
+            batch_size=_B, ring_length=_R, max_burst=_D,
+        )
+        pool.warmup(np.zeros((2,), np.uint8))
+        model = [_NumpySession(game) for _ in range(_B)]
+        for desc in _prelude_and_case(pool, depths, postload, seed=30):
+            for b, session in enumerate(model):
+                session.tick(desc, b)
+            pool._carry = pool._tick(pool._carry, desc)
+        carry = jax.device_get(pool._carry)
+        ring = carry["ring"]
+        for b, session in enumerate(model):
+            for k, want in session.live.items():
+                np.testing.assert_array_equal(
+                    carry["live"][k][b], want, err_msg=f"{case} live {b} {k}")
+            assert ring["frames"][b].tolist() == session.frames, (case, b)
+            for s in range(_R):
+                for k, want in session.slots[s].items():
+                    np.testing.assert_array_equal(
+                        ring["states"][k][b, s], want,
+                        err_msg=f"{case} session {b} slot {s} {k}")
+                got = checksum_to_u128(ring["checksums"][b, s])
+                assert got == session.digests[s], (case, b, s)

@@ -14,7 +14,8 @@ tests/test_spec_integration.py's dispatch pins:
 
 - primitive pins: the served pool's tick program (``session_tick`` under
   ``vmap``, each session at a frame of its own) holds no ``scatter`` and no
-  more ``gather`` / ``dynamic_slice`` than its loads need.  A per-session
+  more ``gather`` / ``dynamic_slice`` than its loads need, beside the burst
+  loop's reads of three descriptor columns at its counter.  A per-session
   slot index under ``vmap`` IS visible to primitive counts: the write
   becomes a ``scatter`` and the read a ``gather``, and XLA:TPU runs each
   scatter as a serial loop over the sessions (PERF §6, PR 26: 67 of a 68 ms
@@ -38,7 +39,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ggrs_tpu.games import EcsWorld
+from ggrs_tpu.games import EcsWorld, ParticleWorld
 from ggrs_tpu.games.boxgame import BoxGame
 from ggrs_tpu.ops.replay import build_replay_programs
 from ggrs_tpu.parallel.batch import BatchedSessions, make_mesh
@@ -46,22 +47,20 @@ from ggrs_tpu.parallel.session_pool import BatchedRequestExecutor
 from ggrs_tpu.sessions.device_synctest import DeviceSyncTestSession
 
 
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of its sub-jaxprs."""
+    for eq in jaxpr.eqns:
+        yield eq
+        for v in eq.params.values():
+            for x in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(x, "jaxpr", x)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_eqns(inner)
+
+
 def _walk_primitives(closed_jaxpr) -> Counter:
     """Primitive-name counts over a jaxpr, recursing into sub-jaxprs."""
-    counts: Counter = Counter()
-
-    def walk(j):
-        for eq in j.eqns:
-            counts[eq.primitive.name] += 1
-            for v in eq.params.values():
-                for x in v if isinstance(v, (list, tuple)) else [v]:
-                    if hasattr(x, "jaxpr"):
-                        walk(x.jaxpr)
-                    elif hasattr(x, "eqns"):
-                        walk(x)
-
-    walk(closed_jaxpr.jaxpr)
-    return counts
+    return Counter(eq.primitive.name for eq in _walk_eqns(closed_jaxpr.jaxpr))
 
 
 class TestFlagshipReplayPins:
@@ -187,38 +186,80 @@ def _indexing(counts: Counter) -> Counter:
 class TestPoolTickProgramPins:
     """The served pool's one tick program (``BatchedRequestExecutor._tick``):
     every ring write is a select over the ring axis, so nothing in it is
-    indexed by a per-session slot but ``ring.load``."""
+    indexed by a per-session slot but ``ring.load``; and the burst loop,
+    whose trip count is the batch's deepest plan, reads its step's
+    descriptor columns at the counter the whole batch shares."""
 
     @pytest.mark.parametrize(
         "make_game,players,ring_length,max_burst",
         [
             (lambda: BoxGame(2), 2, 10, 9),  # boxgame-2p: 3 state leaves
             (lambda: EcsWorld(4, 8), 4, 18, 17),  # ecs-4p's shape: 5 leaves
+            (lambda: ParticleWorld(2, 64, 4, 8), 2, 10, 9),  # particles-2p's: 7
         ],
-        ids=["boxgame-2p", "ecs-4p"],
+        ids=["boxgame-2p", "ecs-4p", "particles-2p"],
     )
     def test_no_scatter_and_only_the_loads_gather(
         self, make_game, players, ring_length, max_burst
     ):
         game = make_game()
+        batch = 4
         ex = BatchedRequestExecutor(
             game.advance, game.init_state(),
             lambda inputs: np.zeros((players,), np.uint8),
-            batch_size=4, ring_length=ring_length, max_burst=max_burst,
+            batch_size=batch, ring_length=ring_length, max_burst=max_burst,
         )
         example = np.zeros((players,), np.uint8)
         ex.warmup(example)
-        got = _indexing(_walk_primitives(
-            jax.make_jaxpr(ex._tick)(ex._carry, ex._blank_desc())
-        ))
+        tick = jax.make_jaxpr(ex._tick)(ex._carry, ex._blank_desc())
+        got = _indexing(_walk_primitives(tick))
         # what the game's own step indexes (BoxGame's direction tables),
-        # counted once: the burst scan's body appears once in the jaxpr
+        # counted once: the burst loop's body appears once in the jaxpr
         own = _indexing(_walk_primitives(
             jax.make_jaxpr(game.advance)(game.init_state(), jnp.asarray(example))
         ))
         leaves = len(jax.tree_util.tree_leaves(game.init_state()))
         assert not [n for n in got if "scatter" in n], got
         assert got["dynamic_update_slice"] == own["dynamic_update_slice"], got
-        reads = lambda c: c["gather"] + c["dynamic_slice"]
+        # a read at ONE index for the whole batch (a dynamic_slice, or the
+        # gather of a single start index that vmap makes of one) against a
+        # read at an index of each session's own
+        shared, per_session = [], []
+        for eq in _walk_eqns(tick.jaxpr):
+            if eq.primitive.name == "dynamic_slice":
+                shared.append(eq)
+            elif eq.primitive.name == "gather":
+                one_index = eq.invars[1].aval.ndim == 1
+                (shared if one_index else per_session).append(eq)
         # ring.load: one gather per state leaf, and nothing else
-        assert reads(got) <= reads(own) + leaves, (got, own, leaves)
+        reads = lambda c: c["gather"] + c["dynamic_slice"]
+        assert len(per_session) <= reads(own) + leaves, (got, own, leaves)
+        # the burst loop's step: inputs, save_mask and save_frame at the
+        # loop counter, each a column [batch, max_burst, ...] of the
+        # descriptor; whatever else is shared is the game's own
+        columns = [eq for eq in shared
+                   if eq.invars[0].aval.shape[:2] == (batch, max_burst)]
+        assert len(columns) == 3, shared
+        assert len(shared) - len(columns) <= reads(own), (shared, own)
+
+    def test_the_burst_loop_has_no_static_length(self):
+        """The program's one loop takes its trip count from the descriptor
+        (the batch's deepest plan): a scan, or a fori_loop of constant
+        bounds, which lowers to one, over ``max_burst`` steps would run them
+        all whatever the plans ask (PERF §6, PR 30: 9 steps where 2 were
+        asked for)."""
+        game = BoxGame(2)
+        ex = BatchedRequestExecutor(
+            game.advance, game.init_state(),
+            lambda inputs: np.zeros((2,), np.uint8),
+            batch_size=4, ring_length=10, max_burst=7,
+        )
+        ex.warmup(np.zeros((2,), np.uint8))
+        tick = jax.make_jaxpr(ex._tick)(ex._carry, ex._blank_desc())
+        loops = [eq for eq in _walk_eqns(tick.jaxpr)
+                 if eq.primitive.name in ("scan", "while")]
+        assert [eq.primitive.name for eq in loops] == ["while"], loops
+        (compare,) = loops[0].params["cond_jaxpr"].jaxpr.eqns
+        assert compare.primitive.name == "lt", compare
+        # counter < bound, both carried or closed over: no literal 7
+        assert not [v for v in compare.invars if hasattr(v, "val")], compare
