@@ -117,8 +117,7 @@ def prometheus_text(registry) -> str:
 
 def json_snapshot(registry) -> Dict[str, Any]:
     """The registry (or a ``MultiRegistry`` view) as a JSON-serializable
-    dict — the shape bench.py embeds in its ``bench_out`` records and
-    chaos summaries print.  Same-name families across member registries
+    dict — the shape chaos summaries print.  Same-name families across member registries
     merge their sample lists."""
     out: Dict[str, Any] = {}
     for group in _grouped_families(registry):

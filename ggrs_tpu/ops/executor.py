@@ -17,15 +17,6 @@ slicing).  A device→host read makes the host wait for everything the
 device has queued — a pipeline stall on any transport — so "no reads on
 the live path" keeps host and device overlapped.  (What a read costs on a
 directly attached chip is not measured yet.)
-
-With a ``speculation`` strategy (``parallel.SpeculativeRollback``) attached,
-the executor keeps K branch trajectories alive between ticks and lets a
-rollback be fulfilled by *branch selection* instead of replay: matching,
-selection, and the fallback replay are ONE fused ``lax.cond`` dispatch
-(``SpeculativeRollback.fulfill``), so the host never reads whether it hit —
-the TPU answer to the reference's rollback hot loop
-(/root/reference/src/sessions/p2p_session.rs:658-714).  Misses cost one
-replay inside that same dispatch — correctness never depends on a hit.
 """
 
 from __future__ import annotations
@@ -34,6 +25,7 @@ from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.types import (
     AdvanceFrame,
@@ -42,10 +34,22 @@ from ..core.types import (
     LoadGameState,
     SaveGameState,
 )
-from ..parallel.spec_rollback import SpeculativeRollback, _stack_pytrees
 from .checksum import DeviceChecksum, checksum_device
 
 InputsToArray = Callable[[Sequence[Tuple[Any, InputStatus]]], Any]
+
+
+def _stack_pytrees(trees: Sequence[Any]) -> Any:
+    """Stack pytrees on a new leading (time) axis, staying on the host when
+    every leaf is NumPy — the single H2D transfer then happens inside the
+    consuming jit instead of as eager device ops."""
+
+    def stack(*leaves: Any) -> Any:
+        if all(isinstance(l, np.ndarray) for l in leaves):
+            return np.stack(leaves)
+        return jnp.stack([jnp.asarray(l) for l in leaves])
+
+    return jax.tree_util.tree_map(stack, *trees)
 
 
 class ExecutorPrograms:
@@ -53,11 +57,10 @@ class ExecutorPrograms:
     single advance, the fused rollback burst, and the checksum — shareable
     across every ``DeviceRequestExecutor`` driving the same game.
 
-    jit caches hang off the wrapped callables, so N peers in one process (or
-    the speculation-on/off variants of a benchmark) that each build their own
-    executor would otherwise compile every program N times.  Build one of
-    these and pass it to each executor's ``programs`` argument to compile
-    once.
+    jit caches hang off the wrapped callables, so N peers in one process that
+    each build their own executor would otherwise compile every program N
+    times.  Build one of these and pass it to each executor's ``programs``
+    argument to compile once.
     """
 
     def __init__(
@@ -99,12 +102,6 @@ class DeviceRequestExecutor:
                        the array ``advance`` consumes (e.g. u8 bitmask vector
                        for BoxGame).  Disconnected players already arrive as
                        default inputs, matching the reference's dummy inputs.
-    ``speculation``    optional ``SpeculativeRollback``: K branch trajectories
-                       that turn a rollback into a device-side select (see
-                       module docstring).  The executor re-anchors the
-                       branches at frame ``load+1`` after every rollback (the
-                       next rollback's steady-state target) and extends them
-                       by one hypothesized frame per executed advance.
     ``programs``       optional shared ``ExecutorPrograms`` (same ``advance``
                        and ``with_checksums``): lets N executors in one
                        process reuse one set of compiled programs.
@@ -116,7 +113,6 @@ class DeviceRequestExecutor:
         init_state: Any,
         inputs_to_array: InputsToArray,
         with_checksums: bool = True,
-        speculation: Optional[SpeculativeRollback] = None,
         programs: Optional[ExecutorPrograms] = None,
     ) -> None:
         if programs is None:
@@ -138,8 +134,6 @@ class DeviceRequestExecutor:
         self._inputs_to_array = inputs_to_array
         self._with_checksums = with_checksums
         self._checksum = programs.checksum
-        self._spec = speculation
-        self._spec_rollbacks = 0  # host-side: rollbacks seen while speculating
         self._burst = programs.burst
 
     # ------------------------------------------------------------------
@@ -174,27 +168,6 @@ class DeviceRequestExecutor:
             )
             outs.append(self._burst(self._state, stacked))
         jax.block_until_ready(outs)
-        if self._spec is not None:
-            # the fused speculation programs (extend, advance+extend, and
-            # per-depth fulfill/refill) compile lazily too — warm them all
-            self._spec.warmup(
-                self._state,
-                example_inputs,
-                range(1, self._spec.max_window + 1),
-                self._with_checksums,
-            )
-
-    @property
-    def spec_hits(self) -> int:
-        """Rollbacks fulfilled by a branch hit.  Reads the device counter —
-        call outside timed paths."""
-        return 0 if self._spec is None else self._spec.hits
-
-    @property
-    def spec_misses(self) -> int:
-        """Rollbacks that fell back to replay (including windows the host
-        already knew were unanswerable)."""
-        return self._spec_rollbacks - self.spec_hits
 
     def run(self, requests: List[GgrsRequest]) -> None:
         """Execute a session's request list in order."""
@@ -204,20 +177,11 @@ class DeviceRequestExecutor:
             req = requests[i]
             if isinstance(req, SaveGameState):
                 self._do_save(req)
-                if self._spec is not None and self._spec.root_frame is None:
-                    self._spec.root(req.frame, self._state)
                 i += 1
             elif isinstance(req, LoadGameState):
+                self._do_load(req)
                 pairs, saves, i = self._collect_burst(requests, i + 1)
-                if self._spec is not None and pairs:
-                    self._run_rollback_spec(req, pairs, saves)
-                else:
-                    if self._spec is not None:
-                        # a rollback we can't resolve disproves the predicted
-                        # inputs the branch prefixes were validated against
-                        self._spec.invalidate()
-                    self._do_load(req)
-                    self._run_pairs(pairs, saves)
+                self._run_pairs(pairs, saves)
             elif isinstance(req, AdvanceFrame):
                 pairs, saves, i = self._collect_burst(requests, i)
                 self._run_pairs(pairs, saves)
@@ -247,19 +211,16 @@ class DeviceRequestExecutor:
         self,
         pairs: List[AdvanceFrame],
         saves: List[Optional[SaveGameState]],
-        arrays: Optional[List[Any]] = None,
-    ) -> List[Tuple[int, SaveGameState, Any]]:
-        """Execute an (Advance, Save?)* run, fused when it's a real burst.
-        Returns the fulfilled saves as ``(pair_index, request, snapshot)``."""
+    ) -> None:
+        """Execute an (Advance, Save?)* run, fused when it's a real burst."""
         if not pairs:
-            return []
+            return
         if len(pairs) == 1:
-            self._do_advance(pairs[0], inputs=arrays[0] if arrays else None)
+            self._do_advance(pairs[0])
             if saves[0] is not None:
                 self._do_save(saves[0])
-                return [(0, saves[0], self._state)]
-            return []
-        return self._do_burst(pairs, saves, arrays=arrays)
+            return
+        self._do_burst(pairs, saves)
 
     # ------------------------------------------------------------------
 
@@ -276,111 +237,25 @@ class DeviceRequestExecutor:
         assert data is not None, f"loading frame {req.frame} from an empty cell"
         self._state = data
 
-    def _do_advance(self, req: AdvanceFrame, inputs: Any = None) -> None:
-        if inputs is None:
-            inputs = self._inputs_to_array(req.inputs)
-        if self._spec is not None:
-            # live advance + K branch extensions fused into one dispatch
-            nxt = self._spec.advance_and_extend(self._state, inputs)
-            if nxt is not None:
-                self._state = nxt
-                return
-        self._state = self._advance(self._state, inputs)
+    def _do_advance(self, req: AdvanceFrame) -> None:
+        self._state = self._advance(
+            self._state, self._inputs_to_array(req.inputs)
+        )
 
     def _do_burst(
         self,
         pairs: List[AdvanceFrame],
         saves: List[Optional[SaveGameState]],
-        arrays: Optional[List[Any]] = None,
-    ) -> List[Tuple[int, SaveGameState, Any]]:
+    ) -> None:
         """(Advance, Save?)×N as one scan dispatch; save cells receive the
-        per-step jit outputs directly (device handles, lazy checksums).
-        Returns the fulfilled saves as ``(pair_index, request, snapshot)`` so
-        callers can re-anchor speculation without refetching."""
-        if arrays is None:
-            arrays = [self._inputs_to_array(p.inputs) for p in pairs]
-        # host-side stack when the arrays are NumPy: the single H2D then
-        # happens inside the fused call instead of as eager device ops
-        stacked = _stack_pytrees(arrays)
+        per-step jit outputs directly (device handles, lazy checksums)."""
+        stacked = _stack_pytrees(
+            [self._inputs_to_array(p.inputs) for p in pairs]
+        )
         final, steps, sums = self._burst(self._state, stacked)
         self._state = final
-        if self._spec is not None:
-            # keep the one-extend-per-executed-advance invariant fulfill()
-            # depends on (no-op while unrooted, e.g. on the rollback miss path)
-            for arr in arrays:
-                self._spec.extend(arr)
-        fulfilled: List[Tuple[int, SaveGameState, Any]] = []
         for k, save in enumerate(saves):
             if save is None:
                 continue
             cs = DeviceChecksum(sums[k]) if self._with_checksums else None
             save.cell.save(save.frame, steps[k], cs)
-            fulfilled.append((k, save, steps[k]))
-        return fulfilled
-
-    # ------------------------------------------------------------------
-    # speculative rollback fulfillment
-    # ------------------------------------------------------------------
-
-    def _run_rollback_spec(
-        self,
-        load: LoadGameState,
-        pairs: List[AdvanceFrame],
-        saves: List[Optional[SaveGameState]],
-    ) -> None:
-        """Fulfill ``Load + (Advance, Save?)*`` with one fused
-        resolve-or-replay dispatch when the speculation window can answer it;
-        otherwise fall back to load + fused replay.
-
-        The burst's trailing advance carries the *live* (not resimulated)
-        frame exactly when it has no trailing save — the session always saves
-        the current frame before the live advance — so the resolve window is
-        all advances except a saveless last one.  (When every advance has a
-        save — e.g. sparse saving hit the threshold — treating them all as
-        resim frames is equally correct: the fused program only selects
-        branches whose inputs are bit-equal, so trajectory states equal
-        replay states.)
-        """
-        g = load.frame
-        m = len(pairs)
-        n_resim = m if saves[-1] is not None else m - 1
-        arrays = [self._inputs_to_array(p.inputs) for p in pairs]
-        self._spec_rollbacks += 1
-
-        if n_resim >= 1 and self._spec.window_valid(g, n_resim):
-            # ONE dispatch for the whole rollback TICK: hypothesis match +
-            # branch select (or the fallback replay — the host never reads
-            # which), re-anchoring the branches at frame g+1, re-hypothesizing
-            # the still-unconfirmed tail, and — when the burst has a trailing
-            # saveless live advance — that advance plus its window extension.
-            has_live = n_resim < m
-            out = self._spec.fulfill_and_refill(
-                g,
-                arrays[:n_resim],
-                load.cell.data(),
-                self._with_checksums,
-                live_inputs=arrays[-1] if has_live else None,
-            )
-            steps, sums = out[0], out[1]
-            for j in range(n_resim):
-                if saves[j] is not None:
-                    cs = (
-                        DeviceChecksum(sums[j])
-                        if self._with_checksums
-                        else None
-                    )
-                    saves[j].cell.save(saves[j].frame, steps[j], cs)
-            self._state = out[2] if has_live else steps[n_resim - 1]
-        else:
-            # window can't answer this rollback (host-known): the rollback
-            # disproved the predicted inputs the prefixes were validated
-            # against — invalidate, replay, and re-anchor at the first saved
-            # frame of the burst.
-            self._spec.invalidate()
-            self._do_load(load)
-            fulfilled = self._run_pairs(pairs, saves, arrays=arrays)
-            if fulfilled:
-                j0, save0, snap0 = fulfilled[0]
-                self._spec.root(save0.frame, snap0)
-                for arr in arrays[j0 + 1 :]:
-                    self._spec.extend(arr)

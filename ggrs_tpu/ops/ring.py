@@ -117,10 +117,11 @@ class DeviceStateRing:
         of 19 writes a tick at 819 GB/s, against 92 ms for the scatter).
         That is the wrong trade once B x R x state bytes reaches gigabytes
         (ROADMAP B2/M7): choose the write there from those three numbers,
-        which this method can see.  Measured there (``particles-2p``, PR 29):
-        512 x 10 x 520,028 B = 2.66 GB a write, 11 writes a tick, a tick
-        program of 198 ms of which the saves are 57.5% and their
-        digests 23.3% (PERF.md section 5)."""
+        which this method can see.  Measured there (``particles-2p``):
+        512 x 10 x 520,028 B = 2.66 GB a write; 11 writes a tick and a tick
+        program of 198 ms at PR 29, 3 writes (pre-save, 2 burst steps) and
+        68.65 ms since PR 30 ends the burst at the batch's deepest plan
+        (PERF.md section 5)."""
         hit = (
             jnp.arange(self.length, dtype=jnp.int32) == self.slot(frame)
         ) & pred

@@ -5,8 +5,6 @@ The reference is a single-threaded Rust library with no parallelism at all
 design, not ports:
 
 - **temporal** — the rollback replay as ``lax.scan`` (ggrs_tpu.ops.replay);
-- **speculative** — ``vmap`` over K predicted-input branches with post-hoc
-  selection on confirmed inputs (``speculation``);
 - **session** — ``shard_map`` batching of many independent sessions across a
   device mesh with ICI collectives for global health counters (``batch``),
   plus massed request fulfillment for LIVE heterogeneous sessions — B
@@ -18,8 +16,6 @@ design, not ports:
   this by construction, e.g. BoxGame's (P, ...) arrays).
 """
 
-from .speculation import SpeculativeBranches, build_speculation_programs
-from .spec_rollback import SpeculativeRollback
 from .batch import (
     BatchedSessions,
     HOST_AXIS,
@@ -38,9 +34,6 @@ __all__ = [
     "BatchedSessions",
     "HOST_AXIS",
     "SESSION_AXIS",
-    "SpeculativeBranches",
-    "SpeculativeRollback",
-    "build_speculation_programs",
     "make_distributed_mesh",
     "make_mesh",
     "make_mesh2d",

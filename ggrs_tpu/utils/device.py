@@ -91,7 +91,7 @@ def place_compile_cache() -> Optional[str]:
     """Point JAX's persistent compilation cache at its one directory and
     return that directory (None: no persistent cache).  Call once, before
     the first compile, from every entry point that compiles
-    (``chip_smoke.py``, ``bench.py`` children, the examples); tests do not
+    (``chip_smoke.py``, ``benchmark/run.py``, the examples); tests do not
     call it.  Initialises the backend.
 
     If ``JAX_COMPILATION_CACHE_DIR`` is set JAX has already read it and no

@@ -17,8 +17,8 @@ Five gates, all source-level (DESIGN.md §20, §22):
   transitions  ggrs-model conformance: every fleet-layer state-setter
                site performs an edge of the declared SLOT_/PROC_/
                SHARD_TRANSITIONS tables
-  hygiene      no generated artifacts (__pycache__, *.pyc, *.so,
-               bench_out) tracked by git; .gitignore keeps covering them
+  hygiene      no generated artifacts (__pycache__, *.pyc, *.so) tracked
+               by git; .gitignore keeps covering them
 
 plus, with --model, the exploration leg: the §9/§16/§17 protocol
 models from analysis/machines.py are explored breadth-first under a
@@ -83,18 +83,14 @@ def check_hygiene(analysis) -> list:
     except (subprocess.SubprocessError, OSError):
         return []  # not a git checkout: nothing to police
     for path in tracked:
-        if (
-            "__pycache__" in path
-            or path.endswith((".pyc", ".so"))
-            or path.startswith("bench_out/")
-        ):
+        if "__pycache__" in path or path.endswith((".pyc", ".so")):
             findings.append(Finding(
                 "hygiene/tracked-artifact", path, 0,
                 "generated artifact is tracked by git",
             ))
     gitignore = (REPO / ".gitignore")
     rules = gitignore.read_text().splitlines() if gitignore.exists() else []
-    for needed in ("__pycache__/", "*.pyc", "*.so", "bench_out/"):
+    for needed in ("__pycache__/", "*.pyc", "*.so"):
         if needed not in rules:
             findings.append(Finding(
                 "hygiene/gitignore", ".gitignore", 0,

@@ -3,8 +3,8 @@
 The r3 perf redesign made save checksums lazy (``DeviceChecksum`` handles
 that materialize only when the desync exchange reports one).  These tests
 close the loop the unit tests can't: two live P2P peers fulfilled by device
-executors — one speculating — exchange real checksum reports through the
-session's interval machinery, and synchronized simulations must produce ZERO
+executors exchange real checksum reports through the session's interval
+machinery, and synchronized simulations must produce ZERO
 DesyncDetected events (while a deliberately corrupted peer must produce
 one).  Reference flow: /root/reference/src/sessions/p2p_session.rs:904-975.
 """
@@ -17,7 +17,6 @@ from ggrs_tpu.core import DesyncDetected, DesyncDetection, Local, Remote
 from ggrs_tpu.games import BoxGame, boxgame_config
 from ggrs_tpu.net import InMemoryNetwork
 from ggrs_tpu.ops import DeviceRequestExecutor
-from ggrs_tpu.parallel import SpeculativeRollback
 from ggrs_tpu.sessions import SessionBuilder
 
 
@@ -29,7 +28,7 @@ def _b_sched(i):
     return (i // 3) % 16  # transitions force regular rollbacks
 
 
-def _make_pair(interval=10, speculate=True):
+def _make_pair(interval=10):
     game = BoxGame(2)
     net = InMemoryNetwork()
     sessions, executors = [], []
@@ -43,18 +42,8 @@ def _make_pair(interval=10, speculate=True):
             .add_player(Remote(other), 1 - local_handle)
             .start_p2p_session(net.socket(me))
         )
-        spec = None
-        if speculate and me == "A":
-            def branch_inputs(k, frame, arr):
-                out = np.array(arr, np.uint8, copy=True)
-                if k:
-                    out[1] = np.uint8(_b_sched(frame))
-                return out
-
-            spec = SpeculativeRollback(game.advance, 2, branch_inputs, max_window=8)
         executors.append(
-            DeviceRequestExecutor(game.advance, game.init_state(), _to_arr,
-                                  speculation=spec)
+            DeviceRequestExecutor(game.advance, game.init_state(), _to_arr)
         )
         sessions.append(sess)
     return game, sessions, executors
@@ -74,10 +63,9 @@ def _drive(sessions, executors, ticks):
 class TestDeviceExecutorDesyncExchange:
     def test_synchronized_peers_report_no_desync(self):
         """Lazy device checksums materialize at the send interval, cross the
-        wire as u128s, and compare equal — for both the speculating peer
-        (whose save cells are filled from branch trajectories) and the
-        replaying peer."""
-        game, sessions, executors = _make_pair(interval=10, speculate=True)
+        wire as u128s, and compare equal on both peers (save cells filled by
+        single saves and by the fused burst's per-step outputs)."""
+        game, sessions, executors = _make_pair(interval=10)
         events = _drive(sessions, executors, 80)
         for p in (0, 1):
             desyncs = [e for e in events[p] if isinstance(e, DesyncDetected)]
@@ -92,7 +80,7 @@ class TestDeviceExecutorDesyncExchange:
         the reference's frame-200 desync test)."""
         import jax.numpy as jnp
 
-        game, sessions, executors = _make_pair(interval=5, speculate=False)
+        game, sessions, executors = _make_pair(interval=5)
         _drive(sessions, executors, 30)
         # nudge B's simulation off-course (bit-level corruption)
         ex_b = executors[1]

@@ -1,21 +1,13 @@
-"""Lazy device checksums and the fused speculation dispatches.
+"""Lazy device checksums.
 
 Round-3 perf redesign contract: the executor's save path attaches
 ``DeviceChecksum`` handles (no device→host read until the value is actually
-consumed), and speculation's steady-state tick / rollback fulfillment are
-single fused dispatches whose results are bit-identical to the unfused
-primitives they replaced."""
-
-import numpy as np
-
-import jax
-import jax.numpy as jnp
+consumed)."""
 
 from ggrs_tpu.core.sync_layer import GameStateCell
 from ggrs_tpu.games import BoxGame
 from ggrs_tpu.ops import pytree_checksum
 from ggrs_tpu.ops.checksum import DeviceChecksum, checksum_device
-from ggrs_tpu.parallel import SpeculativeRollback
 
 
 class TestDeviceChecksum:
@@ -47,125 +39,3 @@ class TestDeviceChecksum:
         state = BoxGame(2).init_state()
         lazy = DeviceChecksum(checksum_device(state))
         assert lazy == pytree_checksum(state)
-
-
-def _mk_spec(game, K=3):
-    candidates = np.asarray([0, 4, 8], np.uint8)
-
-    def branch_inputs(k, frame, local_inputs):
-        out = np.array(np.asarray(local_inputs), np.uint8, copy=True)
-        out[1] = candidates[k]
-        return out
-
-    return SpeculativeRollback(game.advance, K, branch_inputs, max_window=8)
-
-
-class TestFusedSpeculation:
-    def test_advance_and_extend_matches_separate_calls(self):
-        game = BoxGame(2)
-        state = game.init_state()
-        spec_a, spec_b = _mk_spec(game), _mk_spec(game)
-        spec_a.root(0, state)
-        spec_b.root(0, state)
-
-        live_a = state
-        live_b = state
-        for i in range(4):
-            inp = np.asarray([i % 3, 4], np.uint8)
-            fused = spec_a.advance_and_extend(live_a, inp)
-            assert fused is not None
-            live_a = fused
-            live_b = game.advance(live_b, inp)
-            spec_b.extend(inp)
-
-        assert spec_a.window == spec_b.window == 4
-        for k in ("pos", "vel", "rot"):
-            np.testing.assert_array_equal(
-                np.asarray(live_a[k]), np.asarray(live_b[k]), err_msg=k
-            )
-        # both windows resolve identically (remote candidate 4 was correct)
-        confirmed = [
-            np.asarray([i % 3, 4], np.uint8) for i in range(4)
-        ]
-        ta = spec_a.resolve(0, confirmed)
-        tb = spec_b.resolve(0, confirmed)
-        assert ta is not None and tb is not None
-        for k in ("pos", "vel", "rot"):
-            np.testing.assert_array_equal(
-                np.asarray(ta[-1][k]), np.asarray(tb[-1][k]), err_msg=k
-            )
-
-    def test_advance_and_extend_none_when_unrooted_or_full(self):
-        game = BoxGame(2)
-        state = game.init_state()
-        spec = _mk_spec(game)
-        inp = np.asarray([1, 4], np.uint8)
-        assert spec.advance_and_extend(state, inp) is None  # unrooted
-        spec.root(0, state)
-        for _ in range(8):
-            assert spec.advance_and_extend(state, inp) is not None
-        assert spec.window == 8
-        assert spec.advance_and_extend(state, inp) is None  # window full
-
-    def test_fulfill_hit_matches_replay_and_counts(self):
-        game = BoxGame(2)
-        state = game.init_state()
-        spec = _mk_spec(game)
-        spec.root(0, state)
-        seq = [np.asarray([i, 4], np.uint8) for i in (1, 2, 3)]
-        for s in seq:
-            spec.extend(s)
-
-        assert spec.window_valid(0, 3)
-        steps, sums = spec.fulfill(0, seq, state, with_checksums=True)
-        assert len(steps) == 3 and len(sums) == 3
-        truth = state
-        for t, s in enumerate(seq):
-            truth = game.advance(truth, s)
-            for k in ("pos", "vel", "rot"):
-                np.testing.assert_array_equal(
-                    np.asarray(steps[t][k]), np.asarray(truth[k]), err_msg=k
-                )
-            assert DeviceChecksum(sums[t]) == pytree_checksum(truth)
-        assert spec.hits == 1
-
-    def test_fulfill_miss_replays_from_load_state(self):
-        game = BoxGame(2)
-        state = game.init_state()
-        spec = _mk_spec(game)
-        spec.root(0, state)
-        hyp = [np.asarray([1, 4], np.uint8)]
-        spec.extend(hyp[0])
-        # confirmed remote input 15 matches no candidate: the fused cond must
-        # fall back to replaying load_state under the confirmed inputs
-        confirmed = [np.asarray([1, 15], np.uint8)]
-        steps, _ = spec.fulfill(0, confirmed, state, with_checksums=False)
-        truth = game.advance(state, confirmed[0])
-        for k in ("pos", "vel", "rot"):
-            np.testing.assert_array_equal(
-                np.asarray(steps[0][k]), np.asarray(truth[k]), err_msg=k
-            )
-        assert spec.hits == 0
-
-    def test_refill_reanchors_window(self):
-        game = BoxGame(2)
-        state = game.init_state()
-        spec = _mk_spec(game)
-        spec.root(0, state)
-        seq = [np.asarray([i, 4], np.uint8) for i in (1, 2, 3)]
-        for s in seq:
-            spec.extend(s)
-        steps, _ = spec.fulfill(0, seq, state, with_checksums=False)
-        # re-anchor at frame 1 with the remaining tail hypothesized again
-        spec.refill(1, steps[0], seq[1:])
-        assert spec.root_frame == 1 and spec.window == 2
-        # the refilled window must resolve the same tail
-        traj = spec.resolve(1, seq[1:])
-        assert traj is not None
-        truth = state
-        for s in seq:
-            truth = game.advance(truth, s)
-        for k in ("pos", "vel", "rot"):
-            np.testing.assert_array_equal(
-                np.asarray(traj[-1][k]), np.asarray(truth[k]), err_msg=k
-            )

@@ -1,4 +1,4 @@
-"""Speculative branch execution (vmap) and batched sessions (shard_map).
+"""Batched sessions (shard_map).
 
 Runs on the virtual 8-device CPU mesh set up in conftest.py."""
 
@@ -11,7 +11,6 @@ import jax.numpy as jnp
 from ggrs_tpu.games import BoxGame
 from ggrs_tpu.parallel import (
     BatchedSessions,
-    build_speculation_programs,
     make_mesh,
     make_mesh2d,
 )
@@ -20,60 +19,6 @@ from ggrs_tpu.parallel import (
 def _random_inputs(shape, seed=0):
     rng = np.random.default_rng(seed)
     return rng.integers(0, 16, size=shape).astype(np.uint8)
-
-
-class TestSpeculation:
-    def setup_method(self):
-        self.game = BoxGame(2)
-        self.spec = build_speculation_programs(self.game.advance, num_branches=4)
-
-    def _branch_inputs(self, w, seed=0):
-        """[K, W, P] input windows; branch 2 will be 'correct'."""
-        inputs = _random_inputs((4, w, 2), seed=seed)
-        return jnp.asarray(inputs)
-
-    def test_matching_branch_selected(self):
-        w = 6
-        base = self.game.init_state()
-        inputs_kw = self._branch_inputs(w, seed=3)
-        branches = self.spec.speculate_window(base, inputs_kw)
-        confirmed = inputs_kw[2]  # branch 2 guessed right
-        state, idx, found = self.spec.resolve(branches, inputs_kw, confirmed)
-        assert bool(found)
-        assert int(idx) == 2
-        # selected state equals a plain replay under the confirmed inputs
-        replayed = self.spec.replay_window(base, confirmed)
-        for k in ("pos", "vel", "rot"):
-            np.testing.assert_array_equal(
-                np.asarray(state[k]), np.asarray(replayed[k])
-            )
-
-    def test_no_match_reports_not_found(self):
-        w = 4
-        base = self.game.init_state()
-        inputs_kw = self._branch_inputs(w, seed=5)
-        branches = self.spec.speculate_window(base, inputs_kw)
-        confirmed = jnp.full((w, 2), 255, jnp.uint8)  # matches no branch
-        _, _, found = self.spec.resolve(branches, inputs_kw, confirmed)
-        assert not bool(found)
-
-    def test_branches_diverge(self):
-        w = 8
-        base = self.game.init_state()
-        inputs_kw = self._branch_inputs(w, seed=7)
-        branches = self.spec.speculate_window(base, inputs_kw)
-        pos = np.asarray(branches["pos"])  # [K, P, 2]
-        assert not np.array_equal(pos[0], pos[1])
-
-    def test_collapse_picks_branch(self):
-        base = self.game.init_state()
-        inputs_kw = self._branch_inputs(3, seed=9)
-        branches = self.spec.speculate_window(base, inputs_kw)
-        picked = self.spec.collapse(branches, jnp.int32(1))
-        for k in ("pos", "vel", "rot"):
-            np.testing.assert_array_equal(
-                np.asarray(picked[k]), np.asarray(branches[k])[1]
-            )
 
 
 class TestBatchedSessions:

@@ -4,9 +4,8 @@ history aliasing after frame wrap — only surface at 10^5+ frames, a horizon
 the reference's tests never reach (/root/reference/tests/test_p2p_session.rs
 runs hundreds of frames).
 
-The harnesses live in bench.py (``p2p_soak`` / ``pool_soak``) and are shared
-verbatim with the recorded `bench.py soak` metrics, so the test tier and the
-bench line certify the same behavior.  Tiers:
+The harnesses (``p2p_soak`` / ``pool_soak``) live in tests/soak_harness.py and
+assert convergence themselves.  Tiers:
 
   - test_p2p_soak_100k_frames: two peers over the seeded fault net for 1e5
     frames with desync detection on; bit-exact convergence at every settled
@@ -21,14 +20,9 @@ Both are marked ``soak`` — deselect with ``-m "not soak"`` when iterating.
 
 from __future__ import annotations
 
-import sys
-from pathlib import Path
-
 import pytest
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-from bench import p2p_soak, pool_soak  # noqa: E402
+from soak_harness import p2p_soak, pool_soak
 
 pytestmark = pytest.mark.soak
 

@@ -3,7 +3,7 @@
 Tier-1 runs on the CPU backend, where wall-clock says nothing about the
 chip, so perf regressions on the flagship replay and the batched-session
 tick are pinned STRUCTURALLY instead, extending the pattern of
-tests/test_spec_integration.py's dispatch pins:
+tests/test_device_executor_p2p.py's dispatch pin:
 
 - dispatch-count pins: a steady-state chunk is exactly ONE jitted call
   (catches per-tick dispatching, chunk splitting, accidental warmup
@@ -21,6 +21,11 @@ tests/test_spec_integration.py's dispatch pins:
   scatter as a serial loop over the sessions (PERF §6, PR 26: 67 of a 68 ms
   tick).
 
+- source pins (no program compiled): the package graph and the census of
+  environment switches, each held to ONE table in ``docs/DESIGN.md`` (§1
+  "The package graph", §29), so the document and the test are the same
+  table.
+
 Known limitation, measured while building these: the ~30x
 shared-vs-per-session ring-index regression of the REPLAY path
 (ReplayPrograms docstring) is invisible to primitive counts — there both
@@ -31,7 +36,11 @@ bench deltas, not by these pins.
 
 from __future__ import annotations
 
+import ast
+import functools
+import re
 from collections import Counter
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -263,3 +272,151 @@ class TestPoolTickProgramPins:
         assert compare.primitive.name == "lt", compare
         # counter < bound, both carried or closed over: no literal 7
         assert not [v for v in compare.invars if hasattr(v, "val")], compare
+
+
+# ----------------------------------------------------------------------
+# source pins: the package graph and the switch census, against DESIGN.md
+# ----------------------------------------------------------------------
+
+REPO = Path(__file__).resolve().parents[1]
+PACKAGE = REPO / "ggrs_tpu"
+# every top-level entry of the package: its twelve sub-packages and the two
+# modules beside them (``chaos``, ``__init__``)
+UNITS = sorted(
+    p.stem if p.is_file() else p.name
+    for p in PACKAGE.iterdir()
+    if p.suffix == ".py" or (p / "__init__.py").is_file()
+)
+
+
+def _design_table(heading: str) -> list:
+    """The rows (lists of cells) of the first table under ``heading`` in
+    docs/DESIGN.md, header and ruler dropped."""
+    lines = (REPO / "docs" / "DESIGN.md").read_text().splitlines()
+    at = lines.index(heading)
+    start = next(i for i in range(at, len(lines)) if lines[i].startswith("|"))
+    rows = []
+    for line in lines[start:]:
+        if not line.startswith("|"):
+            break
+        rows.append([c.strip() for c in line.strip("|").split("|")])
+    return rows[2:]
+
+
+def _unit_arrows(unit: str) -> dict:
+    """``{imported unit: {importing files}}`` over every ``import`` statement
+    of ``unit``'s files (function-level ones too), itself left out."""
+    files = (
+        [PACKAGE / f"{unit}.py"]
+        if (PACKAGE / f"{unit}.py").is_file()
+        else sorted((PACKAGE / unit).rglob("*.py"))
+    )
+    arrows: dict = {}
+    for path in files:
+        here = ("ggrs_tpu",) + path.relative_to(PACKAGE).parts[:-1]
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Import):
+                targets = [a.name.split(".") for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                # level 1 starts from the file's own package
+                base = list(here[: len(here) - node.level + 1]) if node.level else []
+                base += node.module.split(".") if node.module else []
+                # ``from .. import x`` names the unit in the alias
+                targets = (
+                    [base + [a.name] for a in node.names]
+                    if len(base) == 1 else [base]
+                )
+            else:
+                continue
+            for target in targets:
+                if target[0] == "ggrs_tpu" and len(target) > 1 and target[1] != unit:
+                    arrows.setdefault(target[1], set()).add(
+                        str(path.relative_to(PACKAGE))
+                    )
+    return arrows
+
+
+class TestPackageGraph:
+    """docs/DESIGN.md §1 "The package graph": bottom to top, each row lists
+    what the unit may import, and only rows above it — a total order, so no
+    cycle but the exception the table names."""
+
+    @pytest.fixture(scope="class")
+    def table(self):
+        rows = _design_table("### The package graph")
+        table = {}
+        for unit_cell, may_cell, _ in rows:
+            (unit,) = re.findall(r"`(\w+)`", unit_cell)
+            allowed, _, exception = may_cell.partition("exception:")
+            assert set(re.findall(r"`(\w+)`", allowed)) <= set(table), (
+                f"{unit}'s row names a unit that is not above it"
+            )
+            table[unit] = (
+                set(re.findall(r"`(\w+)`", allowed)),
+                re.findall(r"`([\w/.]+)`", exception),
+            )
+        return table
+
+    @pytest.mark.parametrize("unit", UNITS)
+    def test_unit_imports_only_what_its_row_lists(self, table, unit):
+        assert unit in table, f"{unit} has no row in DESIGN.md's package table"
+        allowed, exception = table[unit]
+        arrows = _unit_arrows(unit)
+        if exception:
+            target, source = exception
+            assert arrows.pop(target, None) == {source}, (
+                f"the named exception {source} -> {target} is not what the "
+                f"tree holds: delete it from the table or restore it"
+            )
+        assert set(arrows) <= allowed, {
+            t: sorted(arrows[t]) for t in set(arrows) - allowed
+        }
+
+
+@functools.cache
+def _switches_read() -> dict:
+    """``{GGRS_TPU_* name: {files}}`` for every such string constant under
+    ``ggrs_tpu/`` (a name in a docstring or a message is part of a longer
+    string and does not match)."""
+    found: dict = {}
+    for path in sorted(PACKAGE.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (
+                isinstance(node, ast.Constant)
+                and isinstance(node.value, str)
+                and re.fullmatch(r"GGRS_TPU_[A-Z0-9_]+", node.value)
+            ):
+                found.setdefault(node.value, set()).add(
+                    str(path.relative_to(REPO))
+                )
+    return found
+
+
+class TestSwitchCensus:
+    """docs/DESIGN.md §29: every ``GGRS_TPU_*`` variable the package reads
+    has a row saying what it forces and who needs it, and no row outlives
+    its reader."""
+
+    @pytest.fixture(scope="class")
+    def table(self):
+        rows = _design_table("## 29. Environment switches")
+        return {
+            re.fullmatch(r"`(GGRS_TPU_\w+)`", name).group(1): (forces, needs)
+            for name, forces, needs in rows
+        }
+
+    @pytest.mark.parametrize("switch", sorted(_switches_read()))
+    def test_switch_has_a_row(self, table, switch):
+        assert switch in table, (
+            f"{switch} is read by {sorted(_switches_read()[switch])} and has "
+            f"no row in DESIGN.md §29: say what it forces and who needs it"
+        )
+        forces, needs = table[switch]
+        assert forces and needs
+        for user in re.findall(r"`((?:tests|scripts)/[\w/.]+)`", needs):
+            assert switch in (REPO / user).read_text(), (
+                f"§29 names {user} as a user of {switch}; it is not"
+            )
+
+    def test_no_row_without_a_reader(self, table):
+        assert set(table) <= set(_switches_read())

@@ -14,9 +14,7 @@ The acceptance pins, mirrored by ``scripts/chaos.py --fault net`` and
 * the plane is strictly piggyback — ZERO extra ctypes crossings per
   tick (the pool crossing budget is unchanged with the timeline sink
   installed and firing) and ZERO extra RPC round trips (the op set of
-  the RPC latency histogram is exactly the serving path's);
-* ``scripts/bench_report.py`` normalizes BENCH rounds and gates on p99
-  regressions vs the best prior comparable round.
+  the RPC latency histogram is exactly the serving path's).
 """
 
 from __future__ import annotations
@@ -495,67 +493,8 @@ class TestDesyncReportTimeline:
 
 
 # ----------------------------------------------------------------------
-# scripts: bench_report gate, match_timeline extraction, fleet_top render
+# scripts: match_timeline extraction, fleet_top render
 # ----------------------------------------------------------------------
-
-
-def _bench_round(tmp_path, n, metrics, rc=0):
-    lines = [json.dumps({"metric": m, "value": v, "unit": "ms",
-                         "vs_baseline": 1.0}) for m, v in metrics]
-    (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps({
-        "n": n, "cmd": ["x"], "rc": rc, "tail": "\n".join(lines),
-    }))
-
-
-class TestBenchReport:
-    def setup_method(self):
-        self.mod = _load_script("bench_report")
-
-    def test_trajectory_and_gate_ok(self, tmp_path):
-        _bench_round(tmp_path, 1, [("tick_ms_p99", 10.0),
-                                   ("throughput", 100.0)])
-        _bench_round(tmp_path, 2, [("tick_ms_p99", 10.5)])
-        rounds = self.mod.load_rounds(str(tmp_path))
-        traj = self.mod.trajectory(rounds)
-        assert [r["value"] for r in traj["tick_ms_p99"]] == [10.0, 10.5]
-        assert traj["throughput"][0]["p99"] is False
-        assert self.mod.gate(traj) == []          # +5% < 15% tolerance
-        text = self.mod.render(rounds, traj, [], 0.15)
-        assert "GATE: ok" in text and "r01" in text
-
-    def test_gate_fires_beyond_threshold_vs_best_prior(self, tmp_path):
-        # best PRIOR round (r1), not the immediately previous one (r2)
-        _bench_round(tmp_path, 1, [("tick_ms_p99", 10.0)])
-        _bench_round(tmp_path, 2, [("tick_ms_p99", 14.0)])
-        _bench_round(tmp_path, 3, [("tick_ms_p99", 12.0)])
-        traj = self.mod.trajectory(self.mod.load_rounds(str(tmp_path)))
-        regs = self.mod.gate(traj, threshold=0.15)
-        assert len(regs) == 1
-        assert regs[0]["best_prior_round"] == 1
-        assert regs[0]["ratio"] == pytest.approx(1.2)
-
-    def test_non_p99_metrics_never_gate(self, tmp_path):
-        _bench_round(tmp_path, 1, [("throughput", 100.0)])
-        _bench_round(tmp_path, 2, [("throughput", 10.0)])
-        traj = self.mod.trajectory(self.mod.load_rounds(str(tmp_path)))
-        assert self.mod.gate(traj) == []
-
-    def test_timeout_round_is_dataless_not_a_regression(self, tmp_path):
-        _bench_round(tmp_path, 1, [("tick_ms_p99", 10.0)])
-        _bench_round(tmp_path, 2, [], rc=124)
-        rounds = self.mod.load_rounds(str(tmp_path))
-        assert self.mod.gate(self.mod.trajectory(rounds)) == []
-        assert "timeout" in self.mod.render(
-            rounds, self.mod.trajectory(rounds), [], 0.15)
-
-    def test_repo_bench_files_all_parse(self):
-        # the real rounds (r03..r11): every file loads, r05 (rc=124) is
-        # data-less
-        rounds = self.mod.load_rounds(str(REPO))
-        assert len(rounds) >= 9
-        by_n = {r["round"]: r for r in rounds}
-        assert by_n[5]["records"] == [] and by_n[5]["rc"] == 124
-        assert sum(len(r["records"]) for r in rounds) > 40
 
 
 class TestMatchTimelineScript:
