@@ -328,6 +328,13 @@ def leg_pool(size: Dict[str, int], seed: int, chips: int,
         "rollback_loads": rollback_loads, "ticks_with_burst_gt1": deep_ticks,
         "compiles_in_ticks": compiled_in_ticks,
         "carry_devices": shard_devices,
+        # what a device holds of the carry, and how much of it the executor
+        # keeps row-major between ticks (DESIGN.md section 3): 0 here, the
+        # BoxGame ring is far under the rule
+        "ring_resident_bytes": int(
+            default_registry().value("ggrs_executor_ring_resident_bytes")),
+        "ring_relaid_bytes": int(
+            default_registry().value("ggrs_executor_ring_relaid_bytes")),
     }
 
 

@@ -18,6 +18,13 @@ Two forms of write, by who indexes (docs/DESIGN.md §3):
   as a serial loop over the sessions.
 
 *load* is a dynamic slice (a gather under a per-session frame).
+
+A ring is logical shapes only: in which layout the device holds a leaf
+between ticks is chosen where the carry is built, not here
+(``parallel/session_pool.py`` ``ring_leaf_layout``: a large, lane-wide leaf
+of the served pool is held row-major, so that it crosses the tick program's
+boundary without a transposition; DESIGN §3 "The ring's layout at the
+program's boundary").
 """
 
 from __future__ import annotations

@@ -33,17 +33,33 @@ Saved states live in per-session device rings ``[B, R, ...]`` tagged with
 frame numbers and (optionally) 4-lane digests; ``GameStateCell``s are
 fulfilled with lazy slot references and lazy checksums, so desync detection
 and user ``cell.load()`` work unchanged while the live path performs ZERO
-device→host reads.
+device→host reads.  The carry is donated to every tick; a large ring leaf
+is made, passed and returned in the layout the tick program computes in
+(``ring_leaf_layout`` below, chosen per leaf from its shape where
+``tick_program`` builds the carry), so no whole-ring transposition stands
+at the program's entry or exit.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+import contextlib
+import math
+from typing import (
+    Any,
+    Callable,
+    Dict,
+    List,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 
 from ..core.types import (
     AdvanceFrame,
@@ -79,6 +95,12 @@ _OBS_RING_RESIDENT_BYTES = default_registry().gauge(
     "bytes the newest pooled executor's carry holds on its fullest device: "
     "ring slots, live states, kept digests and frames",
 )
+_OBS_RING_RELAID_BYTES = default_registry().gauge(
+    "ggrs_executor_ring_relaid_bytes",
+    "bytes, on its fullest device, of the ring leaves the newest pooled "
+    "executor holds row-major between ticks instead of in the device's "
+    "default layout (0: the rule left every leaf alone)",
+)
 _OBS_BURST_DEPTH = default_registry().histogram(
     "ggrs_executor_burst_depth_frames",
     "deepest per-session advance burst (replay depth) per dispatched tick",
@@ -90,6 +112,304 @@ def _tree_where(pred: jax.Array, a: Any, b: Any) -> Any:
     return jax.tree_util.tree_map(
         lambda x, y: jnp.where(pred, x, y), a, b
     )
+
+
+# ----------------------------------------------------------------------
+# the ring's layout at the program's boundary (docs/DESIGN.md §3)
+# ----------------------------------------------------------------------
+
+# A ring leaf smaller than this on a device keeps the device's default
+# layout whatever its shape: both transposes of 32 MiB are under a quarter
+# of a millisecond a tick (7 ms a gigabyte measured, PERF.md §6, PR 32), a
+# sixtieth of the host tick that hides the program in every pool of that
+# size, and the compiled texts the rule was read from are of leaves of
+# hundreds of megabytes.
+_RELAY_MIN_BYTES = 1 << 25
+_LANES = 128  # a TPU tile's minor dimension
+_SUBLANES = 8  # and the most its second-minor holds, of 4-byte elements
+
+
+def ring_leaf_layout(
+    shape: Sequence[int], itemsize: int
+) -> Optional[Layout]:
+    """The layout in which one device's share ``[B, R, ...]`` of a ring leaf
+    is held between ticks: ``None`` for the device's default, or row-major
+    over ``[B, R, ...]`` under the tile XLA:TPU itself computes in.
+
+    The default layout of a TPU array puts the session axis minor-most
+    when that saves padding; the tick program computes on a wide leaf
+    row-major, and transposes the whole leaf at its entry and back at its
+    exit unless the carry already arrives so.  Row-major pays where the
+    minor-most dimension fills the 128 lanes (a 2-wide one would be padded
+    sixty-four times over) and the leaf is large enough for the transposes
+    to cost something.  Read off the shape alone: no option, no game's
+    name."""
+    if len(shape) < 3 or itemsize != 4:
+        return None
+    if shape[-1] < _LANES or math.prod(shape) * itemsize < _RELAY_MIN_BYTES:
+        return None
+    rows = 1
+    while rows < min(shape[-2], _SUBLANES):
+        rows *= 2
+    return Layout(
+        major_to_minor=tuple(range(len(shape))), tiling=((rows, _LANES),)
+    )
+
+
+# A program that RETURNS a re-laid leaf is compiled in the process that runs
+# it, never loaded from JAX's persistent compilation cache: jaxlib 0.9.0
+# labels every result of a deserialized executable with the device's default
+# layout whatever layout the executable writes (the buffer is right, its
+# label is not), and the next call's check of the argument against the
+# ``Format`` it was compiled for then fails (PERF.md §6, PR 32;
+# tests/test_ring_layout.py holds the jaxlib behaviour, so that an upgrade
+# which cures it says so).  The price is the compile at every start, which
+# is why the two programs concerned are compiled at a low effort: 2.6 s a
+# start and a tick of 51.6 ms in particles-2p.wan-sat, where full effort
+# reads 5.3 s and 50.6 ms and breaks the bound on setup_s (PERF.md §6).
+_IN_PROCESS_COMPILER_OPTIONS = {"exec_time_optimization_effort": -1.0}
+
+
+@contextlib.contextmanager
+def _compiled_in_process(formats: Any):
+    """What compiles inside bypasses the persistent compilation cache when
+    ``formats`` re-lays any leaf; otherwise nothing changes."""
+    if not jax.tree_util.tree_leaves(formats):
+        yield
+        return
+    from jax.experimental.compilation_cache import compilation_cache
+
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()  # the decision is taken once and kept
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
+
+
+class TickProgram(NamedTuple):
+    """A pool's device program, from shapes alone (nothing is allocated
+    until ``init`` is called, so it can be lowered for a chip that is only
+    described)."""
+
+    init: Callable[[Any], Dict[str, Any]]  # one session's state -> the carry
+    tick: Callable[[Dict[str, Any], Dict[str, Any]], Dict[str, Any]]
+    formats: Dict[str, Any]  # carry-shaped: a Format where re-laid, else None
+    carry: Dict[str, Any]  # carry-shaped ShapeDtypeStructs, placed as held
+
+
+def tick_program(
+    advance: Callable[[Any, Any], Any],
+    state0: Any,
+    batch_size: int,
+    ring_length: int,
+    with_checksums: bool = True,
+    mesh: Optional["jax.sharding.Mesh"] = None,
+    device: Optional["jax.Device"] = None,
+) -> TickProgram:
+    """Build ``BatchedRequestExecutor``'s carry initialiser and tick program.
+
+    ``device`` is the one device of a pool without a mesh: the process's
+    default when ``None``; a described, unattached one to read the compiled
+    program without a chip (``scripts/profile_tick.py --dump-hlo``)."""
+    from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+    from ..ops.ring import DeviceStateRing
+
+    B = batch_size
+    dring = DeviceStateRing(ring_length)
+    zero_cs = jnp.zeros((CHECKSUM_LANES,), jnp.uint32)
+
+    def fresh(state0: Any) -> Dict[str, Any]:
+        def per_session(l):
+            return jnp.broadcast_to(l[None, ...], (B,) + l.shape)
+
+        return {
+            "live": jax.tree_util.tree_map(per_session, state0),
+            # one DeviceStateRing (states / checksums / frames) per session,
+            # stacked on a leading B axis; its frame tags back the host-side
+            # accessors and the _parse-time ring-capacity guard
+            "ring": jax.tree_util.tree_map(per_session, dring.init(state0)),
+        }
+
+    if mesh is not None:
+        # the session axis over every mesh axis; the rule reads a shard
+        spec_b = PartitionSpec(tuple(mesh.axis_names))
+        sharding: Any = NamedSharding(mesh, spec_b)
+        shards = mesh.devices.size
+    else:
+        if device is None:
+            (device,) = jnp.zeros(()).devices()
+        sharding = SingleDeviceSharding(device)
+        shards = 1
+
+    shapes = jax.eval_shape(fresh, state0)
+
+    def ring_format(l: jax.ShapeDtypeStruct) -> Optional[Format]:
+        layout = ring_leaf_layout(
+            (l.shape[0] // shards,) + l.shape[1:], l.dtype.itemsize
+        )
+        return None if layout is None else Format(layout, sharding)
+
+    formats = {
+        "live": jax.tree_util.tree_map(lambda l: None, shapes["live"]),
+        "ring": jax.tree_util.tree_map(ring_format, shapes["ring"]),
+    }
+    held = jax.tree_util.tree_map(
+        lambda f: sharding if f is None else f,
+        formats,
+        is_leaf=lambda f: f is None,
+    )
+    carry = jax.tree_util.tree_map(
+        lambda l, f: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=f),
+        shapes,
+        held,
+    )
+    options = (
+        _IN_PROCESS_COMPILER_OPTIONS
+        if jax.tree_util.tree_leaves(formats)
+        else None
+    )
+    init = jax.jit(fresh, out_shardings=held, compiler_options=options)
+
+    def session_tick(
+        live: Any,
+        ring: Any,
+        pre_save: jax.Array,
+        pre_frame: jax.Array,
+        do_load: jax.Array,
+        load_frame: jax.Array,
+        postload_save: jax.Array,
+        postload_frame: jax.Array,
+        n_adv: jax.Array,
+        inputs: Any,  # [max_burst, ...]
+        save_mask: jax.Array,  # [max_burst]
+        save_frame: jax.Array,  # [max_burst]
+        # what the whole batch asks (unbatched):
+        n_steps: jax.Array,  # its deepest n_adv
+        any_postload: jax.Array,  # whether any session saves after its load
+    ):
+        # the scopes name the program's parts in a device profile
+        # (metadata only: the lowered operations are the same)
+        def write(ring, frame, st, pred):
+            with jax.named_scope("digest"):
+                cs = checksum_device(st) if with_checksums else zero_cs
+            return dring.save_where(ring, frame, st, cs, pred)
+
+        with jax.named_scope("ring.pre_save"):
+            ring = write(ring, pre_frame, live, pre_save)
+        with jax.named_scope("ring.load"):
+            st = _tree_where(do_load, dring.load(ring, load_frame), live)
+        # sparse saving can save the just-loaded state before any advance
+        # (reference: p2p_session.rs:666-672 — the min_confirmed save);
+        # a batch in which no session does skips the write and its
+        # digest: the ring passes through the conditional uncopied
+        # (PERF.md section 5, PR 30)
+        with jax.named_scope("ring.save"):
+            ring = jax.lax.cond(
+                any_postload,
+                lambda ring: write(ring, postload_frame, st, postload_save),
+                lambda ring: ring,
+                ring,
+            )
+
+        # the burst: as many trips as the deepest plan of the batch asks
+        # (1 on a quiet tick, 2 with a one-frame rollback, max_burst at
+        # most), not max_burst whatever the plans hold.  A step beyond
+        # that is a no-op for every session (act false: the advance
+        # discarded, the save's predicate false), so leaving it out
+        # changes no byte.  The counter is the batch's, so the descriptor
+        # columns are read at ONE index (a slice, not a gather); a
+        # session whose plan is shallower idles through the rest by act.
+        def step(j, carry):
+            st, ring = carry
+            inp, smask, sframe = (
+                jax.lax.dynamic_index_in_dim(col, j, 0, keepdims=False)
+                for col in (inputs, save_mask, save_frame)
+            )
+            act = j < n_adv
+            with jax.named_scope("advance"):
+                st = _tree_where(act, advance(st, inp), st)
+            with jax.named_scope("ring.save"):
+                ring = write(ring, sframe, st, act & smask)
+            return st, ring
+
+        return jax.lax.fori_loop(jnp.int32(0), n_steps, step, (st, ring))
+
+    def tick(carry: Dict[str, Any], desc: Dict[str, Any]) -> Dict[str, Any]:
+        # under shard_map both are each shard's own: no collective
+        n_steps = jnp.max(desc["n_adv"])
+        any_postload = jnp.any(desc["postload_save"])
+        live, ring = jax.vmap(
+            lambda *session: session_tick(*session, n_steps, any_postload)
+        )(
+            carry["live"],
+            carry["ring"],
+            desc["pre_save"],
+            desc["pre_frame"],
+            desc["do_load"],
+            desc["load_frame"],
+            desc["postload_save"],
+            desc["postload_frame"],
+            desc["n_adv"],
+            desc["inputs"],
+            desc["save_mask"],
+            desc["save_frame"],
+        )
+        return {"live": live, "ring": ring}
+
+    if mesh is not None:
+        # sessions are independent: shard the B axis, no collectives
+        from jax import shard_map
+
+        tick = shard_map(
+            tick,
+            mesh=mesh,
+            in_specs=(spec_b, spec_b),
+            out_specs=spec_b,
+            check_vma=False,
+        )
+
+    # the carry is donated on every backend (in-place ring update): the
+    # program tier-1 runs on the CPU is the program the chip runs, so a
+    # use of a donated buffer fails in a test, not as a deferred error
+    # on the chip.  A re-laid ring leaf comes in and goes out in its
+    # Format, so the donated buffer is the result's and no transpose
+    # stands at either end; every other leaf is left to the default.
+    return TickProgram(
+        init,
+        jax.jit(
+            tick,
+            donate_argnums=(0,),
+            in_shardings=(formats, None),
+            out_shardings=formats,
+            compiler_options=options,
+        ),
+        formats,
+        carry,
+    )
+
+
+def blank_desc(
+    batch_size: int, max_burst: int, input_shape: Tuple[int, ...], input_dtype
+) -> Dict[str, np.ndarray]:
+    """One tick's descriptor with every row idle: the tick program's second
+    argument."""
+    B, D = batch_size, max_burst
+    return {
+        "pre_save": np.zeros((B,), bool),
+        "pre_frame": np.zeros((B,), np.int32),
+        "do_load": np.zeros((B,), bool),
+        "load_frame": np.zeros((B,), np.int32),
+        "postload_save": np.zeros((B,), bool),
+        "postload_frame": np.zeros((B,), np.int32),
+        "n_adv": np.zeros((B,), np.int32),
+        "inputs": np.zeros((B, D) + tuple(input_shape), input_dtype),
+        "save_mask": np.zeros((B, D), bool),
+        "save_frame": np.zeros((B, D), np.int32),
+    }
 
 
 class _BatchSlotRef:
@@ -202,42 +522,49 @@ class BatchedRequestExecutor:
                 f"{mesh.devices.size} mesh devices"
             )
 
-        from ..ops.ring import DeviceStateRing
-
         state0 = jax.tree_util.tree_map(jnp.asarray, init_state)
-        B, R = batch_size, ring_length
-        self._ring = DeviceStateRing(R)
-        ring0 = self._ring.init(state0)
-        self._carry: Dict[str, Any] = {
-            "live": jax.tree_util.tree_map(
-                lambda l: jnp.broadcast_to(l[None, ...], (B,) + l.shape), state0
-            ),
-            # one DeviceStateRing (states / checksums / frames) per session,
-            # stacked on a leading B axis; its frame tags back the host-side
-            # accessors and the _parse-time ring-capacity guard
-            "ring": jax.tree_util.tree_map(
-                lambda l: jnp.broadcast_to(l[None, ...], (B,) + l.shape).copy(),
-                ring0,
-            ),
-        }
-        if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            self._sharding = NamedSharding(
-                mesh, PartitionSpec(tuple(mesh.axis_names))
+        program = tick_program(
+            advance, state0, batch_size, ring_length, with_checksums, mesh
+        )
+        # made in the layout it is held in (one pass, no second ring); a
+        # copy of init_state, never its buffers: the carry is donated
+        self._formats = program.formats
+        with _compiled_in_process(self._formats):
+            self._carry: Dict[str, Any] = program.init(
+                jax.tree_util.tree_map(np.asarray, state0)
             )
-            self._carry = jax.tree_util.tree_map(
-                lambda l: jax.device_put(l, self._sharding), self._carry
+        self._tick = program.tick
+        flat_formats = jax.tree_util.tree_leaves(
+            self._formats, is_leaf=lambda f: f is None
+        )
+        relaid = [
+            (leaf, f)
+            for leaf, f in zip(
+                jax.tree_util.tree_leaves(self._carry), flat_formats
             )
+            if f is not None
+        ]
+        for leaf, f in relaid:
+            if leaf.format.layout != f.layout:
+                # a label that lies (see _compiled_in_process) would fail
+                # the first tick less clearly, or not at all
+                raise RuntimeError(
+                    f"ring leaf {leaf.shape} was made in layout "
+                    f"{leaf.format.layout}, not in {f.layout}"
+                )
         # what the executor holds on the device, for a ledger line to tell
         # the ring's own bytes from the lowering's copies (DESIGN.md §14);
         # the session axis shards evenly, so a device's share is 1/size
+        devices = mesh.devices.size if mesh is not None else 1
         _OBS_STATE_BYTES.set(
             sum(l.nbytes for l in jax.tree_util.tree_leaves(state0))
         )
         _OBS_RING_RESIDENT_BYTES.set(
             sum(l.nbytes for l in jax.tree_util.tree_leaves(self._carry))
-            // (mesh.devices.size if mesh is not None else 1)
+            // devices
+        )
+        _OBS_RING_RELAID_BYTES.set(
+            sum(leaf.nbytes for leaf, _ in relaid) // devices
         )
         self._input_dtype: Optional[np.dtype] = None
         self._input_shape: Optional[Tuple[int, ...]] = None
@@ -252,116 +579,9 @@ class BatchedRequestExecutor:
         self._invalid: Optional[str] = None
         # host shadow of the ring frame tags: loud failure at _parse time if
         # a session rolls back past ring_length (device aliasing is silent)
-        self._host_frames = np.full((B, R), -1, np.int64)
-
-        dring = self._ring
-        zero_cs = jnp.zeros((CHECKSUM_LANES,), jnp.uint32)
-
-        def session_tick(
-            live: Any,
-            ring: Any,
-            pre_save: jax.Array,
-            pre_frame: jax.Array,
-            do_load: jax.Array,
-            load_frame: jax.Array,
-            postload_save: jax.Array,
-            postload_frame: jax.Array,
-            n_adv: jax.Array,
-            inputs: Any,  # [max_burst, ...]
-            save_mask: jax.Array,  # [max_burst]
-            save_frame: jax.Array,  # [max_burst]
-            # what the whole batch asks (unbatched):
-            n_steps: jax.Array,  # its deepest n_adv
-            any_postload: jax.Array,  # whether any session saves after its load
-        ):
-            # the scopes name the program's parts in a device profile
-            # (metadata only: the lowered operations are the same)
-            def write(ring, frame, st, pred):
-                with jax.named_scope("digest"):
-                    cs = checksum_device(st) if with_checksums else zero_cs
-                return dring.save_where(ring, frame, st, cs, pred)
-
-            with jax.named_scope("ring.pre_save"):
-                ring = write(ring, pre_frame, live, pre_save)
-            with jax.named_scope("ring.load"):
-                st = _tree_where(do_load, dring.load(ring, load_frame), live)
-            # sparse saving can save the just-loaded state before any advance
-            # (reference: p2p_session.rs:666-672 — the min_confirmed save);
-            # a batch in which no session does skips the write and its
-            # digest: the ring passes through the conditional uncopied
-            # (PERF.md section 5, PR 30)
-            with jax.named_scope("ring.save"):
-                ring = jax.lax.cond(
-                    any_postload,
-                    lambda ring: write(ring, postload_frame, st, postload_save),
-                    lambda ring: ring,
-                    ring,
-                )
-
-            # the burst: as many trips as the deepest plan of the batch asks
-            # (1 on a quiet tick, 2 with a one-frame rollback, max_burst at
-            # most), not max_burst whatever the plans hold.  A step beyond
-            # that is a no-op for every session (act false: the advance
-            # discarded, the save's predicate false), so leaving it out
-            # changes no byte.  The counter is the batch's, so the descriptor
-            # columns are read at ONE index (a slice, not a gather); a
-            # session whose plan is shallower idles through the rest by act.
-            def step(j, carry):
-                st, ring = carry
-                inp, smask, sframe = (
-                    jax.lax.dynamic_index_in_dim(col, j, 0, keepdims=False)
-                    for col in (inputs, save_mask, save_frame)
-                )
-                act = j < n_adv
-                with jax.named_scope("advance"):
-                    st = _tree_where(act, advance(st, inp), st)
-                with jax.named_scope("ring.save"):
-                    ring = write(ring, sframe, st, act & smask)
-                return st, ring
-
-            return jax.lax.fori_loop(jnp.int32(0), n_steps, step, (st, ring))
-
-        def tick(carry: Dict[str, Any], desc: Dict[str, Any]) -> Dict[str, Any]:
-            # under shard_map both are each shard's own: no collective
-            n_steps = jnp.max(desc["n_adv"])
-            any_postload = jnp.any(desc["postload_save"])
-            live, ring = jax.vmap(
-                lambda *session: session_tick(*session, n_steps, any_postload)
-            )(
-                carry["live"],
-                carry["ring"],
-                desc["pre_save"],
-                desc["pre_frame"],
-                desc["do_load"],
-                desc["load_frame"],
-                desc["postload_save"],
-                desc["postload_frame"],
-                desc["n_adv"],
-                desc["inputs"],
-                desc["save_mask"],
-                desc["save_frame"],
-            )
-            return {"live": live, "ring": ring}
-
-        if mesh is not None:
-            # sessions are independent: shard the B axis, no collectives
-            from jax import shard_map
-            from jax.sharding import PartitionSpec
-
-            spec_b = PartitionSpec(tuple(mesh.axis_names))
-            tick = shard_map(
-                tick,
-                mesh=mesh,
-                in_specs=(spec_b, spec_b),
-                out_specs=spec_b,
-                check_vma=False,
-            )
-
-        # the carry is donated on every backend (in-place ring update): the
-        # program tier-1 runs on the CPU is the program the chip runs, so a
-        # use of a donated buffer fails in a test, not as a deferred error
-        # on the chip
-        self._tick = jax.jit(tick, donate_argnums=(0,))
+        self._host_frames = np.full(
+            (batch_size, ring_length), -1, np.int64
+        )
 
         # slot probe with TRACED indices: one compile covers every
         # (session, slot) the desync exchange ever reads.  Eager integer
@@ -497,22 +717,13 @@ class BatchedRequestExecutor:
             )
 
     def _blank_desc(self) -> Dict[str, np.ndarray]:
-        B, D = self.batch_size, self.max_burst
         assert self._input_shape is not None, (
             "call warmup(example_inputs) before the first run()"
         )
-        return {
-            "pre_save": np.zeros((B,), bool),
-            "pre_frame": np.zeros((B,), np.int32),
-            "do_load": np.zeros((B,), bool),
-            "load_frame": np.zeros((B,), np.int32),
-            "postload_save": np.zeros((B,), bool),
-            "postload_frame": np.zeros((B,), np.int32),
-            "n_adv": np.zeros((B,), np.int32),
-            "inputs": np.zeros((B, D) + self._input_shape, self._input_dtype),
-            "save_mask": np.zeros((B, D), bool),
-            "save_frame": np.zeros((B, D), np.int32),
-        }
+        return blank_desc(
+            self.batch_size, self.max_burst,
+            self._input_shape, self._input_dtype,
+        )
 
     # ------------------------------------------------------------------
     # public API
@@ -526,7 +737,8 @@ class BatchedRequestExecutor:
         self._input_dtype = arr.dtype
         self._input_shape = arr.shape
         desc = self._blank_desc()
-        out = self._tick(self._carry, desc)
+        with _compiled_in_process(self._formats):
+            out = self._tick(self._carry, desc)
         jax.block_until_ready(out)
         # a no-op tick leaves the carry semantically unchanged; keep the
         # result, because the dispatch donated (invalidated) its input

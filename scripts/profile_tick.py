@@ -377,27 +377,84 @@ def hlo_scopes(hlo_text: str):
     return {name: resolve(name) for name in own}
 
 
+def entry_copies(hlo_text: str):
+    """``copy`` instructions of the compiled program's ENTRY computation,
+    counted by result shape (``s32[512,10,4,10000]`` -> 2): the layout
+    conversions that stand at the program's boundary, outside every loop.
+    A ring-sized one is a transposition of a whole ring leaf, paid every
+    tick (DESIGN.md §3, "The ring's layout at the program's boundary")."""
+    import re
+
+    copy = re.compile(
+        r"^\s+(?:ROOT\s+)?%[\w.\-]+ = (\w+\[[\d,]*\])\S* copy\(")
+    found, inside = {}, False
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace():
+            inside = line.startswith("ENTRY")
+            continue
+        m = copy.match(line) if inside else None
+        if m:
+            found[m.group(1)] = found.get(m.group(1), 0) + 1
+    return found
+
+
 def dump_hlo(cell, matches, path):
     """The cell's tick program compiled for a v5e that is described, not
     attached: its text carries the ``jax.named_scope`` names as ``op_name``
-    metadata, under the operation names a profile on the chip shows."""
+    metadata, under the operation names a profile on the chip shows.  Built
+    from shapes by the executor's own ``tick_program``, so the carry comes in
+    and goes out in the layouts a pool of this size holds it in and nothing
+    of the cell's size is allocated here."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import importlib
+
     import jax
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    pool, _ = build_cell(cell, matches, 0, 1)
-    chip = SingleDeviceSharding(topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0])
-    ex = pool.executor
-    shapes = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=chip),
-        (ex._carry, ex._blank_desc()))
-    text = ex._tick.lower(*shapes).compile().as_text()
+    from benchmark import run
+    from ggrs_tpu.parallel.session_pool import blank_desc, tick_program
+
+    spec = run.load_cell(run.REPO, cell)
+    config = spec["config"]
+    adapter = importlib.import_module(f"benchmark.adapters.{config['adapter']}")
+    sessions = int(matches or spec["size"]["matches"]) * int(config["players"])
+    game = adapter.make_game(config)
+    chip = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
+    program = tick_program(game.advance, game.init_state(), sessions,
+                           int(config["ring_length"]), device=chip)
+    example = adapter.example_inputs(config)
+    desc = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=SingleDeviceSharding(chip)),
+        blank_desc(sessions, int(config["max_burst"]),
+                   example.shape, example.dtype))
+    compiled = program.tick.lower(program.carry, desc).compile()
+    text = compiled.as_text()
     Path(path).write_text(text)
     found = hlo_scopes(text)
     print(f"{path}: {len(found)} operations, "
           f"{sum(v != '-' for v in found.values())} of them in a named scope")
+    relaid = jax.tree_util.tree_flatten_with_path(program.formats)[0]
+    print(f"  held row-major between ticks: {len(relaid)} ring leaves")
+    for k, held in relaid:
+        print(f"    {jax.tree_util.keystr(k)}: tile {held.layout.tiling[0]}")
+    print("  copies in the ENTRY computation, by shape (a ring-sized one is a "
+          "whole-ring transposition a tick):")
+
+    def elements(shape):  # "s32[512,10,4,10000]" -> 204,800,000
+        dims = shape[shape.index("[") + 1:-1]
+        return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+    for shape, n in sorted(entry_copies(text).items(),
+                           key=lambda kv: (-elements(kv[0]), kv[0])):
+        print(f"    {n} x {shape}")
+    memory = compiled.memory_analysis()
+    print(f"  the compiler's reckoning: arguments "
+          f"{memory.argument_size_in_bytes:,} bytes, aliased "
+          f"{memory.alias_size_in_bytes:,}, temporaries "
+          f"{memory.temp_size_in_bytes:,}")
 
 
 def own_seconds(op_events):

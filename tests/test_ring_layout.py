@@ -1,0 +1,413 @@
+"""The ring's layout at the tick program's boundary (DESIGN.md §3).
+
+Three things, none on a chip: (a) the rule as a function of shapes; (b) the
+tick compiled for a v5e that is described, not attached, from shapes alone
+(nothing of 2.9 GB is allocated): a count of what the compiler wrote, never
+a time; (c) on the CPU, that holding the ring in another layout changes no
+value.  Every compile for the described chip lives in THIS file, behind one
+fixture: a process loads the TPU's library once.
+"""
+
+import os
+import re
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+from jax.experimental.layout import Format
+
+from ggrs_tpu.games import BoxGame, EcsWorld, ParticleWorld
+from ggrs_tpu.obs.registry import default_registry
+from ggrs_tpu.parallel import BatchedRequestExecutor, session_pool
+from ggrs_tpu.parallel.session_pool import (
+    blank_desc,
+    ring_leaf_layout,
+    tick_program,
+)
+
+REPO = Path(__file__).resolve().parents[1]
+if str(REPO / "scripts") not in sys.path:
+    sys.path.insert(0, str(REPO / "scripts"))
+
+from test_session_pool import _drive, _make_matches, _to_arr  # noqa: E402
+
+# the benchmark's three configurations at their cells' populations:
+# sessions, ring length, burst, game
+CELLS = {
+    "particles-2p": (512, 10, 9, lambda: ParticleWorld(2, 10000, 100, 50)),
+    "boxgame-2p": (512, 10, 9, lambda: BoxGame(2)),
+    "ecs-4p": (256, 18, 17, lambda: EcsWorld(4, 32)),
+}
+# the five 10,000-wide leaves of a particle state and the tile of each
+PARTICLE_TILES = {
+    "translation": 4, "rotation": 4, "scale": 4, "ttl": 8, "velocity": 2,
+}
+
+
+def _program(cell, sessions=None, **where):
+    b, ring, _burst, make = CELLS[cell]
+    game = make()
+    return tick_program(
+        game.advance, game.init_state(), sessions or b, ring, **where
+    )
+
+
+def _layouts(formats):
+    """path -> Layout or None, over a carry-shaped tree of formats."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(
+        formats, is_leaf=lambda f: f is None
+    )
+    return {
+        "/".join(str(k.key) for k in path): f if f is None else f.layout
+        for path, f in flat
+    }
+
+
+# ---------------------------------------------------------------------------
+# (a) the rule, from shapes
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("leaf", sorted(PARTICLE_TILES))
+def test_wide_particle_ring_leaves_are_held_row_major(leaf):
+    program = _program("particles-2p")
+    layout = _layouts(program.formats)[f"ring/states/{leaf}"]
+    rank = len(program.carry["ring"]["states"][leaf].shape)
+    assert layout.major_to_minor == tuple(range(rank))
+    assert layout.tiling == ((PARTICLE_TILES[leaf], 128),)
+    held = program.carry["ring"]["states"][leaf]
+    assert isinstance(held.format, Format) and held.format.layout == layout
+
+
+def test_every_other_particle_leaf_keeps_the_default():
+    layouts = _layouts(_program("particles-2p").formats)
+    relaid = {p for p, l in layouts.items() if l is not None}
+    assert relaid == {f"ring/states/{k}" for k in PARTICLE_TILES}
+    for kept in ("ring/states/emitter", "ring/states/resources",
+                 "ring/checksums", "ring/frames", "live/ttl",
+                 "live/rotation", "live/emitter"):
+        assert layouts[kept] is None
+    assert sum(p.startswith("live/") for p in layouts) == 7
+
+
+@pytest.mark.parametrize("cell", ["boxgame-2p", "ecs-4p"])
+def test_the_small_cells_keep_the_default_everywhere(cell):
+    program = _program(cell)
+    assert set(_layouts(program.formats).values()) == {None}
+    if cell == "ecs-4p":  # lane-wide and lane-narrow leaves, both small
+        shapes = {
+            l.shape[2:]
+            for l in jax.tree_util.tree_leaves(program.carry["ring"]["states"])
+        }
+        assert {(128,), (128, 2)} <= shapes
+
+
+@pytest.mark.parametrize(
+    "shape, itemsize, tile",
+    [
+        ((512, 10, 4, 10000), 4, 4),
+        ((512, 10, 3, 10000), 4, 4),
+        ((512, 10, 2, 10000), 4, 2),
+        ((512, 10, 1, 10000), 4, 1),
+        ((512, 10, 10000), 4, 8),  # the second-minor is the ring axis
+        ((512, 10, 16, 10000), 4, 8),
+        ((128, 10, 4, 10000), 4, 4),  # a quarter of the pool: still large
+        ((256, 18, 128), 4, None),  # ecs-4p: lane-wide, 2.4 MB
+        ((256, 18, 128, 2), 4, None),  # 2 wide: 64 times the bytes row-major
+        ((512, 10, 10000, 2), 4, None),  # large, and as narrow
+        ((512, 10, 4, 100), 4, None),  # under a tile's 128 lanes
+        ((8, 10, 4, 10000), 4, None),  # 12.8 MB: under the threshold
+        ((512, 10), 4, None),  # frames: no state dimension
+        ((512, 10, 4, 10000), 2, None),  # other widths tile otherwise
+    ],
+)
+def test_the_rule_reads_trailing_dimensions_and_bytes(shape, itemsize, tile):
+    layout = ring_leaf_layout(shape, itemsize)
+    if tile is None:
+        assert layout is None
+    else:
+        assert layout.major_to_minor == tuple(range(len(shape)))
+        assert layout.tiling == ((tile, 128),)
+
+
+def test_a_shard_of_a_mesh_decides_as_one_device_of_its_size():
+    from ggrs_tpu.parallel import make_mesh
+
+    mesh = make_mesh(4)
+    one = _layouts(_program("particles-2p").formats)
+    # 2,048 sessions over four devices: each holds what one device holds
+    across = _program("particles-2p", sessions=2048, mesh=mesh)
+    assert _layouts(across.formats) == one
+    shardings = {
+        f.sharding for f in jax.tree_util.tree_leaves(across.formats)
+    }
+    assert len(shardings) == 1 and shardings.pop().mesh == mesh
+    # 24 sessions: the whole `ttl` ring (9.6 MB x 4) would pass on one
+    # device of 96, a shard of 24 does not
+    assert ring_leaf_layout((96, 10, 10000), 4) is not None
+    small = _layouts(_program("particles-2p", sessions=96, mesh=mesh).formats)
+    assert small["ring/states/ttl"] is None
+    assert small["ring/states/rotation"] is not None  # 38 MB a shard
+
+
+# ---------------------------------------------------------------------------
+# (b) what the compiler writes for a described v5e
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # pragma: no cover
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+def _compiled(program, cell, chip, tick=None):
+    from jax.sharding import SingleDeviceSharding
+
+    b, _ring, burst, _ = CELLS[cell]
+    players = 4 if cell == "ecs-4p" else 2
+    desc = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=SingleDeviceSharding(chip)
+        ),
+        blank_desc(b, burst, (players,), np.uint8),
+    )
+    return (tick or program.tick).lower(program.carry, desc).compile()
+
+
+def test_the_particle_tick_holds_no_ring_transpose_at_its_boundary(chip):
+    from profile_tick import entry_copies
+
+    program = _program("particles-2p", device=chip)
+    compiled = _compiled(program, "particles-2p", chip)
+    text = compiled.as_text()
+    copies = entry_copies(text)
+    assert copies, "the census found no copy at all: it reads nothing"
+    ring_sized = [s for s in copies if re.match(r"\w+\[512,10,.*10000\]", s)]
+    assert ring_sized == []
+    # the ten live-sized ones stay: the live leaves keep the default
+    assert sum(n for s, n in copies.items() if "10000]" in s) == 10
+    # aliased whole: every leaf of the donated carry is a result's buffer
+    leaves = len(jax.tree_util.tree_leaves(program.carry))
+    assert text.splitlines()[0].count("may-alias") == leaves == 16
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes - memory.alias_size_in_bytes < 4096
+    # the padded row-major ring: 3.5 GB, and under a gigabyte beside it
+    assert 3.4e9 < memory.argument_size_in_bytes < 3.6e9
+    assert memory.temp_size_in_bytes < 1.0e9
+    # and it is made so: the initialiser writes 3.5 GB once, with no temporary
+    made = program.init.lower(
+        jax.tree_util.tree_map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype),
+            ParticleWorld(2, 10000, 100, 50).init_state(),
+        )
+    ).compile()
+    assert made.memory_analysis().temp_size_in_bytes == 0
+    out = _layouts(made.output_formats)
+    assert {p: l for p, l in out.items() if l.major_to_minor[0] == 0} == {
+        p: l for p, l in _layouts(program.formats).items() if l is not None
+    }
+
+
+def test_the_tick_over_a_mesh_of_four_chips_holds_none_either(chip):
+    """2,048 sessions over the four chips of the described host: each shard
+    is the one-chip program, and no collective joins them."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from profile_tick import entry_copies
+
+    mesh = Mesh(np.asarray(chip.client.devices()[:4]), ("sessions",))
+    program = _program("particles-2p", sessions=2048, mesh=mesh)
+    across = NamedSharding(mesh, PartitionSpec(("sessions",)))
+    desc = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=across),
+        blank_desc(2048, 9, (2,), np.uint8),
+    )
+    compiled = program.tick.lower(program.carry, desc).compile()
+    text = compiled.as_text()
+    copies = entry_copies(text)
+    assert copies and not [
+        s for s in copies if re.match(r"\w+\[512,10,.*10000\]", s)
+    ]
+    assert not re.search(r"all-reduce|all-gather|collective-permute", text)
+    assert 3.4e9 < compiled.memory_analysis().argument_size_in_bytes < 3.6e9
+
+
+@pytest.mark.parametrize("cell", ["boxgame-2p", "ecs-4p"])
+def test_the_small_cells_compile_to_the_program_of_before(cell, chip):
+    program = _program(cell, device=chip)
+    # "before": the donated jit with nothing said about the carry
+    plain = jax.jit(program.tick.__wrapped__, donate_argnums=(0,))
+    assert (
+        _compiled(program, cell, chip).as_text()
+        == _compiled(program, cell, chip, tick=plain).as_text()
+    )
+
+
+# ---------------------------------------------------------------------------
+# (c) the layout never changes a value
+# ---------------------------------------------------------------------------
+
+
+def _run_particles(min_bytes, monkeypatch, ticks=36):
+    monkeypatch.setattr(session_pool, "_RELAY_MIN_BYTES", min_bytes)
+    sessions, schedules = _make_matches(3, seed=23)
+    game = ParticleWorld(2, 256, 8, 16)
+    pool = BatchedRequestExecutor(
+        game.advance, game.init_state(), _to_arr,
+        batch_size=len(sessions), ring_length=10, max_burst=9,
+    )
+    relaid = default_registry().value("ggrs_executor_ring_relaid_bytes")
+    pool.warmup(np.zeros((2,), np.uint8))
+    loads = default_registry().value("ggrs_executor_rollback_loads_total")
+    _drive(sessions, schedules, pool.run, ticks)
+    loads = default_registry().value("ggrs_executor_rollback_loads_total") - loads
+    frames = [s.current_frame for s in sessions]
+    live = jax.device_get(pool.live_states)
+    one = pool.live_state(1)
+    saved = {
+        (b, f): (pool.ring_state(b, f), pool.ring_checksum(b, f))
+        for b in range(len(sessions))
+        for f in range(frames[b] - 8, frames[b])
+    }
+    ring = jax.device_get(pool._carry["ring"])
+    held = {
+        k: leaf.format.layout.tiling
+        for k, leaf in pool._carry["ring"]["states"].items()
+    }
+    return {
+        "relaid": relaid, "loads": loads, "frames": frames, "live": live,
+        "one": one, "saved": saved, "ring": ring, "held": held,
+    }
+
+
+def test_an_executor_above_and_below_the_rule_gives_equal_values(monkeypatch):
+    below = _run_particles(1 << 25, monkeypatch)
+    above = _run_particles(1, monkeypatch)
+    # the rule engaged in one and not in the other
+    assert below["relaid"] == 0
+    wide = 6 * 10 * (3 + 4 + 3 + 2 + 1) * 256 * 4
+    assert above["relaid"] == wide
+    assert above["held"]["rotation"] == ((4, 128),)
+    assert above["held"]["ttl"] == ((8, 128),)
+    assert above["held"]["velocity"] == ((2, 128),)
+    assert above["held"]["emitter"] == below["held"]["emitter"]
+    # a rollback-heavy run, and the same one
+    assert above["loads"] == below["loads"] > 20
+    assert above["frames"] == below["frames"]
+    for key in ("live", "one", "saved", "ring"):
+        a, b = (jax.tree_util.tree_leaves(r[key]) for r in (above, below))
+        assert len(a) == len(b) > 0
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y), key)
+    assert int(np.asarray(above["live"]["ttl"]).sum()) > 0
+
+
+# ---------------------------------------------------------------------------
+# (d) the persistent compilation cache and a result with a layout of its own
+# ---------------------------------------------------------------------------
+
+# one child process for both cases: it points JAX's persistent cache at a
+# directory of its own and empties the in-memory caches between two uses,
+# neither of which a worker of this suite should do to itself
+_CACHE_PROBE = r"""
+import json, os, sys, tempfile
+import numpy as np, jax, jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
+
+jax.config.update("jax_platforms", "cpu")
+cache = tempfile.mkdtemp()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+jax.config.update("jax_compilation_cache_dir", cache)
+out = {}
+
+# what jaxlib does: the same jitted function, compiled here and then loaded
+want = Layout((1, 2, 0))
+made = jax.jit(
+    lambda s: jnp.arange(24, dtype=jnp.int32).reshape(2, 3, 4) + s,
+    out_shardings=Format(want, SingleDeviceSharding(jax.devices()[0])),
+)
+out["compiled_here"] = made(np.int32(1)).format.layout.major_to_minor
+jax.clear_caches()
+again = made(np.int32(1))
+out["from_the_cache"] = again.format.layout.major_to_minor
+out["values"] = bool(
+    (np.asarray(again) == np.arange(24).reshape(2, 3, 4) + 1).all())
+
+# what the executor does about it: two pools above the rule, one cache
+from ggrs_tpu.games import ParticleWorld
+from ggrs_tpu.parallel import BatchedRequestExecutor, session_pool
+from test_session_pool import _drive, _make_matches, _to_arr
+
+session_pool._RELAY_MIN_BYTES = 1
+digests = []
+for _ in range(2):
+    sessions, schedules = _make_matches(2, seed=5)
+    game = ParticleWorld(2, 256, 8, 16)
+    pool = BatchedRequestExecutor(
+        game.advance, game.init_state(), _to_arr,
+        batch_size=4, ring_length=10, max_burst=9,
+    )
+    pool.warmup(np.zeros((2,), np.uint8))
+    _drive(sessions, schedules, pool.run, 12)
+    digests.append(hex(pool.ring_checksum(0, sessions[0].current_frame - 1)))
+    out["held"] = list(pool._carry["ring"]["states"]["rotation"]
+                       .format.layout.tiling[0])
+    jax.clear_caches()
+out["digests"] = digests
+out["cached"] = sorted(name.split("-")[0] for name in os.listdir(cache))
+print("PROBE " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def cache_probe():
+    import json
+    import subprocess
+
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [str(REPO), str(REPO / "tests"), os.environ.get("PYTHONPATH", "")]))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    done = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE], env=env, capture_output=True,
+        text=True, timeout=600,
+    )
+    lines = [l for l in done.stdout.splitlines() if l.startswith("PROBE ")]
+    assert done.returncode == 0 and lines, done.stderr[-3000:]
+    return json.loads(lines[-1][len("PROBE "):])
+
+
+def test_jaxlib_mislabels_a_cached_executables_result(cache_probe):
+    """The reason for ``_compiled_in_process``: a result that has a layout
+    of its own keeps it, and says so, when its program was compiled in this
+    process; loaded from the persistent cache the values are right and the
+    label is the default's.  When a newer jaxlib makes this case FAIL, the
+    cache has learned layouts: delete ``_compiled_in_process`` and
+    ``_IN_PROCESS_COMPILER_OPTIONS``, and this case with them."""
+    assert cache_probe["compiled_here"] == [1, 2, 0]
+    assert cache_probe["values"] is True
+    assert cache_probe["from_the_cache"] == [0, 1, 2]
+
+
+def test_a_relaid_pool_never_comes_from_the_persistent_cache(cache_probe):
+    assert cache_probe["held"] == [4, 128]
+    # the second pool found the first one's cache and still holds its ring so
+    assert len(set(cache_probe["digests"])) == 1
+    # the two programs that return re-laid leaves were never written to it;
+    # what reads the ring (eager slices: results in the default layout) was
+    assert "jit_tick" not in cache_probe["cached"]
+    assert "jit_fresh" not in cache_probe["cached"]
+    assert "jit__fetch" in cache_probe["cached"]
