@@ -101,6 +101,11 @@ _OBS_RING_RELAID_BYTES = default_registry().gauge(
     "executor holds row-major between ticks instead of in the device's "
     "default layout (0: the rule left every leaf alone)",
 )
+_OBS_MESH_DEVICES = default_registry().gauge(
+    "ggrs_executor_mesh_devices",
+    "devices the newest pooled executor's session axis is sharded over "
+    "(1: no mesh)",
+)
 _OBS_BURST_DEPTH = default_registry().histogram(
     "ggrs_executor_burst_depth_frames",
     "deepest per-session advance burst (replay depth) per dispatched tick",
@@ -516,11 +521,12 @@ class BatchedRequestExecutor:
         # buffers are deliberately NOT pooled — see _reset_desc.)
         self._ref_rings: List[Optional[List[Any]]] = [None] * batch_size
         self.mesh = mesh
-        if mesh is not None:
-            assert batch_size % mesh.devices.size == 0, (
-                f"batch_size {batch_size} must divide evenly over "
-                f"{mesh.devices.size} mesh devices"
-            )
+        # devices the session axis is divided over, in contiguous blocks
+        self._shards = mesh.devices.size if mesh is not None else 1
+        assert batch_size % self._shards == 0, (
+            f"batch_size {batch_size} must divide evenly over "
+            f"{self._shards} mesh devices"
+        )
 
         state0 = jax.tree_util.tree_map(jnp.asarray, init_state)
         program = tick_program(
@@ -555,7 +561,8 @@ class BatchedRequestExecutor:
         # what the executor holds on the device, for a ledger line to tell
         # the ring's own bytes from the lowering's copies (DESIGN.md §14);
         # the session axis shards evenly, so a device's share is 1/size
-        devices = mesh.devices.size if mesh is not None else 1
+        devices = self._shards
+        _OBS_MESH_DEVICES.set(devices)
         _OBS_STATE_BYTES.set(
             sum(l.nbytes for l in jax.tree_util.tree_leaves(state0))
         )
@@ -916,8 +923,16 @@ class BatchedRequestExecutor:
 
     def _launch(self, desc: Dict[str, Any]) -> None:
         """The call of the tick program: transfer of the descriptors and
-        enqueue; in a closed loop the runtime's back-pressure too."""
-        with self.tracer.span("device.launch"):
+        enqueue; in a closed loop the runtime's back-pressure too.  The
+        descriptors are host arrays of all sessions: over a mesh the call
+        splits each on the session axis and sends every device its block,
+        so one dispatch is ``len(desc)`` transfers a device (DESIGN.md §3;
+        benchmark: launch_transfers_per_dispatch)."""
+        shards = self._shards
+        with self.tracer.span(
+            "device.launch",
+            shards=shards, transfers=len(desc) * shards, dispatches=1,
+        ):
             self._carry = self._tick(self._carry, desc)
 
     def run(self, request_lists: Sequence[List[GgrsRequest]]) -> None:

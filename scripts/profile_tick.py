@@ -159,7 +159,7 @@ def build_cell(cell: str, matches, seed: int, ticks: int):
     spec = run.load_cell(run.REPO, cell)
     config, traffic = spec["config"], spec["traffic"]
     matches = int(matches or spec["size"]["matches"])
-    pool = run.Pool(config, traffic, matches, seed)
+    pool = run.Pool(config, traffic, matches, seed, int(spec["cell"]["chips"]))
     inputs = run.Inputs(traffic, seed, matches, int(config["players"]),
                         ticks + WARM_TICKS + 1, int(config["input_delay"]))
     if not pool.host.native_active:
@@ -410,9 +410,15 @@ def dump_hlo(cell, matches, path):
 
     import jax
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
+    from jax.sharding import (
+        Mesh,
+        NamedSharding,
+        PartitionSpec,
+        SingleDeviceSharding,
+    )
 
     from benchmark import run
+    from ggrs_tpu.parallel.batch import SESSION_AXIS
     from ggrs_tpu.parallel.session_pool import blank_desc, tick_program
 
     spec = run.load_cell(run.REPO, cell)
@@ -420,14 +426,23 @@ def dump_hlo(cell, matches, path):
     adapter = importlib.import_module(f"benchmark.adapters.{config['adapter']}")
     sessions = int(matches or spec["size"]["matches"]) * int(config["players"])
     game = adapter.make_game(config)
-    chip = topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0]
+    chips = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+    n = int(spec["cell"]["chips"])
+    if n > 1:
+        # the cell's mesh, of described chips: each shard's program, under
+        # the operation names a profile of the real mesh shows
+        mesh = Mesh(np.asarray(chips[:n]), (SESSION_AXIS,))
+        where = {"mesh": mesh}
+        placed = NamedSharding(mesh, PartitionSpec((SESSION_AXIS,)))
+    else:
+        where = {"device": chips[0]}
+        placed = SingleDeviceSharding(chips[0])
     program = tick_program(game.advance, game.init_state(), sessions,
-                           int(config["ring_length"]), device=chip)
+                           int(config["ring_length"]), **where)
     example = adapter.example_inputs(config)
     desc = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                       sharding=SingleDeviceSharding(chip)),
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=placed),
         blank_desc(sessions, int(config["max_burst"]),
                    example.shape, example.dtype))
     compiled = program.tick.lower(program.carry, desc).compile()
@@ -487,8 +502,6 @@ def profile_ticks(pool, inputs, ticks, hz, keep_dir=None, ops_json=None,
     import jax
     from jax.profiler import ProfileData
 
-    from ggrs_tpu.obs.trace import ANNOTATION_PREFIX, profile_clock_offset_ns
-
     trace_dir = Path(keep_dir or tempfile.mkdtemp(prefix="ggrs_profile_"))
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
@@ -498,26 +511,50 @@ def profile_ticks(pool, inputs, ticks, hz, keep_dir=None, ops_json=None,
     finally:
         jax.profiler.stop_trace()
     xplane = max(trace_dir.rglob("*.xplane.pb"), key=lambda f: f.stat().st_mtime)
-    host, modules, ops, op_events = {}, [], {}, []
-    for plane in ProfileData.from_file(str(xplane)).planes:
+    print(f"\n# one profile: {ticks} ticks, {xplane.stat().st_size / 1e6:.1f} MB "
+          f"({xplane})")
+    host, modules, ops, op_events = read_profile(
+        ProfileData.from_file(str(xplane)).planes)
+    print_profile(host, modules, ops, op_events,
+                  hlo_scopes(Path(hlo).read_text()) if hlo else {}, ops_json)
+
+
+def read_profile(planes):
+    """``ggrs.*`` spans of the host planes by name and tick, and per device
+    plane (a mesh has one a chip) its ``jit_tick`` programs, its operations'
+    seconds by name and the operation events themselves."""
+    from ggrs_tpu.obs.trace import ANNOTATION_PREFIX
+
+    host, modules, ops, op_events = {}, {}, {}, {}
+    for plane in planes:
         device = plane.name.startswith("/device:TPU:")
         for line in plane.lines:
             if device and line.name == "XLA Modules":
-                modules += [(ev.start_ns, ev.duration_ns) for ev in line.events
-                            if ev.name.startswith("jit_tick")]
+                modules.setdefault(plane.name, []).extend(
+                    (ev.start_ns, ev.duration_ns) for ev in line.events
+                    if ev.name.startswith("jit_tick"))
             elif device and line.name == "XLA Ops":
+                seconds = ops.setdefault(plane.name, {})
+                events = op_events.setdefault(plane.name, [])
                 for ev in line.events:
                     name = ev.name.partition(" = ")[0].lstrip("%")
-                    ops[name] = ops.get(name, 0.0) + ev.duration_ns / 1e9
-                    op_events.append((ev.start_ns, -ev.duration_ns, name))
+                    seconds[name] = seconds.get(name, 0.0) + ev.duration_ns / 1e9
+                    events.append((ev.start_ns, -ev.duration_ns, name))
             elif not device:
                 for ev in line.events:
                     if ev.name.startswith(ANNOTATION_PREFIX):
                         stats = dict(ev.stats)
                         host.setdefault(ev.name[len(ANNOTATION_PREFIX):], {})[
                             stats.get("tick")] = (ev.start_ns, ev.duration_ns, stats)
-    print(f"\n# one profile: {ticks} ticks, {xplane.stat().st_size / 1e6:.1f} MB "
-          f"({xplane})")
+    return host, modules, ops, op_events
+
+
+def print_profile(host, modules, ops, op_events, scopes, ops_json=None):
+    """What ``read_profile`` found, a column a device: launch, program and
+    fence on one clock, the device's seconds by operation and by the scope
+    ``scopes`` (``hlo_scopes`` of the program's text) gives each."""
+    from ggrs_tpu.obs.trace import profile_clock_offset_ns
+
     print("  ggrs.* events on the host plane: "
           + ", ".join(f"{k} {len(v)}" for k, v in sorted(host.items())))
     anchors = [(s["perf_ns"], start) for start, _d, s
@@ -529,55 +566,75 @@ def profile_ticks(pool, inputs, ticks, hz, keep_dir=None, ops_json=None,
     if not modules:
         print("  no device plane (CPU backend): nothing to set the spans against")
         return
-    modules.sort()
+    planes = sorted(modules, key=lambda name: int(name.rpartition(":")[2]))
+    short = [name.rpartition("/")[2] for name in planes]  # device:TPU:n
     launches = sorted(host.get("device.launch", {}).items())
     fences = host.get("device.fence", {})
-    rows = []
-    for (tick, (l_start, l_dur, _)), (m_start, m_dur) in zip(launches, modules):
-        fence = fences.get(tick)
-        if fence is None:
-            continue
-        f_start, f_dur, _ = fence
-        rows.append((m_start - l_start, m_start - (l_start + l_dur), m_dur,
-                     f_start + f_dur - (m_start + m_dur),
-                     f_start - (m_start + m_dur), f_dur))
-    if rows:
-        a = np.asarray(rows, float) / 1e3
-        names = ("launch start -> program start", "launch end -> program start",
-                 "program (jit_tick)", "program end -> fence end",
-                 "program end -> fence start", "fence span")
-        print(f"  {len(rows)} ticks, {len(modules)} jit_tick programs; us, "
-              f"p50 (p5 .. p95):")
+    names = ("launch start -> program start", "launch end -> program start",
+             "program (jit_tick)", "program end -> fence end",
+             "program end -> fence start", "fence span")
+    columns = []
+    for plane in planes:
+        rows = []
+        for (tick, (l_start, l_dur, _)), (m_start, m_dur) in zip(
+                launches, sorted(modules[plane])):
+            fence = fences.get(tick)
+            if fence is None:
+                continue
+            f_start, f_dur, _ = fence
+            rows.append((m_start - l_start, m_start - (l_start + l_dur), m_dur,
+                         f_start + f_dur - (m_start + m_dur),
+                         f_start - (m_start + m_dur), f_dur))
+        columns.append(np.asarray(rows, float) / 1e3 if rows else None)
+    if any(a is not None for a in columns):
+        print(f"  {len(launches)} launches, "
+              f"{sum(len(m) for m in modules.values())} jit_tick programs on "
+              f"{len(planes)} device(s); us, p50 (p5 .. p95), a column a device: "
+              + ", ".join(short))
         for k, name in enumerate(names):
-            p5, p50, p95 = np.percentile(a[:, k], [5, 50, 95])
-            print(f"    {name:<32}{p50:10.1f}  ({p5:.1f} .. {p95:.1f})")
+            cells = []
+            for a in columns:
+                if a is None:
+                    cells.append("-")
+                    continue
+                p5, p50, p95 = np.percentile(a[:, k], [5, 50, 95])
+                cells.append(f"{p50:10.1f}  ({p5:.1f} .. {p95:.1f})")
+            print(f"    {name:<32}" + "  ".join(cells))
     # the text of an executable loaded on the chip carries no op_name
     # metadata; the same program compiled for the described chip does, under
     # the same operation names (--dump-hlo, which needs no chip)
-    scopes = hlo_scopes(Path(hlo).read_text()) if hlo else {}
     if not scopes:
         print("  (no scopes: make the program's text with --dump-hlo OUT under "
               "JAX_PLATFORMS=cpu and pass --hlo OUT)")
-    print(f"  device time by operation ({len(ops)} names), with the scope the "
-          f"compiled program gives each:")
-    for name, sec in sorted(ops.items(), key=lambda kv: -kv[1])[:16]:
-        print(f"    %{name:<34}{scopes.get(name, '?'):<26}{sec:10.4f} s")
-    own = own_seconds(op_events)
+    every = sorted({name for plane in planes for name in ops[plane]},
+                   key=lambda n: -sum(ops[p].get(n, 0.0) for p in planes))
+    print(f"  device time by operation ({len(every)} names), with the scope the "
+          f"compiled program gives each; seconds, a column a device:")
+    for name in every[:16]:
+        print(f"    %{name:<34}{scopes.get(name, '?'):<26}"
+              + "".join(f"{ops[p].get(name, 0.0):10.4f}" for p in planes))
+    own = {plane: own_seconds(op_events[plane]) for plane in planes}
     if ops_json:
         import json
 
         Path(ops_json).write_text(json.dumps(
-            {name: {"total_s": ops[name], "own_s": own.get(name, 0.0),
-                    "scope": scopes.get(name, "?")} for name in ops}, indent=0))
+            {key: {name: {"total_s": ops[plane][name],
+                          "own_s": own[plane].get(name, 0.0),
+                          "scope": scopes.get(name, "?")} for name in ops[plane]}
+             for key, plane in zip(short, planes)}, indent=0))
     by_scope = {}
-    for name, sec in own.items():
-        key = scopes.get(name, "?")
-        by_scope[key] = by_scope.get(key, 0.0) + sec
-    total = sum(by_scope.values()) or 1.0
-    print(f"  device time by scope, each operation's own time (sum "
-          f"{total:.4f} s = the device's busy time):")
-    for key, sec in sorted(by_scope.items(), key=lambda kv: -kv[1]):
-        print(f"    {key:<40}{sec:10.4f} s  {100 * sec / total:5.1f}%")
+    for k, plane in enumerate(planes):
+        for name, sec in own[plane].items():
+            row = by_scope.setdefault(scopes.get(name, "?"), [0.0] * len(planes))
+            row[k] += sec
+    totals = [sum(row[k] for row in by_scope.values()) or 1.0
+              for k in range(len(planes))]
+    print("  device time by scope, each operation's own time; seconds and share "
+          "of the device's busy time, a column a device (busy: "
+          + ", ".join(f"{t:.4f} s" for t in totals) + "):")
+    for key, row in sorted(by_scope.items(), key=lambda kv: -sum(kv[1])):
+        print(f"    {key:<40}" + "".join(
+            f"{sec:10.4f} s {100 * sec / t:5.1f}%" for sec, t in zip(row, totals)))
 
 
 def bar(us, full_us, width=24):
