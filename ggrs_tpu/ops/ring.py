@@ -7,7 +7,7 @@ axis that lives in HBM for the whole session, and neither *save* nor *load*
 moves a byte to the host.  Checksums for each slot are kept in a parallel
 ``(R, 4)`` uint32 array so desync/synctest comparisons are device-side too.
 
-Two forms of write, by who indexes (docs/DESIGN.md §3):
+Forms of write, by who indexes and by size (docs/DESIGN.md §3):
 
 - ``save`` / ``save_many`` write one slot at a dynamic index: an in-place
   slice update when the index is ONE scalar shared by the whole batch (the
@@ -15,7 +15,13 @@ Two forms of write, by who indexes (docs/DESIGN.md §3):
 - ``save_where`` selects over the whole ring axis and holds no dynamic index:
   the form for sessions batched under ``vmap`` at frames of their own (the
   served pool), where an indexed write would be a scatter that XLA:TPU runs
-  as a serial loop over the sessions.
+  as a serial loop over the sessions.  It rewrites all ``R`` slots to change
+  one: right while a ring leaf is kilobytes or megabytes.
+- ``save_where_batch`` is ``save_where`` for the whole batch at once, and
+  chooses per leaf: the select, or ``write_slot``, a kernel that moves the
+  one slot a session saves and nothing else, for the leaves the caller holds
+  row-major (gigabytes of ring, where the select moved 2.66 GB to change
+  0.27).
 
 *load* is a dynamic slice (a gather under a per-session frame).
 
@@ -29,12 +35,104 @@ program's boundary").
 
 from __future__ import annotations
 
-from typing import Any, Tuple
+import math
+from typing import Any, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .checksum import CHECKSUM_LANES
+
+# The in-place write holds two blocks of one slot in VMEM, each double
+# buffered (the state to save, the slot as written): four slots' worth,
+# under the 16 MiB a kernel may use on a v5e.
+_SLOT_BLOCK_MAX_BYTES = 2 << 20
+
+
+def writes_slot_in_place(shape: Sequence[int], itemsize: int) -> bool:
+    """Whether ``write_slot`` takes a ring leaf ``[B, R, ...]``: one slot has
+    to be a block of its own (two trailing dimensions or more: a slot of
+    ``[B, R, N]`` is one row of a tile that holds other slots) and to fit
+    the kernel's VMEM.  The kernel's own limits; WHERE an in-place write
+    pays is the caller's rule (``parallel/session_pool.py``)."""
+    return (
+        len(shape) >= 4
+        and math.prod(shape[2:]) * itemsize <= _SLOT_BLOCK_MAX_BYTES
+    )
+
+
+def write_slot(
+    buf: jax.Array,
+    leaf: jax.Array,
+    slot: jax.Array,
+    pred: jax.Array,
+    interpret: bool = False,
+) -> jax.Array:
+    """``buf[b, slot[b]] = leaf[b]`` for every session ``b`` whose ``pred[b]``
+    is true, and no other byte of ``buf`` read or written.  At least one
+    ``pred`` has to be true (``save_where_batch`` asks first).
+
+    ``buf`` is ``[B, R, ...]`` held row-major (so that ``[b, r]`` is one
+    contiguous block), ``leaf`` ``[B, ...]``, ``slot`` ``[B]`` int32 in
+    ``[0, R)``, ``pred`` ``[B]`` bool.  One grid step a session; the ring is
+    operand AND aliased result, and only the result is blocked, at
+    ``(b, slot[b])`` read from scalar memory: a session that writes puts its
+    state there, whole, so the slot as it was is never read.  A session that
+    does not write must move nothing: its step visits the block of the
+    nearest session before it that does (of the first that does, for those
+    before it) and leaves it as it is, and consecutive steps at one block
+    index neither fetch nor write back.  So a write moves state in and slot
+    out for the sessions that save, where the select of ``save_where`` reads
+    and writes all ``R`` slots of every session.
+
+    ``interpret`` runs the same kernel under Pallas's interpreter (off the
+    TPU: the program tier-1 runs is the program the chip runs)."""
+    sessions = buf.shape[0]
+    rest = buf.shape[2:]
+    zeros = (0,) * len(rest)
+    at = jnp.arange(sessions, dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(pred, at, -1))
+    visit = jnp.where(last < 0, jnp.argmax(pred).astype(jnp.int32), last)
+
+    def kernel(visit_ref, slot_ref, pred_ref, leaf_ref, ring_ref, out_ref):
+        del visit_ref, slot_ref  # read by the block maps
+        del ring_ref  # the result's buffer: every block written is written whole
+
+        @pl.when(pred_ref[pl.program_id(0)] != 0)
+        def _():
+            out_ref[...] = leaf_ref[...][:, None]
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(sessions,),
+            in_specs=[
+                pl.BlockSpec(
+                    (1,) + rest,
+                    lambda b, visit, slot, pred: (visit[b],) + zeros,
+                ),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1) + rest,
+                lambda b, visit, slot, pred: (visit[b], slot[b]) + zeros,
+            ),
+        ),
+        # operands: visit, slot, pred, leaf, buf -> the ring is the result
+        input_output_aliases={4: 0},
+        name="ring_write_slot",
+        interpret=interpret,
+    )(
+        visit,
+        jnp.asarray(slot, jnp.int32)[visit],
+        jnp.asarray(pred, jnp.int32),
+        jnp.asarray(leaf, buf.dtype),
+        buf,
+    )
 
 
 class DeviceStateRing:
@@ -122,13 +220,11 @@ class DeviceStateRing:
         slot: 512 sessions x 10 slots x 40 B = 205 KB a write for
         ``boxgame-2p``, 256 x 18 x 3,104 B = 14.3 MB for ``ecs-4p`` (0.66 ms
         of 19 writes a tick at 819 GB/s, against 92 ms for the scatter).
-        That is the wrong trade once B x R x state bytes reaches gigabytes
-        (ROADMAP B2/M7): choose the write there from those three numbers,
-        which this method can see.  Measured there (``particles-2p``):
-        512 x 10 x 520,028 B = 2.66 GB a write; 11 writes a tick and a tick
-        program of 198 ms at PR 29, 3 writes (pre-save, 2 burst steps) and
-        68.65 ms since PR 30 ends the burst at the batch's deepest plan
-        (PERF.md section 5)."""
+        That is the wrong trade once B x R x state bytes reaches gigabytes:
+        measured there (``particles-2p``), 512 x 10 x 520,028 B = 2.66 GB
+        read and written at each of a tick's 3 writes, 30.5 of a 51.6 ms
+        tick program (PERF.md section 5, PR 32).  ``save_where_batch`` is
+        the way out: per leaf, this select or ``write_slot``."""
         hit = (
             jnp.arange(self.length, dtype=jnp.int32) == self.slot(frame)
         ) & pred
@@ -142,6 +238,65 @@ class DeviceStateRing:
             "checksums": upd(ring["checksums"], checksum),
             "frames": upd(ring["frames"], frame),
         }
+
+    def save_where_batch(
+        self,
+        ring: Any,
+        frame: jax.Array,
+        state: Any,
+        checksum: jax.Array,
+        pred: jax.Array,
+        in_place: Any,
+        interpret: bool = False,
+    ) -> Any:
+        """``save_where`` for a whole batch at once: ``ring`` ``[B, R, ...]``,
+        ``frame`` and ``pred`` ``[B]``, ``state`` ``[B, ...]``, ``checksum``
+        ``[B, 4]``; the same bytes as ``jax.vmap(save_where)``.
+
+        ``in_place`` is shaped like ``ring["states"]`` and says per leaf which
+        form writes it: false, the select (every leaf of a small pool; the
+        digests and frame tags always: kilobytes); true, ``write_slot``, for
+        a leaf the caller holds row-major and ``writes_slot_in_place`` takes.
+        It stands at batch level because a kernel whose block maps read the
+        sessions' slots has no useful rule under ``vmap``."""
+        bufs, tree = jax.tree_util.tree_flatten(ring["states"])
+        # (the caller may hold a slot with unit axes the state has not)
+        leaves = [
+            leaf.reshape(buf.shape[:1] + buf.shape[2:])
+            for leaf, buf in zip(tree.flatten_up_to(state), bufs)
+        ]
+        direct = tree.flatten_up_to(in_place)
+        by_select = [i for i, d in enumerate(direct) if not d]
+        by_kernel = [i for i, d in enumerate(direct) if d]
+        out = jax.vmap(self.save_where)(
+            {**ring, "states": [bufs[i] for i in by_select]},
+            frame,
+            [leaves[i] for i in by_select],
+            checksum,
+            pred,
+        )
+        for i, buf in zip(by_select, out["states"]):
+            bufs[i] = buf
+        if by_kernel:
+            # an idle row's frame of -1 matches no slot: the index the block
+            # map reads is clamped, and the predicate says nothing is written
+            ok = pred & (frame >= 0)
+            slot = jnp.where(ok, self.slot(frame), 0)
+            # a write in which no session saves (the second step of a burst
+            # whose sessions rolled back one frame) runs no kernel: the ring
+            # passes through the conditional uncopied
+            after = jax.lax.cond(
+                jnp.any(ok),
+                lambda before: [
+                    write_slot(buf, leaves[i], slot, ok, interpret)
+                    for i, buf in zip(by_kernel, before)
+                ],
+                lambda before: before,
+                [bufs[i] for i in by_kernel],
+            )
+            for i, buf in zip(by_kernel, after):
+                bufs[i] = buf
+        return {**out, "states": tree.unflatten(bufs)}
 
     def save_many(
         self,

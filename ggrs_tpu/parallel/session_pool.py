@@ -37,7 +37,9 @@ device→host reads.  The carry is donated to every tick; a large ring leaf
 is made, passed and returned in the layout the tick program computes in
 (``ring_leaf_layout`` below, chosen per leaf from its shape where
 ``tick_program`` builds the carry), so no whole-ring transposition stands
-at the program's entry or exit.
+at the program's entry or exit, and a save writes such a leaf in place: the
+one slot a session saves, not a select over all of them (``ops/ring.py``
+``write_slot``; docs/DESIGN.md §3 "Per-session slots").
 """
 
 from __future__ import annotations
@@ -100,6 +102,12 @@ _OBS_RING_RELAID_BYTES = default_registry().gauge(
     "bytes, on its fullest device, of the ring leaves the newest pooled "
     "executor holds row-major between ticks instead of in the device's "
     "default layout (0: the rule left every leaf alone)",
+)
+_OBS_RING_INPLACE_BYTES = default_registry().gauge(
+    "ggrs_executor_ring_inplace_bytes",
+    "bytes, on its fullest device, of the ring leaves whose saves the newest "
+    "pooled executor writes in place, one slot a session, instead of by a "
+    "select over every slot (0: none is)",
 )
 _OBS_MESH_DEVICES = default_registry().gauge(
     "ggrs_executor_mesh_devices",
@@ -203,6 +211,8 @@ class TickProgram(NamedTuple):
     tick: Callable[[Dict[str, Any], Dict[str, Any]], Dict[str, Any]]
     formats: Dict[str, Any]  # carry-shaped: a Format where re-laid, else None
     carry: Dict[str, Any]  # carry-shaped ShapeDtypeStructs, placed as held
+    # shaped like the ring's states: whether a save writes the leaf in place
+    in_place: Any
 
 
 def tick_program(
@@ -221,34 +231,56 @@ def tick_program(
     program without a chip (``scripts/profile_tick.py --dump-hlo``)."""
     from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
-    from ..ops.ring import DeviceStateRing
+    from ..ops.ring import DeviceStateRing, writes_slot_in_place
 
     B = batch_size
     dring = DeviceStateRing(ring_length)
     zero_cs = jnp.zeros((CHECKSUM_LANES,), jnp.uint32)
-
-    def fresh(state0: Any) -> Dict[str, Any]:
-        def per_session(l):
-            return jnp.broadcast_to(l[None, ...], (B,) + l.shape)
-
-        return {
-            "live": jax.tree_util.tree_map(per_session, state0),
-            # one DeviceStateRing (states / checksums / frames) per session,
-            # stacked on a leading B axis; its frame tags back the host-side
-            # accessors and the _parse-time ring-capacity guard
-            "ring": jax.tree_util.tree_map(per_session, dring.init(state0)),
-        }
 
     if mesh is not None:
         # the session axis over every mesh axis; the rule reads a shard
         spec_b = PartitionSpec(tuple(mesh.axis_names))
         sharding: Any = NamedSharding(mesh, spec_b)
         shards = mesh.devices.size
+        platform = mesh.devices.flat[0].platform
     else:
         if device is None:
             (device,) = jnp.zeros(()).devices()
         sharding = SingleDeviceSharding(device)
         shards = 1
+        platform = device.platform
+
+    # A re-laid leaf of one state dimension, [B, R, N], is held with a unit
+    # axis, [B, R, 1, N]: row-major as it is, the RING axis would be the
+    # tile's second-minor (ten slots padded to sixteen rows, one slot one row
+    # of a tile that holds seven others); with the axis a slot of a session
+    # is a block of its own under (1, 128), which the in-place write needs.
+    unit_axis = jax.tree_util.tree_map(
+        lambda l: l.ndim == 1
+        and ring_leaf_layout(
+            (B // shards, ring_length, 1) + l.shape, l.dtype.itemsize
+        )
+        is not None,
+        state0,
+    )
+
+    def fresh(state0: Any) -> Dict[str, Any]:
+        def per_session(l):
+            return jnp.broadcast_to(l[None, ...], (B,) + l.shape)
+
+        ring = jax.tree_util.tree_map(per_session, dring.init(state0))
+        ring["states"] = jax.tree_util.tree_map(
+            lambda l, unit: l[:, :, None] if unit else l,
+            ring["states"],
+            unit_axis,
+        )
+        return {
+            "live": jax.tree_util.tree_map(per_session, state0),
+            # one DeviceStateRing (states / checksums / frames) per session,
+            # stacked on a leading B axis; its frame tags back the host-side
+            # accessors and the _parse-time ring-capacity guard
+            "ring": ring,
+        }
 
     shapes = jax.eval_shape(fresh, state0)
 
@@ -279,34 +311,60 @@ def tick_program(
     )
     init = jax.jit(fresh, out_shardings=held, compiler_options=options)
 
-    def session_tick(
-        live: Any,
-        ring: Any,
-        pre_save: jax.Array,
-        pre_frame: jax.Array,
-        do_load: jax.Array,
-        load_frame: jax.Array,
-        postload_save: jax.Array,
-        postload_frame: jax.Array,
-        n_adv: jax.Array,
-        inputs: Any,  # [max_burst, ...]
-        save_mask: jax.Array,  # [max_burst]
-        save_frame: jax.Array,  # [max_burst]
-        # what the whole batch asks (unbatched):
-        n_steps: jax.Array,  # its deepest n_adv
-        any_postload: jax.Array,  # whether any session saves after its load
-    ):
+    # which ring leaves a save writes in place, touching the one slot it
+    # saves: those held row-major between ticks (a slot of a session is then
+    # one contiguous block), where the kernel takes the shape; every other
+    # leaf, and every leaf of a pool the rule leaves alone, keeps the select
+    # (docs/DESIGN.md §3 "Per-session slots").  Off the TPU the same kernel
+    # runs under Pallas's interpreter, so that the program tier-1 runs on
+    # the CPU is the program the chip runs.
+    in_place = jax.tree_util.tree_map(
+        lambda l, f: f is not None
+        and writes_slot_in_place(l.shape, l.dtype.itemsize),
+        shapes["ring"]["states"],
+        formats["ring"]["states"],
+    )
+    interpret = platform != "tpu"
+
+    def tick(carry: Dict[str, Any], desc: Dict[str, Any]) -> Dict[str, Any]:
+        """One tick of every session: the per-session pieces (the digest,
+        the load, the game's step) under ``vmap``, the ring write at batch
+        level (``save_where_batch``).  Under ``shard_map`` the batch is a
+        shard's own: no collective."""
+        live, ring = carry["live"], carry["ring"]
+        sessions = desc["n_adv"].shape[0]
+        # what the whole batch asks:
+        n_steps = jnp.max(desc["n_adv"])  # its deepest plan
+        # whether any session saves after its load
+        any_postload = jnp.any(desc["postload_save"])
+
         # the scopes name the program's parts in a device profile
         # (metadata only: the lowered operations are the same)
         def write(ring, frame, st, pred):
             with jax.named_scope("digest"):
-                cs = checksum_device(st) if with_checksums else zero_cs
-            return dring.save_where(ring, frame, st, cs, pred)
+                cs = (
+                    jax.vmap(checksum_device)(st)
+                    if with_checksums
+                    else jnp.broadcast_to(zero_cs, (sessions,) + zero_cs.shape)
+                )
+            return dring.save_where_batch(
+                ring, frame, st, cs, pred, in_place, interpret
+            )
+
+        per_session_where = jax.vmap(_tree_where)
 
         with jax.named_scope("ring.pre_save"):
-            ring = write(ring, pre_frame, live, pre_save)
+            ring = write(ring, desc["pre_frame"], live, desc["pre_save"])
         with jax.named_scope("ring.load"):
-            st = _tree_where(do_load, dring.load(ring, load_frame), live)
+            loaded = jax.vmap(dring.load)(ring, desc["load_frame"])
+            st = per_session_where(
+                desc["do_load"],
+                # (a slot held with a unit axis, as the state has it)
+                jax.tree_util.tree_map(
+                    lambda got, l: got.reshape(l.shape), loaded, live
+                ),
+                live,
+            )
         # sparse saving can save the just-loaded state before any advance
         # (reference: p2p_session.rs:666-672 — the min_confirmed save);
         # a batch in which no session does skips the write and its
@@ -315,7 +373,9 @@ def tick_program(
         with jax.named_scope("ring.save"):
             ring = jax.lax.cond(
                 any_postload,
-                lambda ring: write(ring, postload_frame, st, postload_save),
+                lambda ring: write(
+                    ring, desc["postload_frame"], st, desc["postload_save"]
+                ),
                 lambda ring: ring,
                 ring,
             )
@@ -331,37 +391,20 @@ def tick_program(
         def step(j, carry):
             st, ring = carry
             inp, smask, sframe = (
-                jax.lax.dynamic_index_in_dim(col, j, 0, keepdims=False)
-                for col in (inputs, save_mask, save_frame)
+                jax.lax.dynamic_index_in_dim(col, j, 1, keepdims=False)
+                for col in (
+                    desc["inputs"], desc["save_mask"], desc["save_frame"]
+                )
             )
-            act = j < n_adv
+            act = j < desc["n_adv"]
             with jax.named_scope("advance"):
-                st = _tree_where(act, advance(st, inp), st)
+                st = per_session_where(act, jax.vmap(advance)(st, inp), st)
             with jax.named_scope("ring.save"):
                 ring = write(ring, sframe, st, act & smask)
             return st, ring
 
-        return jax.lax.fori_loop(jnp.int32(0), n_steps, step, (st, ring))
-
-    def tick(carry: Dict[str, Any], desc: Dict[str, Any]) -> Dict[str, Any]:
-        # under shard_map both are each shard's own: no collective
-        n_steps = jnp.max(desc["n_adv"])
-        any_postload = jnp.any(desc["postload_save"])
-        live, ring = jax.vmap(
-            lambda *session: session_tick(*session, n_steps, any_postload)
-        )(
-            carry["live"],
-            carry["ring"],
-            desc["pre_save"],
-            desc["pre_frame"],
-            desc["do_load"],
-            desc["load_frame"],
-            desc["postload_save"],
-            desc["postload_frame"],
-            desc["n_adv"],
-            desc["inputs"],
-            desc["save_mask"],
-            desc["save_frame"],
+        live, ring = jax.lax.fori_loop(
+            jnp.int32(0), n_steps, step, (st, ring)
         )
         return {"live": live, "ring": ring}
 
@@ -394,6 +437,7 @@ def tick_program(
         ),
         formats,
         carry,
+        in_place,
     )
 
 
@@ -572,6 +616,17 @@ class BatchedRequestExecutor:
         )
         _OBS_RING_RELAID_BYTES.set(
             sum(leaf.nbytes for leaf, _ in relaid) // devices
+        )
+        _OBS_RING_INPLACE_BYTES.set(
+            sum(
+                leaf.nbytes
+                for leaf, direct in zip(
+                    jax.tree_util.tree_leaves(self._carry["ring"]["states"]),
+                    jax.tree_util.tree_leaves(program.in_place),
+                )
+                if direct
+            )
+            // devices
         )
         self._input_dtype: Optional[np.dtype] = None
         self._input_shape: Optional[Tuple[int, ...]] = None
@@ -1033,7 +1088,10 @@ class BatchedRequestExecutor:
         slot, _ = self._slot_probe(index, frame)
         return jax.device_get(
             jax.tree_util.tree_map(
-                lambda buf: buf[index, slot], self._carry["ring"]["states"]
+                # (a slot held with a unit axis, as the state has it)
+                lambda buf, l: buf[index, slot].reshape(l.shape[1:]),
+                self._carry["ring"]["states"],
+                self._carry["live"],
             )
         )
 

@@ -320,11 +320,12 @@ _STRUCTURE = ("while", "body", "cond")
 
 
 def scope_of(op_name: str) -> str:
-    """``jit(tick)/vmap(ring.save)/while/body/digest/mul`` -> ``ring.save >
-    digest``: the named scopes of ``session_tick`` on an operation's path,
+    """``jit(tick)/while/body/ring.save/digest/vmap()/mul`` -> ``ring.save >
+    digest``: the named scopes of the pool's ``tick`` on an operation's path,
     and under ``advance`` the game's own outermost scope, whatever its name
-    (``.../vmap(advance)/spawn/select_n`` -> ``advance > spawn``): a bare
-    part that is neither the operation at the path's end nor a loop's."""
+    (``.../advance/vmap(spawn)/select_n`` -> ``advance > spawn``): a part,
+    bare or under the ``vmap`` the game's step runs in, that is neither the
+    operation at the path's end nor a loop's."""
     found = []
     parts = op_name.split("/")
     for i, raw in enumerate(parts):
@@ -332,10 +333,11 @@ def scope_of(op_name: str) -> str:
         if part in SCOPES:
             if part not in found:
                 found.append(part)
-        elif (found and found[-1] == "advance" and raw == part
+        elif (found and found[-1] == "advance" and part
+              and raw in (part, f"vmap({part})")
               and i < len(parts) - 1 and raw not in _STRUCTURE
               and not raw.startswith("branch")):
-            found.append(raw)
+            found.append(part)
     return " > ".join(found) if found else "-"
 
 

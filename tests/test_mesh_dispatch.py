@@ -97,21 +97,34 @@ def _traffic(pool, seed):
     return descs, depths
 
 
+@pytest.mark.parametrize("rule", ["above", "below"])
 def test_a_pool_over_four_devices_equals_one_device_and_the_reference(
-        mesh, monkeypatch):
-    # the threshold lowered, as tests/test_ring_layout.py does: the rule is
-    # in force on a SHARD's leaf ([2, 10, ..., 256]), not on the whole one's
-    monkeypatch.setattr(session_pool, "_RELAY_MIN_BYTES", 1)
+        rule, mesh, monkeypatch):
+    """Above the rule a shard's wide ring leaves are held row-major and saved
+    in place (``ops/ring.py`` ``write_slot``, each shard its own kernel under
+    ``shard_map``); below it they keep the default layout and the select.
+    Either way: the reference's bytes."""
     per_device = default_registry().value  # the gauges: the newest executor's
     wide = RING * (3 + 4 + 3 + 2 + 1) * SMALL["capacity"] * 4
+    if rule == "above":
+        # the threshold lowered, as tests/test_ring_layout.py does: the rule
+        # is in force on a SHARD's leaf ([2, 10, ..., 256]), not on the
+        # whole one's
+        monkeypatch.setattr(session_pool, "_RELAY_MIN_BYTES", 1)
+    else:
+        wide = 0
     one = _pool()
     assert per_device("ggrs_executor_ring_relaid_bytes") == SESSIONS * wide
+    assert per_device("ggrs_executor_ring_inplace_bytes") == SESSIONS * wide
     across = _pool(mesh)
     assert per_device("ggrs_executor_ring_relaid_bytes") == 2 * wide
+    assert per_device("ggrs_executor_ring_inplace_bytes") == 2 * wide
     assert per_device("ggrs_executor_mesh_devices") == SHARDS
     for leaf in ("rotation", "ttl", "velocity"):
         held = across._carry["ring"]["states"][leaf]
         assert held.format.layout == one._carry["ring"]["states"][leaf].format.layout
+        # (the CPU's default is row-major too: below the rule this says
+        # nothing, above it the carry was made as the Format asked)
         assert held.format.layout.major_to_minor == tuple(range(held.ndim))
         assert len(held.sharding.device_set) == SHARDS
         assert {s.data.shape[0] for s in held.addressable_shards} == {2}
@@ -144,7 +157,9 @@ def test_a_pool_over_four_devices_equals_one_device_and_the_reference(
         for s in range(RING):
             for k, ref in session.slots[s].items():
                 np.testing.assert_array_equal(
-                    ring["states"][k][b, s], ref, f"session {b} slot {s} {k}")
+                    # (ttl's slot is held [1, N] above the rule)
+                    ring["states"][k][b, s].reshape(ref.shape), ref,
+                    f"session {b} slot {s} {k}")
             assert checksum_to_u128(ring["checksums"][b, s]) == session.digests[s]
     # and through the accessors the benchmark's comparison reads
     live = jax.device_get(across.live_states)

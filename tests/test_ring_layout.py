@@ -1,6 +1,8 @@
-"""The ring's layout at the tick program's boundary (DESIGN.md §3).
+"""The ring's layout at the tick program's boundary, and the save that
+writes one slot of a leaf held so (DESIGN.md §3).
 
-Three things, none on a chip: (a) the rule as a function of shapes; (b) the
+Four things, none on a chip: (e) the in-place write against the select,
+byte for byte, under Pallas's interpreter; (a) the rule as a function of shapes; (b) the
 tick compiled for a v5e that is described, not attached, from shapes alone
 (nothing of 2.9 GB is allocated): a count of what the compiler wrote, never
 a time; (c) on the CPU, that holding the ring in another layout changes no
@@ -21,6 +23,7 @@ from jax.experimental.layout import Format
 
 from ggrs_tpu.games import BoxGame, EcsWorld, ParticleWorld
 from ggrs_tpu.obs.registry import default_registry
+from ggrs_tpu.ops.ring import DeviceStateRing, writes_slot_in_place
 from ggrs_tpu.parallel import BatchedRequestExecutor, session_pool
 from ggrs_tpu.parallel.session_pool import (
     blank_desc,
@@ -43,7 +46,7 @@ CELLS = {
 }
 # the five 10,000-wide leaves of a particle state and the tile of each
 PARTICLE_TILES = {
-    "translation": 4, "rotation": 4, "scale": 4, "ttl": 8, "velocity": 2,
+    "translation": 4, "rotation": 4, "scale": 4, "ttl": 1, "velocity": 2,
 }
 
 
@@ -75,7 +78,9 @@ def _layouts(formats):
 def test_wide_particle_ring_leaves_are_held_row_major(leaf):
     program = _program("particles-2p")
     layout = _layouts(program.formats)[f"ring/states/{leaf}"]
+    # ttl, [B, R, N] by its state, is held [B, R, 1, N] (DESIGN.md §3)
     rank = len(program.carry["ring"]["states"][leaf].shape)
+    assert rank == 4
     assert layout.major_to_minor == tuple(range(rank))
     assert layout.tiling == ((PARTICLE_TILES[leaf], 128),)
     held = program.carry["ring"]["states"][leaf]
@@ -112,7 +117,9 @@ def test_the_small_cells_keep_the_default_everywhere(cell):
         ((512, 10, 3, 10000), 4, 4),
         ((512, 10, 2, 10000), 4, 2),
         ((512, 10, 1, 10000), 4, 1),
-        ((512, 10, 10000), 4, 8),  # the second-minor is the ring axis
+        # the second-minor is the ring axis: what the rule says of the shape;
+        # the pool holds such a leaf as the line before (DESIGN.md §3)
+        ((512, 10, 10000), 4, 8),
         ((512, 10, 16, 10000), 4, 8),
         ((128, 10, 4, 10000), 4, 4),  # a quarter of the pool: still large
         ((256, 18, 128), 4, None),  # ecs-4p: lane-wide, 2.4 MB
@@ -186,11 +193,55 @@ def _compiled(program, cell, chip, tick=None):
     return (tick or program.tick).lower(program.carry, desc).compile()
 
 
-def test_the_particle_tick_holds_no_ring_transpose_at_its_boundary(chip):
+@pytest.fixture(scope="module")
+def particle_tick(chip):
+    program = _program("particles-2p", device=chip)
+    return program, _compiled(program, "particles-2p", chip)
+
+
+def _ring_sized(text, sessions=512):
+    """(operation, K) -> count, over EVERY computation of a compiled text, of
+    the instructions whose result is a whole wide ring leaf
+    ``s32[sessions, 10, K, 10000]`` (K None: a leaf of rank 3, which no
+    carry holds any more)."""
+    found = {}
+    for m in re.finditer(
+        rf"= s32\[{sessions},10,(?:(\d+),)?10000\]\S* ([\w-]+)\(", text
+    ):
+        key = (m.group(2), m.group(1) and int(m.group(1)))
+        found[key] = found.get(key, 0) + 1
+    return found
+
+
+# the three writes of a tick: before the load, after it, in the burst loop
+_WRITES_A_TICK = 3
+
+
+def _assert_written_in_place(text, sessions=512):
+    found = _ring_sized(text, sessions)
+    assert found, "the census found no ring-sized result: it reads nothing"
+    moved = {k: n for k, n in found.items()
+             if k[0] in ("copy", "transpose", "select", "fusion")}
+    assert moved == {}
+    # one kernel a re-laid leaf a write, the ring its operand AND its result
+    # (K: rotation 4, scale and translation 3, velocity 2, ttl 1)
+    calls = {k[1]: n for k, n in found.items() if k[0] == "custom-call"}
+    assert calls == {
+        4: _WRITES_A_TICK, 3: 2 * _WRITES_A_TICK, 2: _WRITES_A_TICK,
+        1: _WRITES_A_TICK,
+    }
+    kernels = re.findall(r".*custom_call_target=\"tpu_custom_call\".*", text)
+    assert len(kernels) == 5 * _WRITES_A_TICK
+    for line in kernels:
+        assert "output_to_operand_aliasing={{}: (4, {})}" in line
+        assert "ring_write_slot" in line
+
+
+def test_the_particle_tick_holds_no_ring_transpose_at_its_boundary(
+        chip, particle_tick):
     from profile_tick import entry_copies
 
-    program = _program("particles-2p", device=chip)
-    compiled = _compiled(program, "particles-2p", chip)
+    program, compiled = particle_tick
     text = compiled.as_text()
     copies = entry_copies(text)
     assert copies, "the census found no copy at all: it reads nothing"
@@ -203,10 +254,12 @@ def test_the_particle_tick_holds_no_ring_transpose_at_its_boundary(chip):
     assert text.splitlines()[0].count("may-alias") == leaves == 16
     memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes - memory.alias_size_in_bytes < 4096
-    # the padded row-major ring: 3.5 GB, and under a gigabyte beside it
-    assert 3.4e9 < memory.argument_size_in_bytes < 3.6e9
+    # the padded row-major ring: 3.37 GB (T(4,128) pads the 3-word leaves to
+    # 4 rows; ttl's unit axis ended its padding, 3.50 GB with it), and under
+    # a gigabyte beside it
+    assert 3.3e9 < memory.argument_size_in_bytes < 3.5e9
     assert memory.temp_size_in_bytes < 1.0e9
-    # and it is made so: the initialiser writes 3.5 GB once, with no temporary
+    # and it is made so: the initialiser writes 3.4 GB once, with no temporary
     made = program.init.lower(
         jax.tree_util.tree_map(
             lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype),
@@ -218,6 +271,18 @@ def test_the_particle_tick_holds_no_ring_transpose_at_its_boundary(chip):
     assert {p: l for p, l in out.items() if l.major_to_minor[0] == 0} == {
         p: l for p, l in _layouts(program.formats).items() if l is not None
     }
+
+
+def test_the_particle_tick_writes_its_wide_leaves_in_place(particle_tick):
+    """The ring passes through a kernel inside the burst loop's body and
+    inside the conditional's branch: no computation holds a copy, a
+    transposition or a select of a whole wide leaf around it."""
+    program, compiled = particle_tick
+    assert program.in_place == {
+        "emitter": False, "resources": False, "ttl": True,
+        "rotation": True, "scale": True, "translation": True, "velocity": True,
+    }
+    _assert_written_in_place(compiled.as_text())
 
 
 def test_the_tick_over_a_mesh_of_four_chips_holds_none_either(chip):
@@ -241,7 +306,11 @@ def test_the_tick_over_a_mesh_of_four_chips_holds_none_either(chip):
         s for s in copies if re.match(r"\w+\[512,10,.*10000\]", s)
     ]
     assert not re.search(r"all-reduce|all-gather|collective-permute", text)
-    assert 3.4e9 < compiled.memory_analysis().argument_size_in_bytes < 3.6e9
+    assert 3.3e9 < compiled.memory_analysis().argument_size_in_bytes < 3.5e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    assert text.splitlines()[0].count("may-alias") == 16
+    # and each shard writes its own 512 sessions in place
+    _assert_written_in_place(text)
 
 
 @pytest.mark.parametrize("cell", ["boxgame-2p", "ecs-4p"])
@@ -249,10 +318,11 @@ def test_the_small_cells_compile_to_the_program_of_before(cell, chip):
     program = _program(cell, device=chip)
     # "before": the donated jit with nothing said about the carry
     plain = jax.jit(program.tick.__wrapped__, donate_argnums=(0,))
-    assert (
-        _compiled(program, cell, chip).as_text()
-        == _compiled(program, cell, chip, tick=plain).as_text()
-    )
+    text = _compiled(program, cell, chip).as_text()
+    assert text == _compiled(program, cell, chip, tick=plain).as_text()
+    # every leaf under the rule: the select as before, no kernel
+    assert set(jax.tree_util.tree_leaves(program.in_place)) == {False}
+    assert "tpu_custom_call" not in text
 
 
 # ---------------------------------------------------------------------------
@@ -260,15 +330,16 @@ def test_the_small_cells_compile_to_the_program_of_before(cell, chip):
 # ---------------------------------------------------------------------------
 
 
-def _run_particles(min_bytes, monkeypatch, ticks=36):
+def _run_particles(min_bytes, monkeypatch, seed, ticks=36):
     monkeypatch.setattr(session_pool, "_RELAY_MIN_BYTES", min_bytes)
-    sessions, schedules = _make_matches(3, seed=23)
+    sessions, schedules = _make_matches(3, seed=seed)
     game = ParticleWorld(2, 256, 8, 16)
     pool = BatchedRequestExecutor(
         game.advance, game.init_state(), _to_arr,
         batch_size=len(sessions), ring_length=10, max_burst=9,
     )
     relaid = default_registry().value("ggrs_executor_ring_relaid_bytes")
+    direct = default_registry().value("ggrs_executor_ring_inplace_bytes")
     pool.warmup(np.zeros((2,), np.uint8))
     loads = default_registry().value("ggrs_executor_rollback_loads_total")
     _drive(sessions, schedules, pool.run, ticks)
@@ -287,20 +358,28 @@ def _run_particles(min_bytes, monkeypatch, ticks=36):
         for k, leaf in pool._carry["ring"]["states"].items()
     }
     return {
-        "relaid": relaid, "loads": loads, "frames": frames, "live": live,
+        "relaid": relaid, "in place": direct, "loads": loads, "frames": frames, "live": live,
         "one": one, "saved": saved, "ring": ring, "held": held,
     }
 
 
-def test_an_executor_above_and_below_the_rule_gives_equal_values(monkeypatch):
-    below = _run_particles(1 << 25, monkeypatch)
-    above = _run_particles(1, monkeypatch)
+@pytest.mark.parametrize("seed", [23, 57])
+def test_an_executor_above_and_below_the_rule_gives_equal_values(
+        seed, monkeypatch):
+    """Above the rule the wide leaves are held row-major and saved in place
+    (the kernel, under the interpreter); below it the default layout and the
+    select: the same live states, ring states and digests."""
+    below = _run_particles(1 << 25, monkeypatch, seed)
+    above = _run_particles(1, monkeypatch, seed)
     # the rule engaged in one and not in the other
-    assert below["relaid"] == 0
+    assert below["relaid"] == below["in place"] == 0
     wide = 6 * 10 * (3 + 4 + 3 + 2 + 1) * 256 * 4
     assert above["relaid"] == wide
+    assert above["in place"] == wide  # ttl too, held [B, R, 1, N]
+    assert above["ring"]["states"]["ttl"].shape == (6, 10, 1, 256)
+    assert below["ring"]["states"]["ttl"].shape == (6, 10, 256)
     assert above["held"]["rotation"] == ((4, 128),)
-    assert above["held"]["ttl"] == ((8, 128),)
+    assert above["held"]["ttl"] == ((1, 128),)
     assert above["held"]["velocity"] == ((2, 128),)
     assert above["held"]["emitter"] == below["held"]["emitter"]
     # a rollback-heavy run, and the same one
@@ -310,7 +389,8 @@ def test_an_executor_above_and_below_the_rule_gives_equal_values(monkeypatch):
         a, b = (jax.tree_util.tree_leaves(r[key]) for r in (above, below))
         assert len(a) == len(b) > 0
         for x, y in zip(a, b):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y), key)
+            x, y = np.asarray(x), np.asarray(y)
+            np.testing.assert_array_equal(x.reshape(y.shape), y, key)
     assert int(np.asarray(above["live"]["ttl"]).sum()) > 0
 
 
@@ -411,3 +491,128 @@ def test_a_relaid_pool_never_comes_from_the_persistent_cache(cache_probe):
     assert "jit_tick" not in cache_probe["cached"]
     assert "jit_fresh" not in cache_probe["cached"]
     assert "jit__fetch" in cache_probe["cached"]
+
+
+# ---------------------------------------------------------------------------
+# (e) the in-place write is the select, byte for byte
+# ---------------------------------------------------------------------------
+
+_RING = 10  # slots
+_WIDE = 256  # the minor dimension: two tiles of lanes
+
+# frames [B] and predicates [B] of one write; -1 is an idle row's frame
+_WRITES = {
+    "slots that differ per session": ([13, 4, 27, 9, 0], [1, 1, 1, 1, 1]),
+    "pred false in some rows": ([13, 4, 27, 9, 0], [1, 0, 1, 0, 0]),
+    "pred false in every row": ([13, 4, 27, 9, 0], [0, 0, 0, 0, 0]),
+    "frame -1 beside real ones": ([-1, 4, -1, 9, 19], [1, 1, 0, 1, 1]),
+    "one session": ([17], [1]),
+    "one session, idle": ([-1], [1]),
+}
+
+
+def _ring_and_state(rng, sessions, rest):
+    """A ring of one wide leaf ``[B, R, *rest]`` and one narrow one, every
+    word distinct, and a state to save into it."""
+    dring = DeviceStateRing(_RING)
+
+    def words(*shape):
+        return rng.integers(-2**31, 2**31 - 1, shape, dtype=np.int32)
+
+    ring = {
+        "states": {
+            "wide": words(sessions, _RING, *rest),
+            "narrow": words(sessions, _RING, 3),
+        },
+        "checksums": rng.integers(
+            0, 2**32 - 1, (sessions, _RING, 4), dtype=np.uint32),
+        "frames": words(sessions, _RING),
+    }
+    state = {"wide": words(sessions, *rest), "narrow": words(sessions, 3)}
+    digest = rng.integers(0, 2**32 - 1, (sessions, 4), dtype=np.uint32)
+    return dring, ring, state, digest
+
+
+def _assert_trees_equal(got, want):
+    flat_got, tree = jax.tree_util.tree_flatten(jax.device_get(got))
+    flat_want, tree_want = jax.tree_util.tree_flatten(jax.device_get(want))
+    assert tree == tree_want
+    for g, w in zip(flat_got, flat_want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize(
+    "rest", [(4, _WIDE), (3, _WIDE), (2, _WIDE), (1, _WIDE), (_WIDE,)],
+    ids=["K=4", "K=3", "K=2", "unit axis", "rank 3"])
+@pytest.mark.parametrize("case", sorted(_WRITES))
+def test_the_in_place_write_equals_the_select(case, rest):
+    frames, preds = (np.asarray(c) for c in _WRITES[case])
+    rng = np.random.default_rng(len(case) * 7 + len(rest))
+    dring, ring, state, digest = _ring_and_state(rng, len(frames), rest)
+    if rest[0] == 1:
+        # as the pool holds a leaf [B, R, N]: the slot [1, N], the state [N]
+        state["wide"] = state["wide"][:, 0]
+    # a leaf held as rank 3 is not the kernel's to take: save_where_batch is
+    # then the select, and still the same bytes
+    direct = writes_slot_in_place(ring["states"]["wide"].shape, 4)
+    assert direct == (len(rest) == 2)
+    args = (frames.astype(np.int32), state, digest, preds.astype(bool))
+    want = jax.jit(jax.vmap(dring.save_where))(ring, *args)
+    got = jax.jit(
+        lambda ring, *a: dring.save_where_batch(
+            ring, *a, in_place={"wide": direct, "narrow": False},
+            interpret=True)
+    )(ring, *args)
+    _assert_trees_equal(got, want)
+    # and it wrote exactly the slots it should have, and nothing else
+    before = ring["states"]["wide"]
+    after = np.asarray(got["states"]["wide"])
+    for b, (f, p) in enumerate(zip(frames.tolist(), preds.tolist())):
+        for r in range(_RING):
+            hit = bool(p) and f >= 0 and f % _RING == r
+            np.testing.assert_array_equal(
+                after[b, r],
+                state["wide"][b].reshape(rest) if hit else before[b, r])
+
+
+@pytest.mark.parametrize("rest", [(4, _WIDE), (3, _WIDE), (2, _WIDE)],
+                         ids=["K=4", "K=3", "K=2"])
+def test_one_slot_written_twice_in_a_tick_holds_the_second_state(rest):
+    """The frame-0 tick saves frame 0 before the burst and again in it."""
+    rng = np.random.default_rng(41 + rest[0])
+    dring, ring, first, digest = _ring_and_state(rng, 4, rest)
+    second = jax.tree_util.tree_map(lambda l: l + 1, first)
+    frames = np.asarray([0, 20, 7, -1], np.int32)
+    twice = np.asarray([True, True, False, True])
+    once = np.ones((4,), bool)
+
+    def two_writes(write):
+        def run(ring):
+            ring = write(ring, frames, first, digest, once)
+            return write(ring, frames, second, digest + 1, twice)
+        return jax.jit(run)(ring)
+
+    got = two_writes(lambda *a: dring.save_where_batch(
+        *a, in_place={"wide": True, "narrow": False}, interpret=True))
+    _assert_trees_equal(got, two_writes(jax.vmap(dring.save_where)))
+    wide = np.asarray(got["states"]["wide"])
+    np.testing.assert_array_equal(wide[0, 0], second["wide"][0])
+    np.testing.assert_array_equal(wide[1, 0], second["wide"][1])
+    np.testing.assert_array_equal(wide[2, 7], first["wide"][2])
+    np.testing.assert_array_equal(wide[3], ring["states"]["wide"][3])
+
+
+@pytest.mark.parametrize(
+    "shape, takes",
+    [
+        ((512, 10, 4, 10000), True),
+        ((128, 10, 2, 10000), True),
+        ((512, 10, 1, 10000), True),
+        ((512, 10, 10000), False),  # a slot is one row of a tile of slots
+        ((512, 10), False),
+        ((4, 10, 64, 10000), False),  # 2.56 MB a slot: six would not fit
+    ],
+)
+def test_the_kernel_says_which_leaves_it_takes(shape, takes):
+    assert writes_slot_in_place(shape, 4) is takes
