@@ -13,9 +13,11 @@ Legs (each raises on failure; nothing is caught and survived):
   pool      B = 256 ex_game BoxGame matches = 512 sessions in one
             ``HostSessionPool`` -> ``RequestPlan`` ->
             ``BatchedRequestExecutor`` (``HostedPool.tick``), 600 ticks over
-            an in-memory network with latency, so rollbacks really happen.
+            an in-memory network with latency, so rollbacks really happen,
+            desync detection on at interval 10 inside the native bank.
             Every match's two peers must hold bit-identical device state,
-            equal to ``BoxGame.advance_np`` replayed on the host.
+            equal to ``BoxGame.advance_np`` replayed on the host; every
+            session's reports are compared by its peer and none differs.
   fence     is ``block_until_ready`` a real completion fence here, before
             and after the process's first device->host read?
   synctest  BASELINE config 2: ``DeviceSyncTestSession`` cd=8 (the donating
@@ -61,7 +63,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ggrs_tpu.core import Local, Remote
+from ggrs_tpu.core import DesyncDetected, DesyncDetection, Local, Remote
 from ggrs_tpu.games import BoxGame, ChipVM, EcsWorld, RtsCmd, boxgame_config
 from ggrs_tpu.net import InMemoryNetwork
 from ggrs_tpu.net.sockets import UdpNonBlockingSocket
@@ -96,6 +98,7 @@ LEGS = ("pool", "fence", "synctest", "games", "pallas", "udp")
 # because nothing such a run prints is the chip result
 NOT_A_CHIP_RESULT = 10
 MAX_PREDICTION = 8  # the builder default the pool and udp legs run under
+DETECTION_INTERVAL = 10  # the pool leg's desync detection, in frames
 
 # the deployment's size, and the tiny one a CPU rehearsal walks through
 REAL = dict(
@@ -211,6 +214,16 @@ def _reference_state(game: BoxGame, seed: int, m: int, frames: int,
     return state
 
 
+def _exchange_counts() -> Tuple[float, float, float]:
+    """(ChecksumReports sent, reports compared, compares that differed) by
+    the sessions of native banks, from the pools' process-wide counters."""
+    reg = default_registry()
+    return tuple(
+        reg.value(f"ggrs_pool_{name}_total") or 0.0
+        for name in ("checksum_reports_sent", "checksum_compares", "desyncs")
+    )
+
+
 def _burst_counts() -> Tuple[float, int, int]:
     """(rollback loads, dispatched ticks, ticks whose deepest burst was 1)
     from the executor's own process-wide counters."""
@@ -243,7 +256,10 @@ def leg_pool(size: Dict[str, int], seed: int, chips: int,
         names = (f"A{m}", f"B{m}")
         for me in (0, 1):
             host.add_session(
-                _builder(clock, seed, m, me, names[1 - me]),
+                # as upstream's examples run it: desync detection on
+                _builder(clock, seed, m, me, names[1 - me])
+                .with_desync_detection_mode(
+                    DesyncDetection.on(DETECTION_INTERVAL)),
                 net.socket(names[me]),
             )
     mesh = make_mesh(chips) if chips > 1 else None
@@ -256,6 +272,7 @@ def leg_pool(size: Dict[str, int], seed: int, chips: int,
     # true simulation (examples/ex_game_server.py does the same)
     hold_from = ticks - 3 * MAX_PREDICTION
     loads0, total0, depth1_0 = _burst_counts()
+    exchange0 = _exchange_counts()
     compiles0 = meter.compiles
     t0 = time.perf_counter()
     for i in range(ticks):
@@ -283,6 +300,27 @@ def leg_pool(size: Dict[str, int], seed: int, chips: int,
         if host.slot_state(i) != "native"
     ]
     check(not off_bank, f"pool: slots left the bank: {off_bank[:8]}")
+    # the exchange closed: every session's digests reached its peer (the
+    # device's, fetched in one batched read a tick that wants any) and
+    # were compared there, inside the crossing; none differed
+    sent, compared, desyncs = (
+        after - before
+        for before, after in zip(exchange0, _exchange_counts())
+    )
+    silent = [
+        i for i in range(sessions)
+        if not len(host.flight_recorder(i).checksums)
+    ]
+    check(not silent, f"pool: sessions that reported no digest: {silent[:8]}")
+    check(compared >= sessions,
+          f"pool: {compared} reports compared over {sessions} sessions")
+    check(sent >= compared, f"pool: {compared} compared of {sent} sent")
+    raised = [
+        (i, e) for i in range(sessions) for e in host.events(i)
+        if isinstance(e, DesyncDetected)
+    ]
+    check(desyncs == 0 and not raised,
+          f"pool: {desyncs} desyncs detected: {raised[:4]}")
     rollback_loads = int(loads1 - loads0)
     deep_ticks = (total1 - total0) - (depth1_1 - depth1_0)
     check(rollback_loads > 0, "pool: no rollback load reached the executor")
@@ -327,6 +365,10 @@ def leg_pool(size: Dict[str, int], seed: int, chips: int,
         "fast_slot_ticks": host.fast_slot_ticks,
         "rollback_loads": rollback_loads, "ticks_with_burst_gt1": deep_ticks,
         "compiles_in_ticks": compiled_in_ticks,
+        "checksum_reports_sent": int(sent),
+        "checksum_reports_compared": int(compared),
+        "desyncs": int(desyncs),
+        "checksum_lag_ticks_max": ex.checksum_lag_ticks_max,
         "carry_devices": shard_devices,
         # what a device holds of the carry, and how much of it the executor
         # keeps row-major between ticks (DESIGN.md section 3): 0 here, the

@@ -17,6 +17,8 @@ import struct
 from dataclasses import dataclass, field
 from typing import Any, Callable, Generic, Optional, TypeVar
 
+from .types import DesyncDetection
+
 I = TypeVar("I")
 
 
@@ -89,6 +91,10 @@ class Config:
                      as SURVEY.md records it).
     input_eq       — equality used for misprediction detection; defaults to ==.
     predictor      — InputPredictor strategy, default repeat-last.
+    desync_detection — the title's desync detection: off, or on at the
+                     interval (in frames) its peers report checksums at.  The
+                     value a ``SessionBuilder`` starts from;
+                     ``with_desync_detection_mode`` overrides it.
     """
 
     input_default: Callable[[], Any]
@@ -102,6 +108,7 @@ class Config:
     # prediction and equality over encoded bytes are exactly the Python
     # semantics over values.  None = unknown shape, Python queues only.
     native_input_size: Optional[int] = None
+    desync_detection: DesyncDetection = field(default_factory=DesyncDetection.off)
 
     def __post_init__(self) -> None:
         # A bare PredictDefault() needs the config's own notion of "default

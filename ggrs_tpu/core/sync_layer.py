@@ -107,6 +107,12 @@ class GameStateCell(Generic[S]):
                 self._state.checksum = cs
             return cs
 
+    def peek(self):
+        """``(frame, checksum as stored)``: the checksum may be an int, None
+        or a lazy handle, and is NOT materialized (no device→host read)."""
+        with self._lock:
+            return self._state.frame, self._state.checksum
+
     def __repr__(self) -> str:  # pragma: no cover
         # format the RAW stored checksum: going through the property would
         # materialize a lazy DeviceChecksum (a device→host read) from a mere

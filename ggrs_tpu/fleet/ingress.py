@@ -488,8 +488,12 @@ class VirtualEndpointSocket:
     def io_syscalls(self) -> int:
         return self._sock.io_syscalls
 
-    def fileno(self) -> int:
-        return self._sock.fileno()
+    # no fileno(): a pool takes a socket that offers its fd onto the
+    # native send table and the batched inbound drain, which move RAW
+    # datagrams; this leg's are wrapped in Python.  (Until PR 35 every
+    # placed match ran desync detection and so sat on the Python tier,
+    # where nothing asked; on the bank the fd sent unwrapped payloads
+    # straight to the peer, from an address it does not know.)
 
     def local_port(self) -> int:
         return self._sock.local_port()

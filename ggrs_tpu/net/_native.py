@@ -513,6 +513,12 @@ def _load() -> Optional[ctypes.CDLL]:
                 lib.ggrs_bank_set_confirmed_stream.argtypes = [
                     ctypes.c_void_p, ctypes.c_int64, ctypes.c_int,
                 ]
+            # desync detection inside the crossing (DESIGN.md §4): the
+            # interval a session reports its saved frames' digests at
+            lib.ggrs_bank_set_desync_detection.restype = ctypes.c_int
+            lib.ggrs_bank_set_desync_detection.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+            ]
             if hasattr(lib, "ggrs_bank_set_timing"):
                 # in-crossing phase timers (tracing, DESIGN.md §14);
                 # absent on a prebuilt pre-trace .so — the pool then runs
@@ -828,8 +834,10 @@ BANK_HDR_FIELDS = (
 # this order, with the count byte last)
 BANK_PHASES = (
     "inbound", "timers", "commit", "rollback", "outbound", "fanout",
-    "emit", "other", "staging",
+    "emit", "other", "staging", "checksum",
 )
+# "checksum" (desync detection: reports out, wanted rows, compares) is an
+# in-crossing phase like the first seven, appended so no older index moves.
 # "staging" is special: it accumulates OUTSIDE the tick window (the
 # ggrs_bank_stage_inputs crossings since the last tick) and rides the next
 # tick's tail — it is never part of the in-crossing sum that "other"

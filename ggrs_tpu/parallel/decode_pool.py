@@ -56,6 +56,7 @@ from ..utils.ownership import ThreadOwned
 # session_bank.cpp EvKind (== host_bank._EV_*) and core.types.NULL_FRAME.
 _EV_INTERRUPTED = 1
 _EV_CHECKSUM = 4
+_EV_DESYNC = 6
 _NULL_FRAME = -1
 
 # A decoded slot is a plain tuple (index comments below); ops entries are
@@ -140,6 +141,9 @@ def decode_slot_record(buf, pos: int, players: int, isize: int,
             frame, lo, hi = unpack_from("<qQQ", buf, pos)
             pos += 24
             events.append((kind, ep_idx, (frame, lo, hi)))
+        elif kind == _EV_DESYNC:
+            events.append((kind, ep_idx, unpack_from("<qQQQQ", buf, pos)))
+            pos += 40
         else:
             events.append((kind, ep_idx, None))
     (n_eps,) = unpack_from("<B", buf, pos)

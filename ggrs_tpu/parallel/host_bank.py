@@ -19,7 +19,7 @@ per-session observables (``current_frame``, ``last_confirmed_frame``,
 
 FALLBACK: when the native library is unavailable (``GGRS_TPU_NO_NATIVE``,
 no toolchain) or any session's shape is outside the bank's mechanism
-(sparse saving, lockstep, spectators, desync detection, handshake,
+(sparse saving, lockstep, spectators without a hub, handshake,
 variable-size inputs), the pool drives ordinary per-session ``P2PSession``
 objects — the untouched semantic reference — and ``native_active`` /
 ``native_reason`` say so and why (a measurement asserts the tier; it does
@@ -120,6 +120,7 @@ from ..core.errors import (
 from ..core.sync_layer import SavedStates
 from ..core.types import (
     AdvanceFrame,
+    DesyncDetected,
     Disconnected,
     Frame,
     GgrsRequest,
@@ -175,6 +176,7 @@ _EV_INTERRUPTED = 1
 _EV_RESUMED = 2
 _EV_DISCONNECTED = 3
 _EV_CHECKSUM = 4
+_EV_DESYNC = 6  # (frame, local lo, hi, remote lo, hi): the bank's compare
 
 # ---- vectorized policy plane (DESIGN.md §19) -----------------------------
 # Packed per-tick output header: one fixed-stride record per slot leads the
@@ -194,6 +196,13 @@ _REQ_DTYPE = np.dtype(list(_native.BANK_REQ_FIELDS))
 _STAGE_DTYPE = np.dtype(list(_native.BANK_STAGE_FIELDS))
 _SEND_DTYPE = np.dtype(list(_native.NET_SEND_FIELDS))
 _RECV_DTYPE = np.dtype(list(_native.NET_RECV_FIELDS))
+# desync detection (DESIGN.md §4): the wanted tail that follows the last
+# body record where a session of the bank detects (session_bank.cpp): four
+# u32 counts, then one row a digest the pool is to fetch from the device
+_WANTED_HEAD = struct.Struct("<IIII")  # rows, sent, compared, desyncs
+_WANTED_DTYPE = np.dtype([("slot", "<u4"), ("pad", "<u4"), ("frame", "<i8")])
+_CTRL_DIGEST = struct.Struct("<BHqQQ")  # ctrl op 4: frame, then lo, hi
+_U64 = (1 << 64) - 1
 # per-session command flag bytes (session_bank.cpp kFlag*, mirrored as
 # _native.CMD_FLAG_*; ggrs-verify pins the pairs equal)
 _CMD_INPUTS = bytes([_native.CMD_FLAG_INPUTS])
@@ -223,6 +232,7 @@ _LZ_INTERRUPTED = "i"
 _LZ_RESUMED = "r"
 _LZ_DISCONNECTED = "d"
 _LZ_WAIT = "w"
+_LZ_DESYNC = "x"
 
 
 def _materialize_events(queue) -> List[Any]:
@@ -240,6 +250,9 @@ def _materialize_events(queue) -> List[Any]:
             out.append(NetworkResumed(addr=ev[1]))
         elif ev[0] == _LZ_DISCONNECTED:
             out.append(Disconnected(addr=ev[1]))
+        elif ev[0] == _LZ_DESYNC:
+            out.append(DesyncDetected(frame=ev[1], local_checksum=ev[2],
+                                      remote_checksum=ev[3], addr=ev[4]))
         else:  # _LZ_WAIT
             out.append(WaitRecommendation(skip_frames=ev[1]))
     return out
@@ -381,8 +394,8 @@ def _bank_eligible(builder, hub_active: bool = False) -> bool:
         return False
     if builder._sparse_saving or builder._max_prediction < 1:
         return False  # sparse saving / lockstep: fallback policy paths
-    if builder._desync_detection.enabled or builder._sync_handshake:
-        return False
+    if builder._sync_handshake:
+        return False  # (desync detection runs inside the crossing: §4)
     if builder._local_players < 1 or builder._num_players > 64:
         return False
     if not hub_active and any(
@@ -397,6 +410,21 @@ def _bank_eligible(builder, hub_active: bool = False) -> bool:
     if _WORST_CASE_FRAMES > _RECV_CAP_FRAMES:
         return False
     return True
+
+
+class _TickRequests(list):
+    """The reference decoder's request lists (``GGRS_TPU_NO_FASTPATH``)
+    of a pool that detects desyncs: a plain list that also carries what
+    ``BatchedRequestExecutor.run`` reads off a :class:`RequestPlan` for the
+    digest exchange."""
+
+    __slots__ = ("pool", "tick_no", "checksum_wanted")
+
+    def __init__(self, lists, pool, wanted):
+        super().__init__(lists)
+        self.pool = pool
+        self.tick_no = pool._tick_no
+        self.checksum_wanted = wanted
 
 
 class RequestPlan:
@@ -425,6 +453,8 @@ class RequestPlan:
     ``save_only_rows``  ``(slot, frame)`` per prediction-limit slot;
     ``eager_rows``  slots whose lists were materialized at build time
         (slow/other/skip slots) — consume via ``plan[i]``;
+    ``checksum_wanted``  ``(slots, frames)`` of the saved frames whose
+        device digests the bank asked for (desync detection), else None;
     ``gather_quiet()``  the quiet rows' advance payloads as
         ``(statuses [k, players] u8, blobs [k, players, isize] u8)``,
         one fancy-index gather, uniform pools only.
@@ -434,12 +464,16 @@ class RequestPlan:
         "pool", "tick_no", "lists", "buffer", "players", "input_size",
         "uniform", "quiet_rows", "quiet_frames", "quiet_offs",
         "quiet_adv_off", "resim_rows", "save_only_rows", "eager_rows",
-        "offs_l", "live_l",
+        "offs_l", "live_l", "checksum_wanted",
     )
 
     def __init__(self, pool, n: int):
         self.pool = pool
         self.tick_no = pool._tick_no
+        # desync detection: (slots, frames) whose device digests the bank
+        # asked for this tick, or None (BatchedRequestExecutor.run fetches
+        # them and hands them to pool.deliver_checksums)
+        self.checksum_wanted: Optional[Tuple[np.ndarray, np.ndarray]] = None
         self.lists: List[Optional[List[GgrsRequest]]] = [None] * n
         self.buffer: Optional[np.ndarray] = None
         self.players = 0
@@ -861,6 +895,18 @@ class HostSessionPool:
         self._m_rollbacks = m.counter(
             "ggrs_pool_rollbacks_total",
             "rollback decisions executed by pooled slots")
+        # desync detection inside the bank: counted in the crossing, read
+        # from the wanted tail (one report a remote endpoint a frame)
+        self._m_cs_sent = m.counter(
+            "ggrs_pool_checksum_reports_sent_total",
+            "ChecksumReports bank slots sent to their remote endpoints")
+        self._m_cs_compares = m.counter(
+            "ggrs_pool_checksum_compares_total",
+            "received ChecksumReports bank slots compared with the local "
+            "digest of their frame")
+        self._m_cs_desyncs = m.counter(
+            "ggrs_pool_desyncs_total",
+            "compares that differed (one DesyncDetected event each)")
         # ---- broadcast (DESIGN.md §13): fan-out + journal observability ----
         self._m_fanout_dgrams = m.counter(
             "ggrs_fanout_datagrams_total",
@@ -996,6 +1042,14 @@ class HostSessionPool:
         self._evict_next_try: Dict[int, int] = {}
         self._inject_dgrams: Dict[int, List[Tuple[int, bytes]]] = {}
         self._inject_err: Dict[int, int] = {}
+        # ---- desync detection inside the bank (DESIGN.md §4) ----
+        # _detects: a session of the bank reports and compares digests, so
+        # the tick output carries the wanted tail (the rows ride the plan:
+        # RequestPlan.checksum_wanted); _digests: slot -> [(frame, lo, hi)]
+        # obtained and not yet handed to the bank (the next crossing's
+        # ctrl op 4)
+        self._detects = False
+        self._digests: Dict[int, List[Tuple[int, int, int]]] = {}
         # ---- broadcast subsystem seams (ggrs_tpu/broadcast) ----
         # _spectator_hub: the SpectatorHub that owns relay policy for this
         # pool (set by SpectatorHub.__init__, must precede finalization);
@@ -1197,6 +1251,16 @@ class HostSessionPool:
             )
             if idx < 0:
                 raise RuntimeError(f"ggrs_bank_add_session failed: {idx}")
+            detection = builder._desync_detection
+            if detection.enabled:
+                rc = lib.ggrs_bank_set_desync_detection(
+                    self._bank, idx, detection.interval
+                )
+                if rc != 0:
+                    raise RuntimeError(
+                        f"ggrs_bank_set_desync_detection failed: {rc}"
+                    )
+                self._detects = True
             mirror = _SessionMirror(
                 cfg, socket, builder._num_players, builder._max_prediction,
                 local_handles,
@@ -2093,6 +2157,8 @@ class HostSessionPool:
             with tracer.span("pool.decode") as span:
                 fast0 = self.fast_slot_ticks
                 request_lists, retire_mask = self._parse_output_plan(ticked)
+                if self._detects:
+                    request_lists.checksum_wanted = self._read_wanted_tail()
                 if tracing:
                     span.set(
                         fast=self.fast_slot_ticks - fast0,
@@ -2104,12 +2170,131 @@ class HostSessionPool:
             self._plan = request_lists
         else:
             request_lists = self._parse_output(ticked)
+            if self._detects:
+                request_lists = _TickRequests(
+                    request_lists, self, self._read_wanted_tail()
+                )
             retire_mask = None
             self._plan = None
         with tracer.span("pool.supervise"):
             self._supervise(request_lists, retire_mask)
         self.last_tick_at = time.monotonic()
         return request_lists
+
+    def _read_wanted_tail(self):
+        """Desync detection (DESIGN.md §4): the tick output's wanted tail,
+        which follows the last body record where a session of the bank
+        detects.  Folds the crossing's counts into the registry and returns
+        ``(slots, frames)``, the saved frames whose device digests the bank
+        asks for, or None.  The executor fetches them
+        (``BatchedRequestExecutor.run``) and hands them back through
+        :meth:`deliver_checksums`."""
+        n = len(self._mirrors)
+        hdr = np.frombuffer(self._out_buf, dtype=_HDR_DTYPE, count=n)
+        pos = n * (self._hdr_stride + self._req_stride) + int(
+            hdr["rec_len"].sum(dtype=np.int64)
+        )
+        rows, sent, compared, desyncs = _WANTED_HEAD.unpack_from(
+            self._out_buf, pos
+        )
+        if sent:
+            self._m_cs_sent.inc(sent)
+        if compared:
+            self._m_cs_compares.inc(compared)
+        if desyncs:
+            self._m_cs_desyncs.inc(desyncs)
+        if not rows:
+            return None
+        table = np.frombuffer(
+            self._out_buf, _WANTED_DTYPE, count=rows,
+            offset=pos + _WANTED_HEAD.size,
+        )
+        slots = table["slot"].astype(np.int64)
+        frames = table["frame"].copy()
+        # whoever fulfills the save requests decides where a digest lives.
+        # A cell saved with the checksum itself (a host-side game) answers
+        # here and now, as it answers the Python session; one saved with
+        # none can never report; a lazy handle (BatchedRequestExecutor: the
+        # digest is on the device) is left for the executor's batched fetch
+        mirrors = self._mirrors
+        lazy: List[int] = []
+        for k, (slot, frame) in enumerate(
+            zip(slots.tolist(), frames.tolist())
+        ):
+            held, checksum = mirrors[slot].saved_states.get_cell(frame).peek()
+            if held != frame or hasattr(checksum, "materialize"):
+                lazy.append(k)
+            elif checksum is not None:
+                self._queue_digest(slot, frame, checksum & _U64,
+                                   checksum >> 64)
+        if not lazy:
+            return None
+        if len(lazy) < rows:
+            slots, frames = slots[lazy], frames[lazy]
+        return slots, frames
+
+    def deliver_checksums(self, slots, frames, lanes) -> None:
+        """Hand the bank the device's digests of the wanted rows
+        ``(slots[i], frames[i])``: ``lanes[i]`` is the four u32 lanes of
+        ``ops.checksum.checksum_device``.  They ride the NEXT crossing (ctrl
+        op 4), which sends each as a ``ChecksumReport`` to the slot's remote
+        endpoints and keeps it for the compare; a slot that has left the
+        bank meanwhile drops its rows."""
+        lanes = np.asarray(lanes, np.uint64)
+        lo = (lanes[:, 0] | (lanes[:, 1] << np.uint64(32))).tolist()
+        hi = (lanes[:, 2] | (lanes[:, 3] << np.uint64(32))).tolist()
+        for slot, frame, l, h in zip(
+            np.asarray(slots).tolist(), np.asarray(frames).tolist(), lo, hi
+        ):
+            self._queue_digest(slot, frame, l, h)
+
+    def _queue_digest(self, slot: int, frame: Frame, lo: int,
+                      hi: int) -> None:
+        if self._slot_state[slot] != SLOT_NATIVE:
+            return
+        self._digests.setdefault(slot, []).append((frame, lo, hi))
+        rec = self._recorders[slot] if self._recorders else None
+        if rec is not None:
+            rec.record_checksum(frame, lo | (hi << 64))
+
+    def _on_desync(self, index: int, m: "_SessionMirror",
+                   ep: "_EndpointMirror", payload) -> None:
+        """The bank's compare found a peer's report differing from the
+        local digest of its frame: the ``DesyncDetected`` event and the
+        ``DesyncReport`` the Python session yields
+        (``P2PSession._compare_local_checksums_against_peers``)."""
+        frame, llo, lhi, rlo, rhi = payload
+        local, remote = llo | (lhi << 64), rlo | (rhi << 64)
+        m.push_event((_LZ_DESYNC, frame, local, remote, ep.addr))
+        rec = self._recorders[index] if self._recorders else None
+        if rec is not None:
+            rec.record_checksum(frame, remote, ep.addr)
+            rec.record(
+                self._tick_no, EV_DESYNC,
+                f"frame {frame}: local {local:#x} != remote {remote:#x}",
+            )
+        self.tracer.add_instant("pool.desync", cat="py", slot=index,
+                                frame=frame)
+        if index in self._desync_reports:
+            return  # a real desync re-fires every interval: the first says all
+        self._desync_reports[index] = build_desync_report(
+            detected_frame=frame,
+            addr=ep.addr,
+            local_checksum=local,
+            remote_checksum=remote,
+            local_history=(
+                rec.checksums if rec is not None else {frame: local}
+            ),
+            remote_history=(
+                rec.remote_checksums[ep.addr] if rec is not None
+                else {frame: remote}
+            ),
+            recorder=rec,
+            journal=self._journal_sinks.get(index),
+            tracer=self.tracer,
+            detail=f"slot {index}: checksum compare inside the bank crossing "
+                   f"at pool tick {self._tick_no}",
+        )
 
     def _trace_phases(self, t_cross: int, dur: int) -> None:
         """The native per-phase timings as children of the crossing span:
@@ -2206,6 +2391,11 @@ class HostSessionPool:
         # must use the build-time view even if new faults land mid-parse
         ticked = [s == SLOT_NATIVE for s in self._slot_state]
         cmd_parts: List[bytes] = []
+        if self._digests:
+            # a slot that left the bank since its digest was asked for
+            # reports from its Python session now
+            for i in [i for i in self._digests if not ticked[i]]:
+                del self._digests[i]
         for i, m in enumerate(self._mirrors):
             if not ticked[i]:
                 cmd_parts.append(_CMD_SKIP)  # no fields follow
@@ -2224,9 +2414,12 @@ class HostSessionPool:
             inj = self._inject_err.pop(i, None)
             if inj is not None:
                 ctrl = ctrl + [(2, 0, inj)]  # op 2: simulated slot fault
-            cmd_parts.append(pack("<H", len(ctrl)))
+            digests = self._digests.pop(i, ()) if self._digests else ()
+            cmd_parts.append(pack("<H", len(ctrl) + len(digests)))
             for op, ep_idx, frame in ctrl:
                 cmd_parts.append(pack("<BHq", op, ep_idx, frame))
+            for frame, lo, hi in digests:
+                cmd_parts.append(_CTRL_DIGEST.pack(4, 0, frame, lo, hi))
             datagrams = []
             spec_datagrams = []
             if drained is not None and i in drained:
@@ -2950,6 +3143,11 @@ class HostSessionPool:
                 frame, lo, hi = unpack_from("<qQQ", buf, pos)
                 pos += 24
                 staged_events.append((kind, ep_idx, (frame, lo, hi)))
+            elif kind == _EV_DESYNC:
+                staged_events.append(
+                    (kind, ep_idx, unpack_from("<qQQQQ", buf, pos))
+                )
+                pos += 40
             else:
                 staged_events.append((kind, ep_idx, None))
         (n_eps,) = unpack_from("<B", buf, pos)
@@ -3155,6 +3353,8 @@ class HostSessionPool:
                 elif kind == _EV_CHECKSUM:
                     frame, lo, hi = payload
                     self._store_checksum(ep, frame, lo | (hi << 64))
+                elif kind == _EV_DESYNC:
+                    self._on_desync(idx, m, ep, payload)
             pre_current = current - (1 if advanced else 0)
             m.frames_ahead = frames_ahead
             if (
@@ -3371,6 +3571,8 @@ class HostSessionPool:
                 elif kind == _EV_CHECKSUM:
                     frame, lo, hi = payload
                     self._store_checksum(ep, frame, lo | (hi << 64))
+                elif kind == _EV_DESYNC:
+                    self._on_desync(idx, m, ep, payload)
             pre_current = current - (1 if advanced else 0)
             m.frames_ahead = frames_ahead
             if (
@@ -3692,11 +3894,11 @@ class HostSessionPool:
 
     def _build_native_desync_report(self, index: int, code: int,
                                     named: str) -> None:
-        """DesyncReport for a desync-class native fault: no local checksum
-        history exists on the bank path (desync detection is a fallback
-        feature), so the report carries the evidence that IS available —
-        the peers' reported checksums, the flight recorder, the journal
-        tail, and the active trace window."""
+        """DesyncReport for a desync-class native fault (not the checksum
+        compare: :meth:`_on_desync`): the report carries the evidence the
+        pool holds — the peers' reported checksums where the slot does not
+        detect in the bank, the flight recorder, the journal tail, and the
+        active trace window."""
         m = self._mirrors[index]
         rec = self._recorders[index] if self._recorders else None
         # per-peer attribution: same-frame reports from different peers
@@ -3730,9 +3932,11 @@ class HostSessionPool:
                                 frame=m.current_frame, code=code)
 
     def desync_report(self, index: int) -> Optional[DesyncReport]:
-        """The forensic report built when slot ``index`` quarantined on a
-        desync-class fault, or None.  (The checksum-compare detection path
-        lives on Python sessions — see ``P2PSession.desync_reports``.)"""
+        """The forensic report of slot ``index``, or None: built when the
+        bank's checksum compare first found a peer's report differing
+        (``kind`` "checksum-compare", as ``P2PSession.desync_reports``
+        holds them), or when the slot quarantined on a desync-class fault
+        (``kind`` "native-fault")."""
         return self._desync_reports.get(index)
 
     def _try_evict(self, index: int) -> bool:
@@ -5235,8 +5439,9 @@ class HostSessionPool:
 
     def _store_checksum(self, ep: _EndpointMirror, frame: Frame,
                         checksum: int) -> None:
-        """``PeerProtocol._on_checksum_report`` with interval 1 (desync
-        detection is off for bank-eligible sessions)."""
+        """``PeerProtocol._on_checksum_report`` with interval 1, for a slot
+        whose own detection is off (one that detects keeps and compares
+        its peers' reports inside the crossing: no event comes up)."""
         if len(ep.pending_checksums) >= MAX_CHECKSUM_HISTORY_SIZE:
             oldest = frame - (MAX_CHECKSUM_HISTORY_SIZE - 1)
             ep.pending_checksums = {

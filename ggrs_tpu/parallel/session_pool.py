@@ -32,8 +32,11 @@ Grammar parity: the same ``Save | Load (Adv Save?)* | Adv`` request shapes
 Saved states live in per-session device rings ``[B, R, ...]`` tagged with
 frame numbers and (optionally) 4-lane digests; ``GameStateCell``s are
 fulfilled with lazy slot references and lazy checksums, so desync detection
-and user ``cell.load()`` work unchanged while the live path performs ZERO
-device→host reads.  The carry is donated to every tick; a large ring leaf
+and user ``cell.load()`` work unchanged while the live path never WAITS for
+a device→host read: a pool that detects desyncs inside the host bank has
+its digests fetched by one batched read a tick that wants any, enqueued
+behind the dispatch and handed over when it has landed
+(``_exchange_digests``; docs/DESIGN.md §4).  The carry is donated to every tick; a large ring leaf
 is made, passed and returned in the layout the tick program computes in
 (``ring_leaf_layout`` below, chosen per leaf from its shape where
 ``tick_program`` builds the carry), so no whole-ring transposition stands
@@ -46,6 +49,7 @@ from __future__ import annotations
 
 import contextlib
 import math
+from collections import deque
 from typing import (
     Any,
     Callable,
@@ -113,6 +117,11 @@ _OBS_MESH_DEVICES = default_registry().gauge(
     "ggrs_executor_mesh_devices",
     "devices the newest pooled executor's session axis is sharded over "
     "(1: no mesh)",
+)
+_OBS_DIGESTS_MISSED = default_registry().counter(
+    "ggrs_executor_checksum_frames_missed_total",
+    "saved frames whose digest the host bank wanted for a ChecksumReport "
+    "after their ring slot had been written again (never reported)",
 )
 _OBS_BURST_DEPTH = default_registry().histogram(
     "ggrs_executor_burst_depth_frames",
@@ -200,6 +209,41 @@ def _compiled_in_process(formats: Any):
     finally:
         jax.config.update("jax_enable_compilation_cache", enabled)
         compilation_cache.reset_cache()
+
+
+def digest_fetch_program(
+    batch_size: int, mesh: Optional["jax.sharding.Mesh"] = None
+) -> Callable[[jax.Array, Any], jax.Array]:
+    """The one batched read of saved frames' digests (docs/DESIGN.md §4):
+    ``(ring checksums [B, R, 4], ring slots [B]) -> [B, 4]`` u32, session
+    ``b``'s digest of the frame in its slot ``slots[b]``.  One fixed shape
+    whatever the rows wanted, so one compile (in ``warmup``) and one
+    transfer each way a tick that wants any digest; over a mesh every
+    device reads its own sessions' rows, no collective."""
+
+    def fetch(checksums: jax.Array, slots: jax.Array) -> jax.Array:
+        with jax.named_scope("digest.fetch"):
+            return jax.vmap(
+                lambda row, s: jax.lax.dynamic_index_in_dim(
+                    row, s, 0, keepdims=False
+                )
+            )(checksums, slots)
+
+    if mesh is None:
+        return jax.jit(fetch)
+    from jax import shard_map
+    from jax.sharding import PartitionSpec
+
+    spec_b = PartitionSpec(tuple(mesh.axis_names))
+    return jax.jit(
+        shard_map(
+            fetch,
+            mesh=mesh,
+            in_specs=(spec_b, spec_b),
+            out_specs=spec_b,
+            check_vma=False,
+        )
+    )
 
 
 class TickProgram(NamedTuple):
@@ -659,6 +703,15 @@ class BatchedRequestExecutor:
             )
 
         self._fetch_slot = jax.jit(_fetch)
+        # desync detection inside the host bank (DESIGN.md §4): the batched
+        # digest read, and the reads in flight, oldest first: (pool, tick,
+        # slots, frames, the [B, 4] result on its way to the host)
+        self._fetch_digests = digest_fetch_program(batch_size, mesh)
+        self._digest_fetches: deque = deque()
+        # ticks from a digest being wanted to its landing on the host
+        # (the newest, and the most); the bank sends it one crossing later
+        self.checksum_lag_ticks = 0
+        self.checksum_lag_ticks_max = 0
 
     # ------------------------------------------------------------------
     # request-list parsing (host, NumPy only — zero dispatches)
@@ -805,13 +858,21 @@ class BatchedRequestExecutor:
         # a no-op tick leaves the carry semantically unchanged; keep the
         # result, because the dispatch donated (invalidated) its input
         self._carry = out
-        # the desync exchange's slot probe must be compiled up front too
+        # the desync exchange's reads must be compiled up front too: the
+        # slot probe (Python sessions, diagnostics) and the batched fetch
+        # (sessions that detect inside the host bank)
         jax.block_until_ready(
-            self._fetch_slot(
-                self._carry["ring"]["frames"],
-                self._carry["ring"]["checksums"],
-                np.int32(0),
-                np.int32(0),
+            (
+                self._fetch_slot(
+                    self._carry["ring"]["frames"],
+                    self._carry["ring"]["checksums"],
+                    np.int32(0),
+                    np.int32(0),
+                ),
+                self._fetch_digests(
+                    self._carry["ring"]["checksums"],
+                    np.zeros((self.batch_size,), np.int32),
+                ),
             )
         )
 
@@ -1013,6 +1074,64 @@ class BatchedRequestExecutor:
                 self._run_plan(request_lists)
             else:
                 self._run_lists(request_lists)
+        wanted = getattr(request_lists, "checksum_wanted", None)
+        if wanted is not None or self._digest_fetches:
+            self._exchange_digests(request_lists, wanted)
+
+    def _exchange_digests(self, request_lists, wanted) -> None:
+        """Desync detection inside the host bank (DESIGN.md §4): hand the
+        pool the digests that have landed, and start the read of those the
+        bank asked for this tick.  Never waits for the device: a read is
+        enqueued behind the dispatch that precedes it (so it sees the ring
+        as that dispatch leaves it, and the next dispatch's donation of the
+        carry is ordered after it), its copy to the host starts at once,
+        and it is handed over by the first later tick that finds it
+        landed."""
+        fetches = self._digest_fetches
+        tick = self._dispatched_tick
+        landed = 0
+        for fetch in fetches:  # in the order asked: reports go out so
+            if not fetch[4].is_ready():
+                break
+            landed += 1
+        if wanted is None and not landed:
+            return  # a read in flight, nothing to do about it this tick
+        with self.tracer.span("device.checksum_fetch") as span:
+            rows = 0
+            for _ in range(landed):
+                pool, asked, slots, frames, out = fetches.popleft()
+                pool.deliver_checksums(slots, frames, np.asarray(out)[slots])
+                rows += len(slots)
+                self.checksum_lag_ticks = tick - asked
+                self.checksum_lag_ticks_max = max(
+                    self.checksum_lag_ticks_max, tick - asked
+                )
+            if wanted is not None:
+                slots, frames = wanted
+                ring_slots = np.zeros((self.batch_size,), np.int32)
+                ring_slots[slots] = frames % self.ring_length
+                # a session that reports one frame a tick can fall behind
+                # its ring when a burst of confirmations makes several
+                # interval frames due at once (intervals under the window;
+                # the Python session fails its assert there): such a frame
+                # is never reported, the next ones are
+                kept = self._host_frames[slots, ring_slots[slots]] == frames
+                if not kept.all():
+                    _OBS_DIGESTS_MISSED.inc(int((~kept).sum()))
+                    slots, frames = slots[kept], frames[kept]
+                if len(slots):
+                    out = self._fetch_digests(
+                        self._carry["ring"]["checksums"], ring_slots
+                    )
+                    out.copy_to_host_async()
+                    fetches.append(
+                        (request_lists.pool, tick, slots, frames, out)
+                    )
+            span.set(
+                wanted=0 if wanted is None else len(wanted[0]),
+                landed=rows, lag_ticks=self.checksum_lag_ticks,
+                in_flight=len(fetches),
+            )
 
     def _run_lists(self, request_lists: Sequence[List[GgrsRequest]]) -> None:
         if all(not reqs for reqs in request_lists):

@@ -47,7 +47,7 @@ class SessionBuilder(Generic[I, S, A]):
         self._max_prediction = DEFAULT_MAX_PREDICTION_FRAMES
         self._fps = DEFAULT_FPS
         self._sparse_saving = DEFAULT_SPARSE_SAVING
-        self._desync_detection = DesyncDetection.off()
+        self._desync_detection = config.desync_detection
         self._disconnect_timeout_ms = DEFAULT_DISCONNECT_TIMEOUT_MS
         self._disconnect_notify_start_ms = DEFAULT_DISCONNECT_NOTIFY_START_MS
         self._input_delay = DEFAULT_INPUT_DELAY

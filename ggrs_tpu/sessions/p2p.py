@@ -633,8 +633,14 @@ class P2PSession(ThreadOwned, Generic[I, S, A]):
         self._next_spectator_frame = next_spectator_frame
         # desync-detection continuity: checksum reporting resumes from the
         # adopted frame — the default cursor (NULL_FRAME → send at
-        # `interval`) would assert on cells the resumed ring never held
-        self._last_sent_checksum_frame = frame
+        # `interval`) would assert on cells the resumed ring never held.
+        # On the interval's grid, the one the peers report on: a session
+        # adopted at frame 57 of a match that detects every 10th frame
+        # reports 60 next, not 67, which no peer would ever compare
+        interval = self._desync_detection.interval
+        self._last_sent_checksum_frame = (
+            frame - frame % interval if interval > 0 else frame
+        )
 
     def adopt_spectator_endpoint(self, addr: A, endpoint) -> None:
         """Graft a spectator endpoint onto a LIVE session — the broadcast

@@ -403,7 +403,9 @@ enum BankPhase : int {
   kPhStaging = 8,   // ggrs_bank_stage_inputs time since the LAST tick —
                     // accumulated outside the tick window, reported on the
                     // next tick's tail (never part of the in-crossing sum)
-  kNumPhases = 9,
+  kPhChecksum = 9,  // desync detection: reports out, wanted rows, compares
+                    // (in-crossing; appended so that no older index moves)
+  kNumPhases = 10,
 };
 
 inline uint64_t mono_ns() {
@@ -444,6 +446,18 @@ enum EvKind : uint8_t {
   kEvDisconnected = 3,
   kEvChecksum = 4,
   kEvInput = 5,  // internal only: applied natively, never surfaced
+  kEvDesync = 6,  // a peer's report differed from the local digest of its
+                  // frame (sessions with desync detection in the bank only)
+};
+
+// protocol.py MAX_CHECKSUM_HISTORY_SIZE: how many reports a peer's pending
+// window and the local history keep
+constexpr size_t kMaxChecksumHistory = 32;
+
+// one frame's u128 digest, as the wire carries it (low half first)
+struct FrameDigest {
+  int64_t frame;
+  uint64_t lo, hi;
 };
 
 struct EpEvent {
@@ -476,6 +490,10 @@ struct BankEndpoint {
   // events persist across ticks (a post-drain event surfaces next tick,
   // exactly like protocol.py's deque)
   std::deque<EpEvent> events;
+  // the peer's checksum reports not yet compared (protocol.py
+  // pending_checksums, arrival order); filled only where the session
+  // detects desyncs in the bank, else the reports go up as kEvChecksum
+  std::vector<FrameDigest> cs_pending;
   std::vector<uint8_t> evin_bytes;  // per-tick EvInput payload scratch
   // per-tick outbound datagram streams, [u32 len][bytes]... each.  TWO
   // phases because the Python session flushes every endpoint's queue at
@@ -526,6 +544,18 @@ struct BankSession {
   int64_t current_frame = 0;
   int64_t last_confirmed = kNullFrame;
   int64_t disconnect_frame = kNullFrame;
+  // ---- desync detection (p2p.py _check_checksum_send_interval and
+  // _compare_local_checksums_against_peers; DESIGN.md section 4) ----
+  // cs_interval 0: off, and nothing below is touched.  The digest of a
+  // saved frame lives on the device, so the send is split in two: the tick
+  // that finds the next interval frame confirmed and saved ASKS for it (a
+  // row of the output's wanted tail), and the tick whose command stream
+  // brings the digest back (ctrl op 4) sends the report, in frame order.
+  int64_t cs_interval = 0;
+  int64_t cs_last_asked = kNullFrame, cs_last_sent = kNullFrame;
+  int64_t last_saved = kNullFrame;  // sync_layer.last_saved_frame
+  std::vector<FrameDigest> cs_local;      // reported digests, frame order
+  std::vector<FrameDigest> cs_delivered;  // this tick's ctrl op 4 records
   // ---- observability accumulators (ggrs_bank_stats) ----
   // monotonic; read-only for the harvest, never consulted by the tick
   uint64_t stat_ticks = 0;            // ticks this slot was actually stepped
@@ -586,6 +616,11 @@ struct Bank {
   // tick (timing armed only); flushed into the next tick's timing tail as
   // the kPhStaging entry
   uint64_t staging_pending = 0;
+  // desync detection: sessions with an interval set (0: the output carries
+  // no wanted tail), and this tick's wanted rows (slot, frame) and counts
+  size_t cs_sessions = 0;
+  std::vector<std::pair<uint32_t, int64_t>> cs_wanted;
+  uint32_t cs_sent = 0, cs_compares = 0, cs_desyncs = 0;
 };
 
 // ---- little-endian put/get over byte vectors -----------------------------
@@ -707,6 +742,116 @@ void queue_sync_reply(BankEndpoint* ep, int64_t now, uint64_t nonce) {
   msg_header(&w, ep->magic, kTagSyncReply);
   w.uvarint(nonce);
   queue_small(ep, now, w);
+}
+
+void queue_checksum_report(BankEndpoint* ep, int64_t now,
+                           const FrameDigest& d) {
+  Writer w;
+  msg_header(&w, ep->magic, kTagChecksumReport);
+  w.svarint(d.frame);
+  for (int i = 0; i < 8; ++i) w.u8((d.lo >> (8 * i)) & 0xFF);
+  for (int i = 0; i < 8; ++i) w.u8((d.hi >> (8 * i)) & 0xFF);
+  queue_small(ep, now, w);
+}
+
+// Both bounded windows (a peer's pending reports, the local history) are
+// pruned the way protocol.py and p2p.py prune theirs: what is older than
+// 31 intervals before the newest frame goes.
+void prune_digests(std::vector<FrameDigest>* window, int64_t newest,
+                   int64_t interval) {
+  int64_t oldest =
+      newest - static_cast<int64_t>(kMaxChecksumHistory - 1) * interval;
+  size_t keep = 0;
+  for (const FrameDigest& d : *window) {
+    if (d.frame >= oldest) (*window)[keep++] = d;
+  }
+  window->resize(keep);
+}
+
+// protocol.py _on_checksum_report: the window keeps the newest reports
+void store_checksum(BankEndpoint* ep, int64_t interval, const FrameDigest& d) {
+  std::vector<FrameDigest>& pending = ep->cs_pending;
+  if (pending.size() >= kMaxChecksumHistory) {
+    prune_digests(&pending, d.frame, interval);
+  }
+  for (FrameDigest& p : pending) {
+    if (p.frame == d.frame) {
+      p = d;
+      return;
+    }
+  }
+  pending.push_back(d);
+}
+
+// Desync detection for one session, where p2p.py runs it: after the poll,
+// before anything of this tick can confirm a frame.  Three steps.  (1) The
+// digests the pool brought back go out as ChecksumReports to every running
+// endpoint, exactly once a frame and in frame order, and join the local
+// history (bounded as the Python session's).  (2) The next interval frame,
+// once confirmed and saved, is asked for: one wanted row.  A frame at or
+// under last_confirmed is never loaded or saved again (a rollback loads a
+// frame above it), so whatever reads its ring slot after this tick's
+// dispatch reads the digest both peers must agree on.  (3) Every pending
+// report of a peer whose frame is confirmed and in the history is
+// compared; a difference is a kEvDesync event.
+void checksum_exchange(Bank* bank, BankSession* s, uint32_t slot, int64_t now,
+                       std::vector<uint8_t>* out_events,
+                       uint16_t* n_out_events) {
+  const int64_t interval = s->cs_interval;
+  for (const FrameDigest& d : s->cs_delivered) {
+    if (d.frame <= s->cs_last_sent) continue;  // never twice, never back
+    for (BankEndpoint& ep : s->endpoints) {
+      if (ep.state != kRunning) continue;
+      queue_checksum_report(&ep, now, d);
+      bank->cs_sent += 1;
+    }
+    s->cs_last_sent = d.frame;
+    s->cs_local.push_back(d);
+    if (s->cs_local.size() > kMaxChecksumHistory) {
+      prune_digests(&s->cs_local, d.frame, interval);
+    }
+  }
+  s->cs_delivered.clear();
+
+  int64_t next = s->cs_last_asked == kNullFrame ? interval
+                                                : s->cs_last_asked + interval;
+  if (next <= s->last_confirmed && next <= s->last_saved) {
+    bank->cs_wanted.emplace_back(slot, next);
+    s->cs_last_asked = next;
+  }
+
+  for (size_t e = 0; e < s->endpoints.size(); ++e) {
+    std::vector<FrameDigest>& pending = s->endpoints[e].cs_pending;
+    size_t keep = 0;
+    for (const FrameDigest& r : pending) {
+      const FrameDigest* local = nullptr;
+      if (r.frame < s->last_confirmed) {
+        for (const FrameDigest& l : s->cs_local) {
+          if (l.frame == r.frame) {
+            local = &l;
+            break;
+          }
+        }
+      }
+      if (local == nullptr) {
+        pending[keep++] = r;  // inputs or the local digest still to come
+        continue;
+      }
+      bank->cs_compares += 1;
+      if (local->lo != r.lo || local->hi != r.hi) {
+        bank->cs_desyncs += 1;
+        put_u8(out_events, kEvDesync);
+        put_u16(out_events, static_cast<uint16_t>(e));
+        put_i64(out_events, r.frame);
+        put_u64(out_events, local->lo);
+        put_u64(out_events, local->hi);
+        put_u64(out_events, r.lo);
+        put_u64(out_events, r.hi);
+        ++*n_out_events;
+      }
+    }
+    pending.resize(keep);
+  }
 }
 
 // protocol.py _mark_alive
@@ -1280,6 +1425,7 @@ int advance_session(Bank* bank, BankSession* s, int64_t now,
   put_u8(ops, 0);
   put_i64(ops, s->current_frame);
   ++*n_ops;
+  s->last_saved = s->current_frame;
   pt->lap(kPhRollback);
 
   // broadcast fan-out + journal tap: BEFORE set_last_confirmed discards the
@@ -1582,6 +1728,25 @@ int ggrs_bank_set_confirmed_stream(void* ptr, int64_t session, int enabled) {
 // ggrs_bank_stats appends the cumulative totals — tracing rides the
 // existing crossings.  When disarmed (the default) the tick performs zero
 // clock reads and emits byte-identical output to a pre-timing build.
+// Desync detection for one session, inside the crossing: interval > 0
+// turns it on (a ChecksumReport for every interval-th confirmed frame, the
+// compare of every report received), 0 turns it off.  Set before the
+// session's first tick.
+int ggrs_bank_set_desync_detection(void* ptr, int64_t session,
+                                   int64_t interval) {
+  Bank* bank = static_cast<Bank*>(ptr);
+  if (session < 0 ||
+      static_cast<size_t>(session) >= bank->sessions.size() || interval < 0) {
+    return kBankErrCmd;
+  }
+  BankSession* s = bank->sessions[static_cast<size_t>(session)];
+  if ((s->cs_interval > 0) != (interval > 0)) {
+    bank->cs_sessions += interval > 0 ? 1 : -1;
+  }
+  s->cs_interval = interval;
+  return kBankOk;
+}
+
 int ggrs_bank_set_timing(void* ptr, int enabled) {
   static_cast<Bank*>(ptr)->timing = enabled != 0;
   return kBankOk;
@@ -1600,6 +1765,8 @@ int ggrs_bank_set_timing(void* ptr, int enabled) {
 //     op 2 = inject a simulated per-slot fault (`frame` carries the error
 //            code; the chaos harness's native-fault stand-in)
 //     op 3 = disconnect spectator `ep` (hub policy, applied next tick)
+//     op 4 = the device's digest of saved frame `frame`, followed by
+//            u64 lo, u64 hi (desync detection: answers a wanted row)
 //   u16 n_datagrams;  per datagram: u16 ep, u32 len, bytes
 //   u16 n_spec_datagrams;  per datagram: u16 spectator, u32 len, bytes
 // Output stream: FIRST a packed header table (DESIGN.md §19) — per session,
@@ -1638,7 +1805,11 @@ int ggrs_bank_set_timing(void* ptr, int enabled) {
 //   u16 n_spec_events;  per: u8 kind, u16 spectator [+ i64 for interrupted]
 //   u16 n_conf;  [if > 0] i64 conf_start; per frame:
 //     players * u8 blank_flag, players * input_size bytes  [journal tap]
-// After the last session record, ONLY when ggrs_bank_set_timing armed the
+// After the last session record, ONLY when a session of the bank detects
+// desyncs (ggrs_bank_set_desync_detection), the wanted tail:
+//   u32 n_wanted, u32 reports_sent, u32 reports_compared, u32 desyncs;
+//   per wanted row: u32 slot, u32 0, i64 frame
+// Then, ONLY when ggrs_bank_set_timing armed the
 // phase timers (DESIGN.md §14):
 //   kNumPhases * u64 phase_ns, u64 tick_t0_ns, u8 n_phases   [timing tail:
 //     tick_t0_ns is this crossing's entry on steady_clock, which places
@@ -1697,9 +1868,10 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
       }
       uint16_t n_ctrl = scan.u16();
       for (uint16_t i = 0; i < n_ctrl; ++i) {
-        scan.u8();
+        uint8_t op = scan.u8();
         scan.u16();
         scan.i64();
+        if (op == 4) scan.raw(16);  // the digest's two halves
       }
       for (int section = 0; section < 2; ++section) {
         uint16_t nd = scan.u16();
@@ -1809,6 +1981,14 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
         // simulated native slot fault: the whole slot tick is skipped, as
         // a real mid-tick fault would leave it
         err = frame < 0 ? static_cast<int>(frame) : kBankErrInjected;
+      } else if (op == 4) {
+        // a digest the pool fetched from the device for the wanted row
+        // (slot, frame) of an earlier tick: sent and kept at the
+        // exchange below (dropped where the slot no longer detects)
+        FrameDigest d{frame, static_cast<uint64_t>(r.i64()),
+                      static_cast<uint64_t>(r.i64())};
+        if (!r.ok) return kBankErrCmd;
+        if (s->cs_interval > 0) s->cs_delivered.push_back(d);
       } else if (op == 3 && ep_idx < s->spectators.size()) {
         // disconnect spectator (hub policy, one tick after its event —
         // p2p.py's spectator branch of _disconnect_player_at_frame: no
@@ -1988,6 +2168,11 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
             }
           }
         } else {
+          if (ev.kind == kEvChecksum && s->cs_interval > 0) {
+            // compared here, in the crossing: no event goes up
+            store_checksum(&ep, s->cs_interval, FrameDigest{ev.a, ev.lo, ev.hi});
+            continue;
+          }
           put_u8(&out_events, ev.kind);
           put_u16(&out_events, static_cast<uint16_t>(staged_eps[i]));
           if (ev.kind == kEvInterrupted) put_i64(&out_events, ev.a);
@@ -2009,6 +2194,12 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
       for (BankEndpoint& ep : s->spectators) ep.cur_out = &ep.out_adv;
       if (flags & kFlagInputs) {
         if (!local_inputs) return kBankErrCmd;
+        if (s->cs_interval > 0) {
+          pt.skip();
+          checksum_exchange(bank, s, static_cast<uint32_t>(my_hdr / kHdrStride),
+                            now, &out_events, &n_out_events);
+          pt.lap(kPhChecksum);
+        }
         int rc = advance_session(bank, s, now, local_inputs, &ops, &n_ops,
                                  &landed, &frames_ahead, &pt);
         if (rc != kBankOk) err = rc;
@@ -2146,6 +2337,24 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
   }
 
   if (r.pos != r.len) return kBankErrCmd;  // trailing garbage: refuse
+  if (bank->cs_sessions > 0) {
+    // wanted tail (desync detection; a bank without a detecting session
+    // emits none, so its output is byte for byte what it was): u32
+    // n_wanted, u32 reports sent, u32 reports compared, u32 desyncs, then
+    // per row u32 slot, u32 0, i64 frame -- the digests the pool is to
+    // fetch from the device and hand back through ctrl op 4
+    put_u32(&bank->out, static_cast<uint32_t>(bank->cs_wanted.size()));
+    put_u32(&bank->out, bank->cs_sent);
+    put_u32(&bank->out, bank->cs_compares);
+    put_u32(&bank->out, bank->cs_desyncs);
+    for (const auto& row : bank->cs_wanted) {
+      put_u32(&bank->out, row.first);
+      put_u32(&bank->out, 0);
+      put_i64(&bank->out, row.second);
+    }
+    bank->cs_wanted.clear();
+    bank->cs_sent = bank->cs_compares = bank->cs_desyncs = 0;
+  }
   if (pt.on) {
     // timing tail (count byte LAST so Python can parse from the end
     // without knowing the phase count up front): kNumPhases u64 ns then
@@ -2155,6 +2364,7 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
     uint64_t total = mono_ns() - tick_t0;
     uint64_t sum = 0;
     for (int i = 0; i < kPhOther; ++i) sum += pt.ns[i];
+    sum += pt.ns[kPhChecksum];
     pt.ns[kPhOther] = total > sum ? total - sum : 0;
     // staging happened OUTSIDE this tick's window (ggrs_bank_stage_inputs
     // crossings since the last tick); it rides the tail as its own entry

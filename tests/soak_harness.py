@@ -81,6 +81,15 @@ def p2p_soak(frames: int, periodic=None) -> dict:
     speculative — the LAST save wins, compared once both peers are
     max_prediction+1 past it, then forgotten so memory stays bounded).
 
+    Which tier it soaks: the sessions are started with
+    ``start_p2p_session``, not added to a ``HostSessionPool``, so detection
+    at interval 100 runs in the per-session Python ``P2PSession`` here: the
+    protocol's plain reference.  Since PR 35 a POOL of such builders is
+    served by the native bank with detection inside the crossing
+    (docs/DESIGN.md §4); that tier is held to this one by
+    ``tests/test_bank_desync_detection.py`` and driven on the chip by
+    ``chip_smoke.py``'s ``pool`` leg, not by this soak.
+
     ``periodic(sessions, digests)`` runs every 10k frames for extra
     invariants (the test asserts queue bounds there).  Returns
     ``{"fps", "compared", "desyncs", "rss_drift_mb"}`` after asserting

@@ -71,6 +71,13 @@ class TestChipSmoke:
         pool = summary["legs"]["pool"]
         assert pool["crossings"] == pool["plan_ticks"] == pool["ticks"]
         assert pool["rollback_loads"] > 0 and pool["compiles_in_ticks"] == 0
+        # desync detection on, inside the bank: at least one report
+        # compared a session, none differing
+        assert pool["native"] == "native bank engaged"
+        assert pool["checksum_reports_compared"] >= pool["sessions"]
+        assert (pool["checksum_reports_sent"]
+                >= pool["checksum_reports_compared"])
+        assert pool["desyncs"] == 0
         # every line a rehearsal prints says what it is
         assert all(
             l.startswith("REHEARSAL") for l in lines[:-1]

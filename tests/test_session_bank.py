@@ -368,7 +368,8 @@ class TestFallback:
 
     def test_ineligible_shapes_fall_back(self):
         """Session shapes outside the bank's mechanism must use the Python
-        sessions even when the native library is present."""
+        sessions even when the native library is present; desync detection
+        is inside it."""
         from ggrs_tpu.core.types import DesyncDetection
 
         def make(builder_tweak):
@@ -390,7 +391,9 @@ class TestFallback:
 
         assert not make(lambda b: b.with_sparse_saving_mode(True)).native_active
         assert not make(lambda b: b.with_max_prediction_window(0)).native_active
-        assert not make(
+        # desync detection runs inside the bank since PR 35 (DESIGN.md §4):
+        # a pool whose builders have it on is served by the native tier
+        assert make(
             lambda b: b.with_desync_detection_mode(DesyncDetection.on(100))
         ).native_active
         assert not make(lambda b: b.with_sync_handshake(True)).native_active
