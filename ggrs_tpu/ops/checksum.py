@@ -65,29 +65,49 @@ def _as_u32_words(x: jax.Array) -> jax.Array:
     return jnp.sum(packed << shifts[None, :], axis=1, dtype=jnp.uint32)
 
 
+def lane_terms(words: jax.Array, idx: jax.Array) -> Tuple[jax.Array, ...]:
+    """The four per-word terms whose mod-2^32 sums are the digest's lanes —
+    THE single definition of the lane math: ``words`` u32 of any shape,
+    ``idx`` the 1-based global index of each word, u32, shaped alike.  Plain
+    operators and NumPy constants only, so that the same lines trace under
+    XLA (``lane_sums``) and inside a Pallas kernel, which may close over no
+    device array (``ops/pallas_checksum.py``; ``ops/ring.py`` ``write_slot``,
+    which digests a slot where it writes it)."""
+    rot = (words << np.uint32(13)) | (words >> np.uint32(19))
+    return (
+        words,
+        words * idx,
+        words * (idx * _PRIME_A + np.uint32(1)),
+        rot ^ (idx * _PRIME_B),
+    )
+
+
+def wrap_sum(x: jax.Array) -> jax.Array:
+    """Mod-2^32 sum of a u32 array as an int32 scalar: how a Pallas kernel
+    reduces a lane's terms.  Mosaic has no unsigned reductions (and no scalar
+    bitcasts), so sum through an int32 vector bitcast and keep the scalar
+    signed — two's-complement wraparound addition is bit-identical to
+    unsigned mod-2^32 addition; the caller bitcasts the accumulated lanes
+    back to u32 outside the kernel."""
+    return jnp.sum(jax.lax.bitcast_convert_type(x, jnp.int32), dtype=jnp.int32)
+
+
 def lane_sums(words: jax.Array, offset=0) -> jax.Array:
     """The four lane sums over a u32 word vector with 1-based global indices
-    starting at ``offset + 1`` — THE single definition of the lane math.
+    starting at ``offset + 1`` (the terms: ``lane_terms``).
     Every lane is a commutative mod-2^32 sum of per-word terms, so digests
     of consecutive chunks add: ``lane_sums(w) == lane_sums(w[:k]) +
-    lane_sums(w[k:], k)`` (the property the pallas kernel's tail fold uses).
+    lane_sums(w[k:], k)`` (the property the pallas kernel's tail fold uses,
+    and the ring's write kernels, whose partial sums a leaf add up).
     """
     n = words.shape[0]
     idx = jnp.asarray(offset, jnp.uint32) + jnp.arange(
         1, n + 1, dtype=jnp.uint32
     )
-    rot = (words << jnp.uint32(13)) | (words >> jnp.uint32(19))
     # one (4, n) reduction instead of four separate sums: inside a scan body
     # each tiny reduction is a serially-scheduled op, and the digest sits on
     # the critical path of every resimulated frame
-    terms = jnp.stack(
-        [
-            words,
-            words * idx,
-            words * (idx * _PRIME_A + jnp.uint32(1)),
-            rot ^ (idx * _PRIME_B),
-        ]
-    )
+    terms = jnp.stack(lane_terms(words, idx))
     return jnp.sum(terms, axis=1, dtype=jnp.uint32)
 
 
@@ -172,16 +192,24 @@ def checksum_device(state: Any) -> jax.Array:
     dominate when leaves are a few words each).
     """
     leaves = [jnp.asarray(l) for l in jax.tree_util.tree_leaves(state)]
-    # dtype must be explicit: _INIT_LANES holds ints above int32 max, and
-    # jnp.asarray's int32 default turns the empty-pytree path into an
-    # OverflowError (ADVICE r5)
-    salt = jnp.asarray(
-        _structure_salt(leaves) if leaves else _INIT_LANES, jnp.uint32
-    )
     if not leaves:
-        return salt
-    lanes = _digest_words([_as_u32_words(l) for l in leaves])
-    acc = salt * _GOLDEN + lanes
+        # dtype must be explicit: _INIT_LANES holds ints above int32 max, and
+        # jnp.asarray's int32 default turns the empty-pytree path into an
+        # OverflowError (ADVICE r5)
+        return jnp.asarray(_INIT_LANES, jnp.uint32)
+    return finish_digest(
+        leaves, _digest_words([_as_u32_words(l) for l in leaves])
+    )
+
+
+def finish_digest(leaves, lanes: jax.Array) -> jax.Array:
+    """``checksum_device``'s tail: the lane sums ``[..., 4]`` over all of a
+    state's words (``lane_sums`` at global offsets, leaf after leaf in
+    ``jax.tree_util`` order) salted with the structure of its ``leaves``
+    (anything with a ``dtype`` and a ``size``: one state's arrays or their
+    shapes) and finalized.  Whoever sums the lanes elsewhere (the ring's write,
+    ``ops/ring.py``) ends here, so the digest is ``checksum_device``'s."""
+    acc = jnp.asarray(_structure_salt(leaves), jnp.uint32) * _GOLDEN + lanes
     return acc ^ (acc >> jnp.uint32(15))
 
 

@@ -39,24 +39,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# lane constants — imported from ops.checksum so the kernel's per-word terms
-# and the XLA formulas can never drift apart
-from .checksum import _PRIME_A, _PRIME_B, lane_sums
+# the per-word lane terms are ops.checksum's own, so the kernel's and the XLA
+# formulas can never drift apart
+from .checksum import lane_sums, lane_terms, wrap_sum
 
 # (sublanes, lanes) per grid step: 256×128 u32 = 128 KiB of VMEM per block,
 # comfortably inside the ~16 MiB VMEM budget with room for double-buffering
 _BLOCK_ROWS = 256
 _LANES = 128
 MIN_PALLAS_WORDS = 1 << 15  # below ~32k words the launch overhead dominates
-
-
-def _wrap_sum(x: jax.Array) -> jax.Array:
-    """Mod-2^32 sum of a u32 array, as an int32 scalar.  Mosaic has no
-    unsigned reductions (and no scalar bitcasts), so sum through an int32
-    vector bitcast and keep the scalar signed — two's-complement wraparound
-    addition is bit-identical to unsigned mod-2^32 addition; the caller
-    bitcasts the (4,) accumulator back to u32 outside the kernel."""
-    return jnp.sum(jax.lax.bitcast_convert_type(x, jnp.int32), dtype=jnp.int32)
 
 
 def _digest_kernel(w_ref, out_ref):
@@ -74,11 +65,7 @@ def _digest_kernel(w_ref, out_ref):
     # 1-based global word index, as in checksum._leaf_digest
     idx = base + row * np.uint32(_LANES) + col + np.uint32(1)
 
-    lane0 = _wrap_sum(w)
-    lane1 = _wrap_sum(w * idx)
-    lane2 = _wrap_sum(w * (idx * _PRIME_A + np.uint32(1)))
-    rot = (w << np.uint32(13)) | (w >> np.uint32(19))
-    lane3 = _wrap_sum(rot ^ (idx * _PRIME_B))
+    lane0, lane1, lane2, lane3 = (wrap_sum(t) for t in lane_terms(w, idx))
 
     @pl.when(i == 0)
     def _init():

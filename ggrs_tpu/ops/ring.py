@@ -21,7 +21,9 @@ Forms of write, by who indexes and by size (docs/DESIGN.md §3):
   chooses per leaf: the select, or ``write_slot``, a kernel that moves the
   one slot a session saves and nothing else, for the leaves the caller holds
   row-major (gigabytes of ring, where the select moved 2.66 GB to change
-  0.27).
+  0.27).  Asked to, it also takes the digest of what it saves, by the same
+  rule: the lane sums out of the kernels that write for a leaf written in
+  place, ``lane_sums`` in XLA for a leaf the select writes.
 
 *load* is a dynamic slice (a gather under a per-session frame).
 
@@ -36,14 +38,23 @@ program's boundary").
 from __future__ import annotations
 
 import math
-from typing import Any, Sequence
+from typing import Any, Optional, Sequence, Tuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .checksum import CHECKSUM_LANES
+from .checksum import (
+    CHECKSUM_LANES,
+    _as_u32_words,
+    finish_digest,
+    lane_sums,
+    lane_terms,
+    wrap_sum,
+)
 
 # The in-place write holds two blocks of one slot in VMEM, each double
 # buffered (the state to save, the slot as written): four slots' worth,
@@ -69,7 +80,8 @@ def write_slot(
     slot: jax.Array,
     pred: jax.Array,
     interpret: bool = False,
-) -> jax.Array:
+    digest_offset: Optional[int] = None,
+) -> Tuple[jax.Array, Optional[jax.Array]]:
     """``buf[b, slot[b]] = leaf[b]`` for every session ``b`` whose ``pred[b]``
     is true, and no other byte of ``buf`` read or written.  At least one
     ``pred`` has to be true (``save_where_batch`` asks first).
@@ -87,6 +99,17 @@ def write_slot(
     out for the sessions that save, where the select of ``save_where`` reads
     and writes all ``R`` slots of every session.
 
+    **The digest is taken here** when ``digest_offset`` is given: the second
+    result is then ``[B, 4]`` u32, row ``b`` the four lane sums
+    (``ops/checksum.py`` ``lane_terms``) of the words session ``b`` writes,
+    each at its 1-based global index ``digest_offset + 1 + `` its row-major
+    place in ``leaf[b]``: what ``lane_sums(words(leaf[b]), digest_offset)``
+    gives, read off the block that is in VMEM for the write, so that no
+    second pass reads the state again and a session that does not save is
+    not digested.  A row whose ``pred`` is false holds nothing meant (the
+    caller selects by ``pred``).  ``None``: no digest in the kernel, and
+    ``None`` for the second result.
+
     ``interpret`` runs the same kernel under Pallas's interpreter (off the
     TPU: the program tier-1 runs is the program the chip runs)."""
     sessions = buf.shape[0]
@@ -95,43 +118,84 @@ def write_slot(
     at = jnp.arange(sessions, dtype=jnp.int32)
     last = jax.lax.cummax(jnp.where(pred, at, -1))
     visit = jnp.where(last < 0, jnp.argmax(pred).astype(jnp.int32), last)
+    digest = digest_offset is not None
+    if digest:
+        assert buf.dtype.itemsize == 4, buf.dtype  # a word an element
+        # each word's index less its place along the block's own axes
+        strides = [math.prod(rest[d + 1:]) for d in range(len(rest))]
+        first = np.uint32(digest_offset + 1)
 
-    def kernel(visit_ref, slot_ref, pred_ref, leaf_ref, ring_ref, out_ref):
+    def kernel(visit_ref, slot_ref, pred_ref, leaf_ref, ring_ref, out_ref,
+               lanes_ref=None):
         del visit_ref, slot_ref  # read by the block maps
         del ring_ref  # the result's buffer: every block written is written whole
+        b = pl.program_id(0)
 
-        @pl.when(pred_ref[pl.program_id(0)] != 0)
+        @pl.when(pred_ref[b] != 0)
         def _():
-            out_ref[...] = leaf_ref[...][:, None]
+            state = leaf_ref[...]
+            out_ref[...] = state[:, None]
+            if lanes_ref is None:
+                return
+            words = state[0]
+            if words.dtype != jnp.uint32:
+                words = jax.lax.bitcast_convert_type(words, jnp.uint32)
+            idx = first
+            for d, stride in enumerate(strides):
+                if rest[d] > 1:
+                    idx = idx + jax.lax.broadcasted_iota(
+                        jnp.uint32, rest, d
+                    ) * np.uint32(stride)
+            # (a lane the tile pads, 10,000 words in 79 tiles of 128, is no
+            # element of the block: the reduction never sees it)
+            for lane, terms in enumerate(lane_terms(words, idx)):
+                lanes_ref[CHECKSUM_LANES * b + lane] = wrap_sum(terms)
 
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(sessions,),
-            in_specs=[
-                pl.BlockSpec(
-                    (1,) + rest,
-                    lambda b, visit, slot, pred: (visit[b],) + zeros,
+    ring_shape = jax.ShapeDtypeStruct(buf.shape, buf.dtype)
+    ring_spec = pl.BlockSpec(
+        (1, 1) + rest,
+        lambda b, visit, slot, pred: (visit[b], slot[b]) + zeros,
+    )
+    # the lanes as one flat vector of scalar memory, written a scalar a time
+    lanes_shape = jax.ShapeDtypeStruct(
+        (sessions * CHECKSUM_LANES,), jnp.int32
+    )
+    with jax.named_scope("write_slot"):
+        out = pl.pallas_call(
+            kernel,
+            out_shape=(ring_shape, lanes_shape) if digest else ring_shape,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(sessions,),
+                in_specs=[
+                    pl.BlockSpec(
+                        (1,) + rest,
+                        lambda b, visit, slot, pred: (visit[b],) + zeros,
+                    ),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                ],
+                out_specs=(
+                    (ring_spec, pl.BlockSpec(memory_space=pltpu.SMEM))
+                    if digest
+                    else ring_spec
                 ),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1) + rest,
-                lambda b, visit, slot, pred: (visit[b], slot[b]) + zeros,
             ),
-        ),
-        # operands: visit, slot, pred, leaf, buf -> the ring is the result
-        input_output_aliases={4: 0},
-        name="ring_write_slot",
-        interpret=interpret,
-    )(
-        visit,
-        jnp.asarray(slot, jnp.int32)[visit],
-        jnp.asarray(pred, jnp.int32),
-        jnp.asarray(leaf, buf.dtype),
-        buf,
+            # operands: visit, slot, pred, leaf, buf -> the ring is the result
+            input_output_aliases={4: 0},
+            name="ring_write_slot",
+            interpret=interpret,
+        )(
+            visit,
+            jnp.asarray(slot, jnp.int32)[visit],
+            jnp.asarray(pred, jnp.int32),
+            jnp.asarray(leaf, buf.dtype),
+            buf,
+        )
+    if not digest:
+        return out, None
+    written, lanes = out
+    return written, jax.lax.bitcast_convert_type(lanes, jnp.uint32).reshape(
+        sessions, CHECKSUM_LANES
     )
 
 
@@ -244,7 +308,7 @@ class DeviceStateRing:
         ring: Any,
         frame: jax.Array,
         state: Any,
-        checksum: jax.Array,
+        checksum: Optional[jax.Array],
         pred: jax.Array,
         in_place: Any,
         interpret: bool = False,
@@ -258,16 +322,76 @@ class DeviceStateRing:
         digests and frame tags always: kilobytes); true, ``write_slot``, for
         a leaf the caller holds row-major and ``writes_slot_in_place`` takes.
         It stands at batch level because a kernel whose block maps read the
-        sessions' slots has no useful rule under ``vmap``."""
+        sessions' slots has no useful rule under ``vmap``.
+
+        ``checksum`` ``None``: the digest of each saved state is taken here,
+        and is ``checksum_device``'s bit for bit.  A leaf written in place is
+        digested by the kernel that writes it, in the pass that has the slot
+        in VMEM and for the sessions that save only (a write in which none
+        saves digests nothing), and the lanes add up: the kernels' partial
+        sums, the select's leaves' ``lane_sums`` at their offsets, then
+        ``finish_digest``.  The same rule as the write, per leaf, from
+        ``in_place`` (docs/DESIGN.md §3 "Per-session slots"); with no leaf
+        written in place every lane is XLA's, and the caller may as well
+        hand in ``jax.vmap(checksum_device)``'s digests, as the pool does."""
         bufs, tree = jax.tree_util.tree_flatten(ring["states"])
+        per_state = tree.flatten_up_to(state)
         # (the caller may hold a slot with unit axes the state has not)
         leaves = [
             leaf.reshape(buf.shape[:1] + buf.shape[2:])
-            for leaf, buf in zip(tree.flatten_up_to(state), bufs)
+            for leaf, buf in zip(per_state, bufs)
         ]
         direct = tree.flatten_up_to(in_place)
         by_select = [i for i, d in enumerate(direct) if not d]
         by_kernel = [i for i, d in enumerate(direct) if d]
+        digest_here = checksum is None
+        # where each leaf's words start among one state's, as
+        # checksum_device counts them
+        one_state = [
+            jax.ShapeDtypeStruct(leaf.shape[1:], leaf.dtype)
+            for leaf in per_state
+        ]
+        offsets = np.cumsum(
+            [0] + [(l.size * l.dtype.itemsize + 3) // 4 for l in one_state]
+        ).tolist()
+        lanes = jnp.zeros(pred.shape + (CHECKSUM_LANES,), jnp.uint32)
+        if by_kernel:
+            # an idle row's frame of -1 matches no slot: the index the block
+            # map reads is clamped, and the predicate says nothing is written
+            ok = pred & (frame >= 0)
+            slot = jnp.where(ok, self.slot(frame), 0)
+
+            def written(before, lanes):
+                after, partial = zip(*(
+                    write_slot(
+                        buf, leaves[i], slot, ok, interpret,
+                        offsets[i] if digest_here else None,
+                    )
+                    for i, buf in zip(by_kernel, before)
+                ))
+                return list(after), sum(partial) if digest_here else lanes
+
+            # a write in which no session saves (the second step of a burst
+            # whose sessions rolled back one frame) runs no kernel: the ring
+            # passes through the conditional uncopied
+            after, lanes = jax.lax.cond(
+                jnp.any(ok),
+                written,
+                lambda before, lanes: (before, lanes),
+                [bufs[i] for i in by_kernel],
+                lanes,
+            )
+            for i, buf in zip(by_kernel, after):
+                bufs[i] = buf
+        if digest_here:
+            with jax.named_scope("digest"):
+                for i in by_select:
+                    lanes = lanes + jax.vmap(
+                        lambda l, at=offsets[i]: lane_sums(
+                            _as_u32_words(l), at
+                        )
+                    )(leaves[i])
+                checksum = finish_digest(one_state, lanes)
         out = jax.vmap(self.save_where)(
             {**ring, "states": [bufs[i] for i in by_select]},
             frame,
@@ -277,25 +401,6 @@ class DeviceStateRing:
         )
         for i, buf in zip(by_select, out["states"]):
             bufs[i] = buf
-        if by_kernel:
-            # an idle row's frame of -1 matches no slot: the index the block
-            # map reads is clamped, and the predicate says nothing is written
-            ok = pred & (frame >= 0)
-            slot = jnp.where(ok, self.slot(frame), 0)
-            # a write in which no session saves (the second step of a burst
-            # whose sessions rolled back one frame) runs no kernel: the ring
-            # passes through the conditional uncopied
-            after = jax.lax.cond(
-                jnp.any(ok),
-                lambda before: [
-                    write_slot(buf, leaves[i], slot, ok, interpret)
-                    for i, buf in zip(by_kernel, before)
-                ],
-                lambda before: before,
-                [bufs[i] for i in by_kernel],
-            )
-            for i, buf in zip(by_kernel, after):
-                bufs[i] = buf
         return {**out, "states": tree.unflatten(bufs)}
 
     def save_many(

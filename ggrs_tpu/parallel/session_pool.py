@@ -41,7 +41,8 @@ is made, passed and returned in the layout the tick program computes in
 (``ring_leaf_layout`` below, chosen per leaf from its shape where
 ``tick_program`` builds the carry), so no whole-ring transposition stands
 at the program's entry or exit, and a save writes such a leaf in place: the
-one slot a session saves, not a select over all of them (``ops/ring.py``
+one slot a session saves, not a select over all of them, and the kernel that
+writes it takes its digest from the block it holds (``ops/ring.py``
 ``write_slot``; docs/DESIGN.md §3 "Per-session slots").
 """
 
@@ -112,6 +113,13 @@ _OBS_RING_INPLACE_BYTES = default_registry().gauge(
     "bytes, on its fullest device, of the ring leaves whose saves the newest "
     "pooled executor writes in place, one slot a session, instead of by a "
     "select over every slot (0: none is)",
+)
+_OBS_DIGEST_AT_WRITE_BYTES = default_registry().gauge(
+    "ggrs_executor_digest_at_write_bytes",
+    "bytes, on its fullest device, of one batch's state leaves whose digest "
+    "the newest pooled executor takes in the kernel that writes their ring "
+    "slot, not in a pass of its own over every session's state (0: none, or "
+    "no digests are kept)",
 )
 _OBS_MESH_DEVICES = default_registry().gauge(
     "ggrs_executor_mesh_devices",
@@ -369,12 +377,17 @@ def tick_program(
         formats["ring"]["states"],
     )
     interpret = platform != "tpu"
+    # and the digest of a save follows the write: where a kernel writes any
+    # leaf, the kernels take the lane sums of what they write and
+    # save_where_batch adds the select's leaves' (the same digest, bit for
+    # bit); where the select writes every leaf, checksum_device as ever
+    digest_at_write = any(jax.tree_util.tree_leaves(in_place))
 
     def tick(carry: Dict[str, Any], desc: Dict[str, Any]) -> Dict[str, Any]:
-        """One tick of every session: the per-session pieces (the digest,
-        the load, the game's step) under ``vmap``, the ring write at batch
-        level (``save_where_batch``).  Under ``shard_map`` the batch is a
-        shard's own: no collective."""
+        """One tick of every session: the per-session pieces (the load, the
+        game's step) under ``vmap``, the ring write and the digest of what
+        it saves at batch level (``save_where_batch``).  Under ``shard_map``
+        the batch is a shard's own: no collective."""
         live, ring = carry["live"], carry["ring"]
         sessions = desc["n_adv"].shape[0]
         # what the whole batch asks:
@@ -385,12 +398,15 @@ def tick_program(
         # the scopes name the program's parts in a device profile
         # (metadata only: the lowered operations are the same)
         def write(ring, frame, st, pred):
-            with jax.named_scope("digest"):
-                cs = (
-                    jax.vmap(checksum_device)(st)
-                    if with_checksums
-                    else jnp.broadcast_to(zero_cs, (sessions,) + zero_cs.shape)
-                )
+            if not with_checksums:
+                cs = jnp.broadcast_to(zero_cs, (sessions,) + zero_cs.shape)
+            elif digest_at_write:
+                # taken by the kernels that write, from the block they hold,
+                # for the sessions that save
+                cs = None
+            else:
+                with jax.named_scope("digest"):
+                    cs = jax.vmap(checksum_device)(st)
             return dring.save_where_batch(
                 ring, frame, st, cs, pred, in_place, interpret
             )
@@ -661,16 +677,23 @@ class BatchedRequestExecutor:
         _OBS_RING_RELAID_BYTES.set(
             sum(leaf.nbytes for leaf, _ in relaid) // devices
         )
-        _OBS_RING_INPLACE_BYTES.set(
-            sum(
-                leaf.nbytes
-                for leaf, direct in zip(
-                    jax.tree_util.tree_leaves(self._carry["ring"]["states"]),
-                    jax.tree_util.tree_leaves(program.in_place),
+
+        def in_place_bytes(states: Any) -> int:
+            return (
+                sum(
+                    leaf.nbytes
+                    for leaf, direct in zip(
+                        jax.tree_util.tree_leaves(states),
+                        jax.tree_util.tree_leaves(program.in_place),
+                    )
+                    if direct
                 )
-                if direct
+                // devices
             )
-            // devices
+
+        _OBS_RING_INPLACE_BYTES.set(in_place_bytes(self._carry["ring"]["states"]))
+        _OBS_DIGEST_AT_WRITE_BYTES.set(
+            in_place_bytes(self._carry["live"]) if with_checksums else 0
         )
         self._input_dtype: Optional[np.dtype] = None
         self._input_shape: Optional[Tuple[int, ...]] = None

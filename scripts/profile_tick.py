@@ -315,13 +315,20 @@ def tracer_ab(pool, inputs, tracer, hz, segment_ticks, segments):
 # one profile: the program's spans beside the device's operations
 # ---------------------------------------------------------------------------
 
-SCOPES = ("ring.pre_save", "ring.load", "ring.save", "advance", "digest")
+# ``write_slot``: the kernels that save a re-laid ring leaf in place AND take
+# the lane sums of what they write (``ops/ring.py``); ``digest``: what XLA
+# still digests (the leaves the select writes, the salt, the finalizer; in a
+# pool with no leaf in place, ``jax.vmap(checksum_device)`` whole)
+SCOPES = ("ring.pre_save", "ring.load", "ring.save", "advance", "digest",
+          "write_slot")
 _STRUCTURE = ("while", "body", "cond")
 
 
 def scope_of(op_name: str) -> str:
     """``jit(tick)/while/body/ring.save/digest/vmap()/mul`` -> ``ring.save >
-    digest``: the named scopes of the pool's ``tick`` on an operation's path,
+    digest``, ``jit(tick)/ring.pre_save/cond/branch_1_fun/write_slot/
+    ring_write_slot/pallas_call`` -> ``ring.pre_save > write_slot``: the
+    named scopes of the pool's ``tick`` on an operation's path,
     and under ``advance`` the game's own outermost scope, whatever its name
     (``.../advance/vmap(spawn)/select_n`` -> ``advance > spawn``): a part,
     bare or under the ``vmap`` the game's step runs in, that is neither the

@@ -102,8 +102,10 @@ def test_a_pool_over_four_devices_equals_one_device_and_the_reference(
         rule, mesh, monkeypatch):
     """Above the rule a shard's wide ring leaves are held row-major and saved
     in place (``ops/ring.py`` ``write_slot``, each shard its own kernel under
-    ``shard_map``); below it they keep the default layout and the select.
-    Either way: the reference's bytes."""
+    ``shard_map``, which also takes the digest of what it writes); below it
+    they keep the default layout, the select and ``jax.vmap(checksum_device)``.
+    Either way: the reference's bytes and the reference's digests, on four
+    devices as on one."""
     per_device = default_registry().value  # the gauges: the newest executor's
     wide = RING * (3 + 4 + 3 + 2 + 1) * SMALL["capacity"] * 4
     if rule == "above":
@@ -116,9 +118,13 @@ def test_a_pool_over_four_devices_equals_one_device_and_the_reference(
     one = _pool()
     assert per_device("ggrs_executor_ring_relaid_bytes") == SESSIONS * wide
     assert per_device("ggrs_executor_ring_inplace_bytes") == SESSIONS * wide
+    # (one batch's states of those leaves: digested by the write kernels)
+    assert per_device("ggrs_executor_digest_at_write_bytes") == (
+        SESSIONS * wide // RING)
     across = _pool(mesh)
     assert per_device("ggrs_executor_ring_relaid_bytes") == 2 * wide
     assert per_device("ggrs_executor_ring_inplace_bytes") == 2 * wide
+    assert per_device("ggrs_executor_digest_at_write_bytes") == 2 * wide // RING
     assert per_device("ggrs_executor_mesh_devices") == SHARDS
     for leaf in ("rotation", "ttl", "velocity"):
         held = across._carry["ring"]["states"][leaf]

@@ -10,6 +10,7 @@ value.  Every compile for the described chip lives in THIS file, behind one
 fixture: a process loads the TPU's library once.
 """
 
+import functools
 import os
 import re
 import sys
@@ -205,8 +206,10 @@ def _ring_sized(text, sessions=512):
     ``s32[sessions, 10, K, 10000]`` (K None: a leaf of rank 3, which no
     carry holds any more)."""
     found = {}
+    # (a write kernel's result is the ring leaf AND its savers' lane sums)
     for m in re.finditer(
-        rf"= s32\[{sessions},10,(?:(\d+),)?10000\]\S* ([\w-]+)\(", text
+        rf"= \(?s32\[{sessions},10,(?:(\d+),)?10000\]\S*"
+        rf"(?: s32\[{4 * sessions}\]\S*\))? ([\w-]+)\(", text
     ):
         key = (m.group(2), m.group(1) and int(m.group(1)))
         found[key] = found.get(key, 0) + 1
@@ -233,7 +236,7 @@ def _assert_written_in_place(text, sessions=512):
     kernels = re.findall(r".*custom_call_target=\"tpu_custom_call\".*", text)
     assert len(kernels) == 5 * _WRITES_A_TICK
     for line in kernels:
-        assert "output_to_operand_aliasing={{}: (4, {})}" in line
+        assert "output_to_operand_aliasing={{0}: (4, {})}" in line
         assert "ring_write_slot" in line
 
 
@@ -283,6 +286,62 @@ def test_the_particle_tick_writes_its_wide_leaves_in_place(particle_tick):
         "rotation": True, "scale": True, "translation": True, "velocity": True,
     }
     _assert_written_in_place(compiled.as_text())
+
+
+def test_the_particle_tick_digests_no_wide_leaf_outside_its_write_kernels(
+        particle_tick):
+    """``jax.vmap(checksum_device)`` over the batch was a multiply and a
+    reduce over every ``[512, K, 10000]`` leaf at each write; the kernels
+    digest the slot they write, and what XLA still digests is the two small
+    leaves (7 words a session)."""
+    _program_, compiled = particle_tick
+    text = compiled.as_text()
+    wide = re.compile(r"[su]32\[512,(?:\d+,)?10000\]")
+    digest_ops = [
+        line for line in text.splitlines()
+        if "/digest/" in line and wide.search(line)
+    ]
+    assert digest_ops == []
+    # no arithmetic of the lanes (a u32 multiply, a u32 reduce) on an
+    # operand of that size anywhere in the text, under any name
+    u32_wide = re.compile(r"u32\[512,(?:\d+,)?10000\]")
+    assert not [
+        line for line in text.splitlines()
+        if u32_wide.search(line) and re.search(r" (multiply|reduce)\(", line)
+        and "/advance/" not in line
+    ]
+    # what is left under the scope is small: the two leaves the select
+    # writes, the salt and the finalizer
+    left = [line for line in text.splitlines() if "/digest/" in line]
+    assert left, "the census found no digest at all: it reads nothing"
+    assert all(
+        int(np.prod([int(d) for d in dims.split(",")])) <= 512 * 7 * 4
+        for line in left
+        for dims in re.findall(r"= \(?[a-z]+\d*\[([\d,]+)\]", line)
+    )
+    # and every kernel hands its savers' lane sums out beside the ring
+    kernels = re.findall(r".*custom_call_target=\"tpu_custom_call\".*", text)
+    assert len(kernels) == 15
+    assert all(re.search(r", s32\[2048\]\S*\) custom-call\(", k)
+               for k in kernels)
+
+
+def test_a_profile_names_the_write_kernels_and_what_is_left_of_the_digest(
+        particle_tick):
+    """``scripts/profile_tick.py``'s scope table: the kernels that write and
+    digest under ``write_slot``, XLA's remainder under ``digest``."""
+    from profile_tick import hlo_scopes, scope_of
+
+    assert scope_of(
+        "jit(tick)/while/body/ring.save/cond/branch_1_fun/write_slot/"
+        "ring_write_slot/pallas_call") == "ring.save > write_slot"
+    scopes = hlo_scopes(particle_tick[1].as_text())
+    kernels = [v for k, v in scopes.items() if k.startswith("ring_write_slot")]
+    assert sorted(set(kernels)) == [
+        "ring.pre_save > write_slot", "ring.save > write_slot"]
+    assert kernels.count("ring.pre_save > write_slot") == 5
+    assert {"ring.pre_save > digest", "ring.save > digest"} <= set(
+        scopes.values())
 
 
 def test_the_tick_over_a_mesh_of_four_chips_holds_none_either(chip):
@@ -340,6 +399,7 @@ def _run_particles(min_bytes, monkeypatch, seed, ticks=36):
     )
     relaid = default_registry().value("ggrs_executor_ring_relaid_bytes")
     direct = default_registry().value("ggrs_executor_ring_inplace_bytes")
+    digested = default_registry().value("ggrs_executor_digest_at_write_bytes")
     pool.warmup(np.zeros((2,), np.uint8))
     loads = default_registry().value("ggrs_executor_rollback_loads_total")
     _drive(sessions, schedules, pool.run, ticks)
@@ -358,7 +418,8 @@ def _run_particles(min_bytes, monkeypatch, seed, ticks=36):
         for k, leaf in pool._carry["ring"]["states"].items()
     }
     return {
-        "relaid": relaid, "in place": direct, "loads": loads, "frames": frames, "live": live,
+        "relaid": relaid, "in place": direct, "digested at the write": digested,
+        "loads": loads, "frames": frames, "live": live,
         "one": one, "saved": saved, "ring": ring, "held": held,
     }
 
@@ -376,6 +437,9 @@ def test_an_executor_above_and_below_the_rule_gives_equal_values(
     wide = 6 * 10 * (3 + 4 + 3 + 2 + 1) * 256 * 4
     assert above["relaid"] == wide
     assert above["in place"] == wide  # ttl too, held [B, R, 1, N]
+    # the same leaves of one batch's states: digested by the write kernels
+    assert above["digested at the write"] == wide // 10
+    assert below["digested at the write"] == 0
     assert above["ring"]["states"]["ttl"].shape == (6, 10, 1, 256)
     assert below["ring"]["states"]["ttl"].shape == (6, 10, 256)
     assert above["held"]["rotation"] == ((4, 128),)
@@ -616,3 +680,203 @@ def test_one_slot_written_twice_in_a_tick_holds_the_second_state(rest):
 )
 def test_the_kernel_says_which_leaves_it_takes(shape, takes):
     assert writes_slot_in_place(shape, 4) is takes
+
+
+# ---------------------------------------------------------------------------
+# (f) the digest taken where the slot is written is checksum_device's
+# ---------------------------------------------------------------------------
+
+# the wide leaves of a particle state, by what stands before the slot axis
+_DIGEST_LEAVES = {"K=4": (4,), "K=3": (3,), "K=2": (2,), "unit axis": ()}
+# 10,000 words are 78 tiles of 128 lanes and 16 words of a 79th
+_DIGEST_WIDTHS = {"N=10000": 10000, "N=256": 256}
+_DIGEST_SESSIONS = 29
+_SAVERS = {
+    "one saver": [11],
+    "every session": list(range(_DIGEST_SESSIONS)),
+    "a scattered 7%": [4, 19],
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _digest_programs(wide, slots):
+    """The two programs of ``_digested_at_the_write`` for one set of wide
+    leaves (``wide``: sorted ``(name, shape)`` pairs), compiled once for all
+    the predicates they are run with."""
+    from ggrs_tpu.ops.checksum import checksum_device
+
+    dring = DeviceStateRing(slots)
+    in_place = {"first": False, **{k: True for k, _ in wide}, "zlast": False}
+
+    def at_the_write(ring, frames, state, pred):
+        return dring.save_where_batch(
+            ring, frames, state, None, pred, in_place, interpret=True)
+
+    def plain(ring, frames, state, pred):
+        digests = jax.vmap(checksum_device)(state)
+        return jax.vmap(dring.save_where)(
+            ring, frames, state, digests, pred), digests
+
+    return jax.jit(at_the_write), jax.jit(plain)
+
+
+def _digested_at_the_write(rng, wide, savers, slots=3):
+    """One write of a state ``{first, *wide, zlast}`` (``wide``: name ->
+    shape of one session's leaf; two small leaves the select writes stand
+    around them, so no wide leaf starts at word 0 and the salt has seven
+    kinds of leaf to mix) into a ring that holds other digests: what
+    ``save_where_batch`` leaves when it takes the digest itself, and what the
+    select leaves when handed ``jax.vmap(checksum_device)``'s."""
+    sessions = _DIGEST_SESSIONS
+
+    def words(*shape):
+        return rng.integers(-2**31, 2**31 - 1, shape, dtype=np.int32)
+
+    shapes = {"first": (2, 2), **wide, "zlast": (3,)}
+    state = {k: words(sessions, *shape) for k, shape in shapes.items()}
+    plain = {
+        "states": {k: words(sessions, slots, *shape)
+                   for k, shape in shapes.items()},
+        "checksums": rng.integers(
+            0, 2**32 - 1, (sessions, slots, 4), dtype=np.uint32),
+        "frames": words(sessions, slots),
+    }
+    # as the pool holds it: a leaf of one state dimension with a unit axis
+    held = {**plain, "states": {
+        k: v[:, :, None] if len(shapes[k]) == 1 and k in wide else v
+        for k, v in plain["states"].items()}}
+    for k in wide:
+        assert writes_slot_in_place(held["states"][k].shape, 4)
+    frames = ((7 * np.arange(sessions) + 3) % 23).astype(np.int32)
+    frames[19] = -1  # an idle row: saves nothing whatever its predicate says
+    pred = np.isin(np.arange(sessions), savers)
+    at_the_write, by_select = _digest_programs(
+        tuple(sorted(wide.items())), slots)
+    got = at_the_write(held, frames, state, pred)
+    want, digests = by_select(plain, frames, state, pred)
+    return got, want, plain, np.asarray(digests), frames, pred
+
+
+@pytest.mark.parametrize("savers", sorted(_SAVERS))
+@pytest.mark.parametrize("width", sorted(_DIGEST_WIDTHS))
+@pytest.mark.parametrize("leaf", sorted(_DIGEST_LEAVES))
+def test_the_digest_at_the_write_is_checksum_device_s(leaf, width, savers):
+    rng = np.random.default_rng(
+        sum(map(ord, leaf + width + savers)))
+    shape = _DIGEST_LEAVES[leaf] + (_DIGEST_WIDTHS[width],)
+    got, want, before, digests, frames, pred = _digested_at_the_write(
+        rng, {"wide": shape}, _SAVERS[savers])
+    np.testing.assert_array_equal(
+        np.asarray(got["checksums"]), np.asarray(want["checksums"]))
+    # row by row: a saver's slot holds its state's digest, every other row
+    # of every session the digest the ring held
+    kept = np.asarray(got["checksums"])
+    wrote = 0
+    for b in range(_DIGEST_SESSIONS):
+        for r in range(kept.shape[1]):
+            hit = bool(pred[b]) and frames[b] >= 0 and frames[b] % 3 == r
+            wrote += hit
+            np.testing.assert_array_equal(
+                kept[b, r], digests[b] if hit else before["checksums"][b, r])
+    assert wrote == len(set(_SAVERS[savers]) - {19}) > 0
+    for k, leaf_want in want["states"].items():
+        np.testing.assert_array_equal(
+            np.asarray(got["states"][k]).reshape(leaf_want.shape),
+            np.asarray(leaf_want))
+
+
+@pytest.mark.parametrize("width", sorted(_DIGEST_WIDTHS))
+def test_the_partial_sums_of_five_kernels_and_two_small_leaves_add_up(width):
+    """A whole particle state: five leaves digested by the kernels that write
+    them, each from its own word offset, two by ``lane_sums`` in XLA."""
+    n = _DIGEST_WIDTHS[width]
+    wide = {"rotation": (4, n), "scale": (3, n), "translation": (3, n),
+            "ttl": (n,), "velocity": (2, n)}
+    got, want, _before, _digests, _frames, _pred = _digested_at_the_write(
+        np.random.default_rng(n), wide, _SAVERS["a scattered 7%"] + [0, 28])
+    np.testing.assert_array_equal(
+        np.asarray(got["checksums"]), np.asarray(want["checksums"]))
+    assert (np.asarray(got["checksums"]) != 0).any()
+
+
+def test_one_slot_digested_twice_in_a_tick_holds_the_second_digest():
+    from ggrs_tpu.ops.checksum import checksum_device
+
+    rng = np.random.default_rng(77)
+    dring, ring, first, _ = _ring_and_state(rng, 4, (3, _WIDE))
+    second = jax.tree_util.tree_map(lambda l: l ^ 5, first)
+    frames = np.asarray([0, 20, 7, -1], np.int32)
+    twice = np.asarray([True, True, False, True])
+    once = np.ones((4,), bool)
+    in_place = {"wide": True, "narrow": False}
+
+    def run(ring):
+        ring = dring.save_where_batch(
+            ring, frames, first, None, once, in_place, True)
+        return dring.save_where_batch(
+            ring, frames, second, None, twice, in_place, True)
+
+    kept = np.asarray(jax.jit(run)(ring)["checksums"])
+    of_first, of_second = (
+        np.asarray(jax.vmap(checksum_device)(s)) for s in (first, second))
+    np.testing.assert_array_equal(kept[0, 0], of_second[0])
+    np.testing.assert_array_equal(kept[1, 0], of_second[1])
+    np.testing.assert_array_equal(kept[2, 7], of_first[2])
+    np.testing.assert_array_equal(kept[3], ring["checksums"][3])
+    assert (of_first != of_second).all()
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, those inside the branches
+    of its conditionals and the bodies of its loops too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found.extend(_pallas_calls(inner))
+    return found
+
+
+@pytest.mark.parametrize("with_checksums", [True, False])
+def test_a_pool_that_keeps_no_digests_runs_none_in_the_kernel(
+        with_checksums, monkeypatch):
+    monkeypatch.setattr(session_pool, "_RELAY_MIN_BYTES", 1)
+    sessions, schedules = _make_matches(2, seed=9)
+    game = ParticleWorld(2, 256, 8, 16)
+    pool = BatchedRequestExecutor(
+        game.advance, game.init_state(), _to_arr, batch_size=len(sessions),
+        ring_length=10, max_burst=9, with_checksums=with_checksums,
+    )
+    value = default_registry().value
+    state_wide = len(sessions) * (3 + 4 + 3 + 2 + 1) * 256 * 4
+    assert value("ggrs_executor_ring_inplace_bytes") == 10 * state_wide
+    assert value("ggrs_executor_digest_at_write_bytes") == (
+        state_wide if with_checksums else 0)
+    pool.warmup(np.zeros((2,), np.uint8))
+    desc = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), pool._blank_desc())
+    kernels = _pallas_calls(
+        jax.make_jaxpr(pool._tick.__wrapped__)(pool._carry, desc).jaxpr)
+    # five wide leaves at each of the three writes; the second result is
+    # the savers' lane sums
+    assert len(kernels) == 15
+    assert {len(k.outvars) for k in kernels} == {2 if with_checksums else 1}
+    _drive(sessions, schedules, pool.run, 14)
+    digests = np.asarray(pool._carry["ring"]["checksums"])
+    assert digests.any() == with_checksums
+    if with_checksums:
+        from ggrs_tpu.ops.checksum import checksum_device, checksum_to_u128
+
+        frame = sessions[0].current_frame - 1
+        assert pool.ring_checksum(0, frame) == checksum_to_u128(
+            checksum_device(pool.ring_state(0, frame)))
+
+
+def test_with_no_leaf_in_place_the_digest_at_the_write_is_the_plain_one():
+    got, want, *_ = _digested_at_the_write(
+        np.random.default_rng(3), {}, _SAVERS["a scattered 7%"])
+    _assert_trees_equal(got, want)
