@@ -128,30 +128,13 @@ def check(cond: Any, msg: str) -> None:
         raise SmokeFailure(msg)
 
 
-class CompileMeter:
-    """Counts what JAX compiles, from its own monitoring events: seconds
-    spent tracing + lowering + in the backend compiler (or fetching from the
-    persistent cache), backend compile requests, and persistent-cache hits."""
-
-    def __init__(self) -> None:
-        self.seconds = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, duration: float, **_: Any) -> None:
-        if event.startswith("/jax/core/compile/"):
-            self.seconds += duration
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.compiles += 1
-
-    def _event(self, event: str, **_: Any) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def snapshot(self) -> Tuple[float, int, int]:
-        return self.seconds, self.compiles, self.cache_hits
+def _compiles() -> int:
+    """Backend compile requests of this process so far (one served from the
+    persistent cache counts too), as the program counts them itself
+    (``ggrs_tpu/parallel/session_pool.py``, DESIGN.md §14)."""
+    return int(
+        default_registry().value("ggrs_process_backend_compiles_total") or 0
+    )
 
 
 def _equal_trees(a: Any, b: Any) -> bool:
@@ -245,7 +228,7 @@ def _burst_counts() -> Tuple[float, int, int]:
 
 
 def leg_pool(size: Dict[str, int], seed: int, chips: int,
-             meter: CompileMeter, platform: str) -> Dict[str, Any]:
+             platform: str) -> Dict[str, Any]:
     matches, ticks = size["matches"], size["ticks"]
     sessions = 2 * matches
     game = BoxGame(2)
@@ -273,7 +256,7 @@ def leg_pool(size: Dict[str, int], seed: int, chips: int,
     hold_from = ticks - 3 * MAX_PREDICTION
     loads0, total0, depth1_0 = _burst_counts()
     exchange0 = _exchange_counts()
-    compiles0 = meter.compiles
+    compiles0 = _compiles()
     t0 = time.perf_counter()
     for i in range(ticks):
         clock[0] = (i * 1000) // 60
@@ -285,7 +268,7 @@ def leg_pool(size: Dict[str, int], seed: int, chips: int,
         net.tick()
     hosted.block_until_ready()
     run_s = time.perf_counter() - t0
-    compiled_in_ticks = meter.compiles - compiles0
+    compiled_in_ticks = _compiles() - compiles0
     loads1, total1, depth1_1 = _burst_counts()
 
     check(compiled_in_ticks == 0,
@@ -609,7 +592,7 @@ def leg_pallas(size: Dict[str, int], seed: int,
     return facts
 
 
-def leg_udp(size: Dict[str, int], seed: int, meter: CompileMeter) -> Dict[str, Any]:
+def leg_udp(size: Dict[str, int], seed: int) -> Dict[str, Any]:
     """The served transport: hosted peers on the kernel-batched datapath
     over real loopback UDP, their opponents plain per-session ``P2PSession``s
     fulfilled with ``advance_np`` on the host (the reference tier)."""
@@ -652,7 +635,7 @@ def leg_udp(size: Dict[str, int], seed: int, meter: CompileMeter) -> Dict[str, A
                 )
 
     hold_from = ticks - 3 * MAX_PREDICTION
-    compiles0 = meter.compiles
+    compiles0 = _compiles()
     t0 = time.perf_counter()
     for i in range(ticks):
         clock[0] = (i * 1000) // 60
@@ -664,7 +647,7 @@ def leg_udp(size: Dict[str, int], seed: int, meter: CompileMeter) -> Dict[str, A
     hosted.block_until_ready()
     run_s = time.perf_counter() - t0
 
-    check(meter.compiles == compiles0, "udp: a compilation inside the ticks")
+    check(_compiles() == compiles0, "udp: a compilation inside the ticks")
     check(host.crossings == ticks,
           f"udp: {host.crossings} crossings over {ticks} ticks")
     io = host.io_stats()
@@ -763,23 +746,20 @@ def run_legs(args: argparse.Namespace, legs: List[str], rehearsal: bool,
           f"{cache_dir or 'none (CPU backend, none placed from outside)'} "
           f"({cache_before} entries before)", flush=True)
 
-    meter = CompileMeter()
+    compiles_at_start = _compiles()
     report: Dict[str, Any] = {}
 
     def run_leg(name: str, fn: Callable[[], Dict[str, Any]],
-                carried: Tuple[float, int, int] = (0.0, 0, 0)) -> None:
-        s0 = meter.snapshot()
+                carried: int = 0) -> None:
+        c0 = _compiles()
         t0 = time.perf_counter()
         facts = fn()  # a failed check raises; nothing catches it
         wall = time.perf_counter() - t0
-        s1 = meter.snapshot()
         entry = {
             "verdict": "pass",
-            "compile_s": round(s1[0] - s0[0] + carried[0], 3),
             "run_s": round(facts.pop("run_s"), 3),
             "wall_s": round(wall, 3),
-            "compiles": s1[1] - s0[1] + carried[1],
-            "cache_hits": s1[2] - s0[2] + carried[2],
+            "compiles": _compiles() - c0 + carried,
             **facts,
         }
         report[name] = entry
@@ -787,17 +767,15 @@ def run_legs(args: argparse.Namespace, legs: List[str], rehearsal: bool,
 
     # the fence leg's pre-read sample has to be the first device work
     if "fence" in legs:
-        s0 = meter.snapshot()
+        c0 = _compiles()
         fence_pre = fence_sample(size, args.seed)
-        fence_pre_cost = tuple(
-            b - a for a, b in zip(s0, meter.snapshot())
-        )
+        fence_pre_compiles = _compiles() - c0
     if "pool" in legs:
         run_leg("pool", lambda: leg_pool(
-            size, args.seed, args.chips, meter, str(device["platform"])))
+            size, args.seed, args.chips, str(device["platform"])))
     if "fence" in legs:
         run_leg("fence", lambda: leg_fence(
-            size, args.seed, fence_pre, peak_tflops), fence_pre_cost)
+            size, args.seed, fence_pre, peak_tflops), fence_pre_compiles)
     if "synctest" in legs:
         run_leg("synctest", lambda: leg_synctest(size, args.seed))
     if "games" in legs:
@@ -805,13 +783,13 @@ def run_legs(args: argparse.Namespace, legs: List[str], rehearsal: bool,
     if "pallas" in legs:
         run_leg("pallas", lambda: leg_pallas(size, args.seed, rehearsal))
     if "udp" in legs:
-        run_leg("udp", lambda: leg_udp(size, args.seed, meter))
+        run_leg("udp", lambda: leg_udp(size, args.seed))
 
     cache_after = cache_entry_count(cache_dir)
+    requests = _compiles() - compiles_at_start
     print(f"{tag}compile cache: {cache_after} entries after "
-          f"(+{cache_after - cache_before}); compile seconds total "
-          f"{meter.seconds:.1f}, persistent-cache hits {meter.cache_hits} of "
-          f"{meter.compiles} compile requests", flush=True)
+          f"(+{cache_after - cache_before}) of {requests} compile requests",
+          flush=True)
 
     complete = not rehearsal and legs == list(LEGS)
     summary = {
@@ -825,9 +803,7 @@ def run_legs(args: argparse.Namespace, legs: List[str], rehearsal: bool,
             "cache_dir": cache_dir,
             "entries_before": cache_before,
             "entries_after": cache_after,
-            "seconds": round(meter.seconds, 3),
-            "requests": meter.compiles,
-            "persistent_cache_hits": meter.cache_hits,
+            "requests": requests,
         },
     }
     if rehearsal:

@@ -136,6 +136,34 @@ _OBS_BURST_DEPTH = default_registry().histogram(
     "deepest per-session advance burst (replay depth) per dispatched tick",
     buckets=(1, 2, 4, 8, 16, 32),
 )
+_OBS_BACKEND_COMPILES = default_registry().counter(
+    "ggrs_process_backend_compiles_total",
+    "programs this process asked the backend compiler for (one fetched from "
+    "the persistent cache counts too): a served pool compiles in warmup and "
+    "never after",
+)
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _count_backend_compile(event: str, duration: float, **_: Any) -> None:
+    """The program counts its own compiles (DESIGN.md §14): JAX reports each
+    backend compile request on the thread that made it, so where the tracer
+    records, the instant names the span and the tick the compile fell in."""
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    _OBS_BACKEND_COMPILES.inc()
+    tracer = default_tracer()
+    if tracer.enabled:
+        stack = tracer._thread_stack()
+        parent, tick = stack[-1] if stack else (None, None)
+        tracer.add_instant(
+            "device.compile", secs=duration, parent=parent, tick=tick
+        )
+
+
+# once a process: the module is imported once, and the listener serves
+# every executor and every other program the process compiles
+jax.monitoring.register_event_duration_secs_listener(_count_backend_compile)
 
 
 def _tree_where(pred: jax.Array, a: Any, b: Any) -> Any:
@@ -1007,40 +1035,53 @@ class BatchedRequestExecutor:
             _OBS_EMPTY_TICKS.inc()
             return
         try:
-            with self.tracer.span("device.fill") as fill:
-                desc = self._reset_desc()
-                if vector and rows.size:
-                    frames = plan.quiet_frames
-                    desc["pre_save"][rows] = True
-                    desc["pre_frame"][rows] = frames
-                    desc["n_adv"][rows] = 1
-                    statuses, blobs = plan.gather_quiet()
-                    desc["inputs"][rows, 0] = self._raw_inputs(
-                        blobs, statuses
-                    )
+            tracer = self.tracer
+            with tracer.span("device.fill") as fill:
+                # the fill's two halves, each a span (every session is of
+                # one kind a tick and touches its own row only, so their
+                # order is free): first the descriptor arrays,
+                with tracer.span("device.descriptors"):
+                    desc = self._reset_desc()
+                    n_quiet = rows.size if vector else 0
+                    if n_quiet:
+                        frames = plan.quiet_frames
+                        desc["pre_save"][rows] = True
+                        desc["pre_frame"][rows] = frames
+                        desc["n_adv"][rows] = 1
+                        statuses, blobs = plan.gather_quiet()
+                        desc["inputs"][rows, 0] = self._raw_inputs(
+                            blobs, statuses
+                        )
+                    else:
+                        # no bulk converter / non-uniform pool: quiet slots
+                        # materialize like any other (reference semantics)
+                        eager.extend(rows.tolist())
+                    for (b, lf, n_adv, trailing, adv_off,
+                         adv_stride) in plan.resim_rows:
+                        if vector:
+                            self._fill_resim(plan, desc, b, lf, n_adv,
+                                             trailing, adv_off, adv_stride)
+                        else:
+                            eager.append(b)
+                    for b in eager:
+                        reqs = plan[b]
+                        if reqs:
+                            self._parse(b, reqs, desc)
+                # then the one Python call a quiet or save-only row
+                # (ROADMAP A1 (iii))
+                with tracer.span(
+                    "device.fulfill",
+                    rows=n_quiet + len(plan.save_only_rows),
+                ):
                     # _fulfill_fast writes the _host_frames shadow too —
                     # one writer for the ring tags
-                    for b, f in zip(rows.tolist(), frames.tolist()):
+                    if n_quiet:
+                        for b, f in zip(rows.tolist(), frames.tolist()):
+                            self._fulfill_fast(plan.saved_states(b), b, f)
+                    for b, f in plan.save_only_rows:
+                        desc["pre_save"][b] = True
+                        desc["pre_frame"][b] = f
                         self._fulfill_fast(plan.saved_states(b), b, f)
-                elif rows.size:
-                    # no bulk converter / non-uniform pool: quiet slots
-                    # materialize like any other (reference semantics)
-                    eager.extend(rows.tolist())
-                for b, f in plan.save_only_rows:
-                    desc["pre_save"][b] = True
-                    desc["pre_frame"][b] = f
-                    self._fulfill_fast(plan.saved_states(b), b, f)
-                for (b, lf, n_adv, trailing, adv_off,
-                     adv_stride) in plan.resim_rows:
-                    if vector:
-                        self._fill_resim(plan, desc, b, lf, n_adv,
-                                         trailing, adv_off, adv_stride)
-                    else:
-                        eager.append(b)
-                for b in eager:
-                    reqs = plan[b]
-                    if reqs:
-                        self._parse(b, reqs, desc)
                 self._count_dispatch(desc, fill)
             self._launch(desc)
         except BaseException as e:  # incl. KeyboardInterrupt mid-fill
@@ -1068,10 +1109,17 @@ class BatchedRequestExecutor:
         so one dispatch is ``len(desc)`` transfers a device (DESIGN.md §3;
         benchmark: launch_transfers_per_dispatch)."""
         shards = self._shards
-        with self.tracer.span(
-            "device.launch",
-            shards=shards, transfers=len(desc) * shards, dispatches=1,
-        ):
+        tracer = self.tracer
+        args = dict(shards=shards, transfers=len(desc) * shards, dispatches=1)
+        if tracer.enabled:
+            # who paces, from inside: had the device finished the previous
+            # dispatch (whose result the carry is) when the host came with
+            # the next?  Asked of one small leaf, never waited for, and no
+            # reference outlives the call: the carry is donated below
+            args["device_ready"] = int(
+                self._carry["ring"]["frames"].is_ready()
+            )
+        with tracer.span("device.launch", **args):
             self._carry = self._tick(self._carry, desc)
 
     def run(self, request_lists: Sequence[List[GgrsRequest]]) -> None:
@@ -1119,11 +1167,15 @@ class BatchedRequestExecutor:
             landed += 1
         if wanted is None and not landed:
             return  # a read in flight, nothing to do about it this tick
-        with self.tracer.span("device.checksum_fetch") as span:
+        tracer = self.tracer
+        with tracer.span("device.checksum_fetch") as span:
             rows = 0
             for _ in range(landed):
                 pool, asked, slots, frames, out = fetches.popleft()
-                pool.deliver_checksums(slots, frames, np.asarray(out)[slots])
+                with tracer.span("checksum.deliver", rows=len(slots)):
+                    pool.deliver_checksums(
+                        slots, frames, np.asarray(out)[slots]
+                    )
                 rows += len(slots)
                 self.checksum_lag_ticks = tick - asked
                 self.checksum_lag_ticks_max = max(
@@ -1131,25 +1183,28 @@ class BatchedRequestExecutor:
                 )
             if wanted is not None:
                 slots, frames = wanted
-                ring_slots = np.zeros((self.batch_size,), np.int32)
-                ring_slots[slots] = frames % self.ring_length
-                # a session that reports one frame a tick can fall behind
-                # its ring when a burst of confirmations makes several
-                # interval frames due at once (intervals under the window;
-                # the Python session fails its assert there): such a frame
-                # is never reported, the next ones are
-                kept = self._host_frames[slots, ring_slots[slots]] == frames
-                if not kept.all():
-                    _OBS_DIGESTS_MISSED.inc(int((~kept).sum()))
-                    slots, frames = slots[kept], frames[kept]
-                if len(slots):
-                    out = self._fetch_digests(
-                        self._carry["ring"]["checksums"], ring_slots
+                with tracer.span("checksum.ask", rows=len(slots)):
+                    ring_slots = np.zeros((self.batch_size,), np.int32)
+                    ring_slots[slots] = frames % self.ring_length
+                    # a session that reports one frame a tick can fall
+                    # behind its ring when a burst of confirmations makes
+                    # several interval frames due at once (intervals under
+                    # the window; the Python session fails its assert
+                    # there): such a frame is never reported, the next are
+                    kept = (
+                        self._host_frames[slots, ring_slots[slots]] == frames
                     )
-                    out.copy_to_host_async()
-                    fetches.append(
-                        (request_lists.pool, tick, slots, frames, out)
-                    )
+                    if not kept.all():
+                        _OBS_DIGESTS_MISSED.inc(int((~kept).sum()))
+                        slots, frames = slots[kept], frames[kept]
+                    if len(slots):
+                        out = self._fetch_digests(
+                            self._carry["ring"]["checksums"], ring_slots
+                        )
+                        out.copy_to_host_async()
+                        fetches.append(
+                            (request_lists.pool, tick, slots, frames, out)
+                        )
             span.set(
                 wanted=0 if wanted is None else len(wanted[0]),
                 landed=rows, lag_ticks=self.checksum_lag_ticks,

@@ -53,6 +53,8 @@ TREE = {  # span -> parent, as DESIGN.md §14 and PERF.md §3 table them
     "pool.supervise": "pool.tick",
     "device.dispatch": "hosted.tick",
     "device.fill": "device.dispatch",
+    "device.descriptors": "device.fill",
+    "device.fulfill": "device.fill",
     "device.launch": "device.dispatch",
     "device.fence": None,
 }
@@ -98,24 +100,29 @@ def test_the_default_tracer_is_what_pools_use_and_is_off(tracer):
 
 def test_tracing_does_not_choose_the_path(tracer):
     """Same seed, tracer on against off: the descriptor plane decodes every
-    tick, one crossing a tick, and the device state is bit-identical."""
+    tick, one crossing a tick, and the device state and every datagram on
+    the wire are bit-identical."""
     legs = {}
     for on in (False, True):
         tracer.switch(on)
         pool, inputs = build(4)
+        wire, send = [], pool.net._send
+        pool.net._send = lambda src, dst, payload: (
+            wire.append((src, dst, bytes(payload))), send(src, dst, payload))
         drive(pool, inputs, 90)
         host = pool.host
         assert host.plan_ticks == host.crossings == pool.ticks == 90
         assert host._trace_native is on
         legs[on] = (jax.device_get(pool.executor.live_states),
                     host.fast_slot_ticks, host.desc_slow_slots,
-                    pool.executor._host_frames.copy())
+                    pool.executor._host_frames.copy(), wire)
     tracer.switch(False)
     for a, b in zip(jax.tree_util.tree_leaves(legs[False][0]),
                     jax.tree_util.tree_leaves(legs[True][0])):
         np.testing.assert_array_equal(a, b)
     assert legs[False][1:3] == legs[True][1:3]
     np.testing.assert_array_equal(legs[False][3], legs[True][3])
+    assert legs[False][4] == legs[True][4] and len(legs[True][4]) > 90
 
 
 def test_span_tree_of_a_tick(tracer):
