@@ -49,8 +49,10 @@ writes it takes its digest from the block it holds (``ops/ring.py``
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 from collections import deque
+from collections.abc import Mapping
 from typing import (
     Any,
     Callable,
@@ -411,12 +413,14 @@ def tick_program(
     # bit); where the select writes every leaf, checksum_device as ever
     digest_at_write = any(jax.tree_util.tree_leaves(in_place))
 
-    def tick(carry: Dict[str, Any], desc: Dict[str, Any]) -> Dict[str, Any]:
+    def tick(carry: Dict[str, Any], desc: Descriptor) -> Dict[str, Any]:
         """One tick of every session: the per-session pieces (the load, the
         game's step) under ``vmap``, the ring write and the digest of what
         it saves at batch level (``save_where_batch``).  Under ``shard_map``
         the batch is a shard's own: no collective."""
         live, ring = carry["live"], carry["ring"]
+        # the one buffer of the shard's rows, unpacked into its ten fields
+        desc = desc.unpack()
         sessions = desc["n_adv"].shape[0]
         # what the whole batch asks:
         n_steps = jnp.max(desc["n_adv"])  # its deepest plan
@@ -529,24 +533,93 @@ def tick_program(
     )
 
 
+class Descriptor(Mapping):
+    """One tick's descriptor, the tick program's second argument: ONE host
+    buffer ``packed``, ``u8[B, W]``, a row of W bytes a session, and its ten
+    fields as NumPy views into it under their names (``desc["n_adv"]``,
+    ``desc["inputs"]``), so a fill writes the buffer the call sends.
+
+    A pytree of one leaf: ``row``, the row's NumPy structured dtype, is
+    static, so a dispatch sends one buffer (over a mesh, one block of rows
+    a device) and the program gets the ten fields back from ``unpack``."""
+
+    __slots__ = ("packed", "row", "_fields")
+
+    def __init__(self, packed: Any, row: np.dtype) -> None:
+        self.packed = packed
+        self.row = row
+        self._fields: Optional[Dict[str, np.ndarray]] = None
+
+    def __getitem__(self, name: str) -> np.ndarray:
+        if self._fields is None:
+            records = self.packed.view(self.row)[:, 0]  # [B, 1] -> [B]
+            self._fields = {n: records[n] for n in self.row.names}
+        return self._fields[name]
+
+    def __iter__(self):
+        return iter(self.row.names)
+
+    def __len__(self) -> int:
+        return len(self.row.names)
+
+    def unpack(self) -> Dict[str, jax.Array]:
+        """The device side: the ten fields, in the shapes and dtypes the
+        views have, each a static slice of the rows (bitcast where wider
+        than a byte, ``!= 0`` for a mask)."""
+        packed = self.packed
+        b = packed.shape[0]
+        fields = {}
+        for name in self.row.names:
+            field, offset = self.row.fields[name][:2]
+            base = field.base
+            raw = packed[:, offset : offset + field.itemsize]
+            if base == np.bool_:
+                got = raw != 0
+            else:
+                got = jax.lax.bitcast_convert_type(
+                    raw.reshape(b, -1, base.itemsize), base
+                )
+            fields[name] = got.reshape((b,) + field.shape)
+        return fields
+
+
+jax.tree_util.register_pytree_node(
+    Descriptor,
+    lambda d: ((d.packed,), d.row),
+    lambda row, leaves: Descriptor(leaves[0], row),
+)
+
+
+@functools.lru_cache(maxsize=None)
+def _desc_row(
+    max_burst: int, input_shape: Tuple[int, ...], input_dtype: np.dtype
+) -> np.dtype:
+    """A session's descriptor row: the int32 fields first, then the inputs,
+    then the masks, each at its natural alignment."""
+    D, i32 = max_burst, np.int32
+    return np.dtype(
+        [
+            ("pre_frame", i32),
+            ("load_frame", i32),
+            ("postload_frame", i32),
+            ("n_adv", i32),
+            ("save_frame", i32, (D,)),
+            ("inputs", input_dtype, (D,) + input_shape),
+            ("pre_save", np.bool_),
+            ("do_load", np.bool_),
+            ("postload_save", np.bool_),
+            ("save_mask", np.bool_, (D,)),
+        ],
+        align=True,
+    )
+
+
 def blank_desc(
     batch_size: int, max_burst: int, input_shape: Tuple[int, ...], input_dtype
-) -> Dict[str, np.ndarray]:
-    """One tick's descriptor with every row idle: the tick program's second
-    argument."""
-    B, D = batch_size, max_burst
-    return {
-        "pre_save": np.zeros((B,), bool),
-        "pre_frame": np.zeros((B,), np.int32),
-        "do_load": np.zeros((B,), bool),
-        "load_frame": np.zeros((B,), np.int32),
-        "postload_save": np.zeros((B,), bool),
-        "postload_frame": np.zeros((B,), np.int32),
-        "n_adv": np.zeros((B,), np.int32),
-        "inputs": np.zeros((B, D) + tuple(input_shape), input_dtype),
-        "save_mask": np.zeros((B, D), bool),
-        "save_frame": np.zeros((B, D), np.int32),
-    }
+) -> Descriptor:
+    """One tick's descriptor with every row idle: one ``np.zeros``."""
+    row = _desc_row(max_burst, tuple(input_shape), np.dtype(input_dtype))
+    return Descriptor(np.zeros((batch_size, row.itemsize), np.uint8), row)
 
 
 class _BatchSlotRef:
@@ -609,8 +682,8 @@ class BatchedRequestExecutor:
                         divide ``batch_size``) so one pool spans chips — sessions
                         are independent, so the tick program needs no
                         collectives and scales linearly over ICI-attached
-                        devices.  Descriptor arrays are built host-side and
-                        split per-shard by ``shard_map``.
+                        devices.  The descriptor, one host buffer a tick,
+                        is split into a block of rows a device by the call.
     ``raw_inputs_to_array``  optional bulk twin of ``inputs_to_array`` for
                         the descriptor plane (DESIGN.md §21): called as
                         ``raw(blobs, statuses)`` with the ENCODED input
@@ -769,7 +842,7 @@ class BatchedRequestExecutor:
     # ------------------------------------------------------------------
 
     def _parse(
-        self, index: int, requests: List[GgrsRequest], desc: Dict[str, np.ndarray]
+        self, index: int, requests: List[GgrsRequest], desc: Descriptor
     ) -> None:
         """Normalize one session's tick into the descriptor row ``index``,
         fulfilling its Save cells with lazy slot references.
@@ -882,7 +955,7 @@ class BatchedRequestExecutor:
                 f"{requests[i]!r}"
             )
 
-    def _blank_desc(self) -> Dict[str, np.ndarray]:
+    def _blank_desc(self) -> Descriptor:
         assert self._input_shape is not None, (
             "call warmup(example_inputs) before the first run()"
         )
@@ -927,11 +1000,11 @@ class BatchedRequestExecutor:
             )
         )
 
-    def _reset_desc(self) -> Dict[str, np.ndarray]:
-        """Fresh descriptor buffers for one tick.  NOT reused in place:
-        jax may alias host numpy buffers zero-copy (CPU backend) and the
-        dispatch is asynchronous, so mutating last tick's arrays while the
-        program may still read them corrupts the dispatch silently."""
+    def _reset_desc(self) -> Descriptor:
+        """A fresh descriptor buffer for one tick.  NOT reused in place:
+        jax may alias a host numpy buffer zero-copy (CPU backend) and the
+        dispatch is asynchronous, so mutating last tick's buffer while the
+        program may still read it corrupts the dispatch silently."""
         return self._blank_desc()
 
     def _fulfill_fast(self, cells, b: int, frame: Frame) -> None:
@@ -956,7 +1029,7 @@ class BatchedRequestExecutor:
             frame, ref, cs if self._with_checksums else None
         )
 
-    def _fill_resim(self, plan, desc: Dict[str, np.ndarray], b: int,
+    def _fill_resim(self, plan, desc: Descriptor, b: int,
                     lf: int, n_adv: int, trailing: bool, adv_off: int,
                     adv_stride: int) -> None:
         """One rollback-resim slot straight from its descriptor row:
@@ -1088,7 +1161,7 @@ class BatchedRequestExecutor:
             self._invalid = f"{type(e).__name__}: {e}"
             raise
 
-    def _count_dispatch(self, desc: Dict[str, Any], fill) -> None:
+    def _count_dispatch(self, desc: Descriptor, fill) -> None:
         """The filled descriptor's counts, on the registry and on the
         ``device.fill`` span that ends here."""
         loads = int(desc["do_load"].sum())
@@ -1101,16 +1174,16 @@ class BatchedRequestExecutor:
         # left out (benchmark: burst_steps_share)
         fill.set(loads=loads, max_burst=max_burst, burst_cap=self.max_burst)
 
-    def _launch(self, desc: Dict[str, Any]) -> None:
-        """The call of the tick program: transfer of the descriptors and
+    def _launch(self, desc: Descriptor) -> None:
+        """The call of the tick program: transfer of the descriptor and
         enqueue; in a closed loop the runtime's back-pressure too.  The
-        descriptors are host arrays of all sessions: over a mesh the call
-        splits each on the session axis and sends every device its block,
-        so one dispatch is ``len(desc)`` transfers a device (DESIGN.md §3;
-        benchmark: launch_transfers_per_dispatch)."""
+        descriptor is one host buffer of all sessions' rows: over a mesh the
+        call splits it on the session axis and sends every device its block,
+        so one dispatch is one transfer a device (DESIGN.md §3; benchmark:
+        launch_transfers_per_dispatch)."""
         shards = self._shards
         tracer = self.tracer
-        args = dict(shards=shards, transfers=len(desc) * shards, dispatches=1)
+        args = dict(shards=shards, transfers=shards, dispatches=1)
         if tracer.enabled:
             # who paces, from inside: had the device finished the previous
             # dispatch (whose result the carry is) when the host came with

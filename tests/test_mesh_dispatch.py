@@ -146,7 +146,7 @@ def test_a_pool_over_four_devices_equals_one_device_and_the_reference(
             session.tick(desc, b)
         for pool in (across, one):
             # a fresh copy each: the call may alias a host array
-            pool._launch({k: v.copy() for k, v in desc.items()})
+            pool._launch(jax.tree_util.tree_map(np.copy, desc))
 
     got, want = jax.device_get(across._carry), jax.device_get(one._carry)
     flat_got, tree = jax.tree_util.tree_flatten(got)
@@ -228,15 +228,15 @@ def test_the_launch_span_counts_what_the_dispatch_sends(shards, ring, monkeypatc
     ring.switch(False)
     launches = [e[6] for e in ring.events() if e[1] == "device.launch"]
     assert len(launches) == 6
-    arrays = len(descs[0])
-    assert arrays == 10
+    # ten fields, one buffer
+    assert len(descs[0]) == 10 and len(jax.tree_util.tree_leaves(descs[0])) == 1
     for args in launches:
         assert (args["shards"], args["dispatches"]) == (shards, 1)
-        assert args["transfers"] == arrays * shards
-    # over a mesh every descriptor array went out as one buffer a device,
-    # and nothing else did (one device: the call's C++ path sends each array
+        assert args["transfers"] == shards
+    # over a mesh the descriptor went out as one block a device, and
+    # nothing else did (one device: the call's C++ path sends the buffer
     # whole, where no Python can count)
-    assert sent == ([shards] * (6 * arrays) if shards > 1 else [])
+    assert sent == ([shards] * 6 if shards > 1 else [])
     # asleep, the tracer records nothing and the dispatch is the same
     pool._launch(descs[6])
     assert len([e for e in ring.events() if e[1] == "device.launch"]) == 6

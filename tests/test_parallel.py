@@ -217,7 +217,7 @@ class TestPooledTickOverTheMesh:
             for ex in (single, sharded):
                 # a copy each: the CPU backend may alias a host buffer
                 ex._carry = ex._tick(
-                    ex._carry, {k: v.copy() for k, v in desc.items()}
+                    ex._carry, jax.tree_util.tree_map(np.copy, desc)
                 )
         want, got = jax.device_get((single._carry, sharded._carry))
         for w, g in zip(jax.tree_util.tree_leaves(want),

@@ -273,6 +273,32 @@ class TestPoolTickProgramPins:
         # counter < bound, both carried or closed over: no literal 7
         assert not [v for v in compare.invars if hasattr(v, "val")], compare
 
+    @pytest.mark.parametrize("shards", [1, 4])
+    def test_the_tick_takes_one_descriptor_operand_beside_the_carry(
+        self, shards
+    ):
+        """A dispatch sends one descriptor buffer, ``u8[B, W]`` (PR 38: ten
+        arrays were 40 transfers on four chips): the program's operands are
+        the carry's leaves and that buffer, nothing else."""
+        if len(jax.devices()) < shards:
+            pytest.skip("needs the virtual CPU mesh of tests/conftest.py")
+        game = BoxGame(2)
+        ex = BatchedRequestExecutor(
+            game.advance, game.init_state(),
+            lambda inputs: np.zeros((2,), np.uint8),
+            batch_size=8, ring_length=10, max_burst=9,
+            mesh=make_mesh(shards) if shards > 1 else None,
+        )
+        ex.warmup(np.zeros((2,), np.uint8))
+        desc = ex._blank_desc()
+        tick = jax.make_jaxpr(ex._tick)(ex._carry, desc)
+        *carry, packed = tick.jaxpr.invars
+        assert len(carry) == len(jax.tree_util.tree_leaves(ex._carry))
+        # 4 int32 frames and counts, 9 save frames, 9 x 2 input bytes,
+        # 3 + 9 masks: 82 bytes, 84 at int32 alignment
+        assert packed.aval.shape == (8, 84) == desc.packed.shape
+        assert packed.aval.dtype == np.uint8
+
 
 # ----------------------------------------------------------------------
 # source pins: the package graph and the switch census, against DESIGN.md
