@@ -12,12 +12,21 @@ from __future__ import annotations
 from typing import Any, Dict, Optional
 
 
+COUNTED = ("advances", "saves", "loads")
+
+
 def plan_counts(plan: Any) -> Dict[str, int]:
-    """Advances, saves and loads one tick's ``RequestPlan`` asks for, read
-    from its public columns (``quiet_rows`` = [save, advance] each;
+    """Advances, saves and loads one tick's ``RequestPlan`` asks for.  A plan
+    that states its own tally (``counts``, a mapping of those three) is
+    counted by it, so that row kinds this rule does not know (a sparse
+    session's advance without a save) need no edit here.  Otherwise they are
+    read from its public columns (``quiet_rows`` = [save, advance] each;
     ``resim_rows`` = load, ``n_adv`` advances, a save after each but a
     trailing live one; ``save_only_rows`` = one save; ``eager_rows`` =
     materialized request lists, counted by type)."""
+    stated = getattr(plan, "counts", None)
+    if stated is not None:
+        return {k: int(stated[k]) for k in COUNTED}
     quiet = int(plan.quiet_rows.size) if plan.quiet_rows is not None else 0
     advances, saves, loads = quiet, quiet, 0
     for row in plan.resim_rows:

@@ -36,7 +36,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Any, Callable, Dict, List, Optional  # noqa: E402
+from typing import Any, Callable, Dict, List, Optional, Set, Tuple  # noqa: E402
 
 import numpy as np  # noqa: E402
 
@@ -63,6 +63,7 @@ from ggrs_tpu.utils.device import place_compile_cache, require_chip  # noqa: E40
 SPAN_PREFIX = "bench."
 TICK_PROGRAM = "jit_tick"
 RING_SAMPLES = 16
+SAVING = ("every_frame", "sparse")  # a configuration's "saving"; absent: the first
 
 
 def log(msg: str) -> None:
@@ -126,6 +127,9 @@ def load_cell(root: Path, workload: str) -> Dict[str, Any]:
     cell = cells[workload]
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     config = json.loads((root / entry["file"]).read_text())
+    if config.get("saving", SAVING[0]) not in SAVING:
+        raise SystemExit(f"{entry['file']}: saving must be one of {SAVING}, "
+                         f"not {config['saving']!r}")
     traffic = generator.load_traffic(
         root / "benchmark" / "traffic" / f"{cell['traffic']}.json"
     )
@@ -141,6 +145,12 @@ def load_cell(root: Path, workload: str) -> Dict[str, Any]:
             "metrics": metrics}
 
 
+def saves_sparsely(config: Dict[str, Any]) -> bool:
+    """GGRS's sparse saving (``SessionBuilder::with_sparse_saving_mode``):
+    a session saves only confirmed frames and rolls back to its last save."""
+    return config.get("saving") == "sparse"
+
+
 # ---------------------------------------------------------------------------
 # the system under test
 # ---------------------------------------------------------------------------
@@ -151,7 +161,8 @@ class Pool:
     each (one local player, the others remote) in one ``HostSessionPool``
     over one in-memory network and one shared virtual clock, fulfilled by one
     ``BatchedRequestExecutor``.  Session ``m * players + k`` is player ``k``
-    of match ``m``."""
+    of match ``m``.  Every session saves as the configuration's ``saving``
+    says."""
 
     def __init__(self, config: Dict[str, Any], traffic: Dict[str, Any],
                  matches: int, seed: int, chips: int = 1) -> None:
@@ -175,6 +186,8 @@ class Pool:
                     .with_max_prediction_window(int(config["max_prediction"]))
                     .with_input_delay(int(config["input_delay"]))
                 )
+                if saves_sparsely(config):
+                    builder = builder.with_sparse_saving_mode(True)
                 for j in range(players):
                     who = Local() if j == k else Remote(f"m{m}p{j}")
                     builder = builder.add_player(who, j)
@@ -411,22 +424,57 @@ def traced_slice(pool: Pool, inputs: Inputs, traffic: Dict[str, Any],
 
 
 def reference_states(config: Dict[str, Any], inputs: Inputs, matches: int,
-                     frames: int, keep: int):
-    """The reference replayed over every match's true inputs: its state after
-    ``f`` frames for the last ``keep`` values of ``f`` up to ``frames``, and
-    how often it saw what the family's ``witness`` counts (None: it has none)."""
+                     frames: int, keep: Set[int]):
+    """The reference replayed over every match's true inputs for ``frames``
+    frames: its state after ``f`` frames for each ``f`` in ``keep``, and how
+    often it saw what the family's ``witness`` counts (None: it has none)."""
     ref = importlib.import_module(f"benchmark.reference.{config['adapter']}")
     witness = getattr(ref, "witness", None)
     state = ref.init_state(config, matches)
-    kept = {0: state} if frames - keep < 0 else {}
+    kept = {0: state} if 0 in keep else {}
     seen = 0
     for f in range(frames):
         state = ref.advance(config, state, inputs.frame_row(f))
         if witness is not None:
             seen += witness(state)
-        if f + 1 > frames - keep:
+        if f + 1 in keep:
             kept[f + 1] = state
     return kept, (seen if witness is not None else None)
+
+
+def ring_frames(pool: Pool) -> np.ndarray:
+    """``[sessions, ring_length]``: the frame each ring slot holds, as the
+    device tags it (negative: never written).  The executor has no public
+    reader of the tags; ``ring_state`` and ``ring_checksum`` read the same
+    array to validate a slot."""
+    return np.asarray(jax.device_get(pool.executor._carry["ring"]["frames"]))
+
+
+def ring_draws(seed: int, sessions: int, frames: int, depth: int,
+               held: Optional[np.ndarray] = None) -> List[Tuple[int, int]]:
+    """The ring slots ``correct`` reads: ``RING_SAMPLES`` (session, frame)
+    pairs drawn from the seed.  A ring that saves every frame holds the last
+    ``depth`` frames, all confirmed by the hold, and a draw is a session and
+    one of them (a frame before the first is no draw).  Under sparse saving a
+    session saves only confirmed frames, so every frame its ring holds
+    (``held[session]``) is due, whatever its age: a draw is a session whose
+    ring holds a frame and one of those frames (a ring that holds none is
+    ``ring_behind_sessions``' to count)."""
+    rng = random.Random(seed)
+    draws: List[Tuple[int, int]] = []
+    if held is not None:
+        holding = [s for s in range(sessions) if (held[s] >= 0).any()]
+        for _ in range(RING_SAMPLES if holding else 0):
+            s = holding[rng.randrange(len(holding))]
+            mine = sorted(int(f) for f in held[s] if f >= 0)
+            draws.append((s, mine[rng.randrange(len(mine))]))
+        return draws
+    for _ in range(RING_SAMPLES):
+        s = rng.randrange(sessions)
+        f = frames - 1 - rng.randrange(depth)
+        if f >= 0:
+            draws.append((s, f))
+    return draws
 
 
 def wrong_sessions(live: Dict[str, Any], want: Dict[str, np.ndarray],
@@ -449,19 +497,21 @@ def compare(pool: Pool, config: Dict[str, Any], inputs: Inputs, seed: int,
             hold: int, witness_by_frame: Optional[int]) -> Dict[str, int]:
     """Every session's live state after the run, and a sample of ring slots
     drawn from the seed (the saved state and the digest the device keeps of
-    it), against the reference; exact, so each limit is 0."""
+    it), against the reference; exact, so each limit is 0.  A pool that saves
+    sparsely is also held to keeping a save within ``max_prediction`` frames
+    of where each session stands (``ring_behind_sessions``), and to saving
+    sparsely at all (``ring_every_frame_sessions``)."""
     frames, players = pool.ticks, pool.players
     ring = int(config["ring_length"])
     depth = max(1, min(ring - 1, hold))  # saved frames all confirmed by the hold
-    ref, seen = reference_states(config, inputs, pool.matches, frames, depth + 1)
+    held = ring_frames(pool) if saves_sparsely(config) else None
+    draws = ring_draws(seed, pool.sessions, frames, depth, held)
+    keep = {frames} | {f for _, f in draws}
+    ref, seen = reference_states(config, inputs, pool.matches, frames, keep)
     live = jax.device_get(pool.executor.live_states)
     wrong = wrong_sessions(live, ref[frames], players)
-    rng = random.Random(seed)
     ring_wrong = digest_wrong = 0
-    for _ in range(RING_SAMPLES):
-        s, f = rng.randrange(pool.sessions), frames - 1 - rng.randrange(depth)
-        if f < 0:
-            continue
+    for s, f in draws:
         want = {k: v[s // players] for k, v in ref[f].items()}
         try:
             got = pool.executor.ring_state(s, f)
@@ -478,6 +528,22 @@ def compare(pool: Pool, config: Dict[str, Any], inputs: Inputs, seed: int,
               "ring_mismatch_samples": int(ring_wrong),
               "digest_mismatch_samples": int(digest_wrong),
               "session_ticks_missing": int(behind)}
+    if held is not None:
+        # what check_last_saved_state exists to prevent: the newest save
+        # slid out of the prediction window (or no save at all).  And a ring
+        # that holds its last ring_length frames saved every frame: once the
+        # hold has confirmed every input, a sparse session saves only where
+        # its last save lies max_prediction frames back, so its newest save
+        # never has the frame before it saved too
+        window, late, dense = int(config["max_prediction"]), 0, 0
+        for s in range(pool.sessions):
+            mine = held[s][held[s] >= 0]
+            late += (not mine.size
+                     or pool.host.current_frame(s) - int(mine.max()) > window)
+            dense += (mine.size == ring
+                      and int(mine.max()) - int(mine.min()) == ring - 1)
+        checks["ring_behind_sessions"] = int(late)
+        checks["ring_every_frame_sessions"] = int(dense)
     if seen is not None and witness_by_frame is not None:
         if frames >= witness_by_frame:
             # what the cell's `why` says the traffic exercises really happened

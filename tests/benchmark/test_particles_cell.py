@@ -5,11 +5,7 @@ that ``test_benchmark.py`` runs over every cell of ``BENCHMARK.json``.
 ``test_benchmark.py`` fixes its family lists, so the family's cases live here:
 reference against oracle, digests, the witness, the two faults this family's
 cell must catch (a steer step that ignores the mask, an expiry that is
-skipped) and the ``registry_gauge`` reducer.  Three tests of the accepted
-files hold ``BENCHMARK.json`` to the three PR 24 cells by equality
-(``test_window.py:109-114``, ``test_program_spans.py:179-181``,
-``test_benchmark.py:60``) and are red since these cells came: they are a
-``benchmark`` PR's to lift (PERF.md section 7, "Pins")."""
+skipped) and the ``registry_gauge`` reducer."""
 
 from __future__ import annotations
 
@@ -48,10 +44,16 @@ def kind_of(cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_new_cell_reads_every_per_layer_metric_of_its_kind(cell):
+    """The cell reads what the ``workloads`` lists name it in, each a metric
+    of its kind, and among them every metric the first cell of its kind
+    reads (a metric that one cell alone reads is that cell's)."""
     spec = run.load_cell(REPO, cell)
-    wanted = {m["name"] for m in BENCH["per_layer"]
-              if m["name"].endswith("." + kind_of(cell))}
-    assert {m["name"] for m in spec["metrics"]["per_layer"]} == wanted
+    kind = kind_of(cell)
+    listed = {m["name"] for m in BENCH["per_layer"] if run._applies(m, cell)}
+    read = {m["name"] for m in spec["metrics"]["per_layer"]}
+    assert read == listed and all(n.endswith("." + kind) for n in read)
+    first = {"sat": "boxgame-2p.wan-sat", "paced": "boxgame-2p.wan-60hz"}[kind]
+    assert {m["name"] for m in BENCH["per_layer"] if run._applies(m, first)} <= read
     assert len(spec["metrics"]["end_to_end"]) == 2  # its own and setup_s
     # nothing of the source is cut; every departure from it is said
     assert spec["config"]["reduced"] == {}

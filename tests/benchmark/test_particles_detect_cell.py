@@ -6,15 +6,7 @@ bank.  ``test_benchmark.py`` runs its cases over every cell of
 the configuration against ``particles-2p``'s, the exchange in a rehearsal
 (reports sent and compared at every session, none differing, no slot off the
 bank), a state altered mid-run, the two per-layer metrics and the reducer
-they brought, the reference's ``report_digests``.
-
-``test_cell_resolves_to_files[particles-2p-detect.wan-sat]`` is red from
-birth: it holds ``departs_from_source`` to ``desync_detection`` alone
-(``test_benchmark.py:60``), and this configuration departs in its arithmetic
-only; and ``test_particles_cell.py``'s
-``test_new_cell_reads_every_per_layer_metric_of_its_kind[particles-2p.wan-sat]``
-holds that cell to EVERY ``.sat`` metric, the two this cell alone reads
-included.  Both are a ``benchmark`` PR's to lift (PERF.md section 7, "Pins")."""
+they brought, the reference's ``report_digests``."""
 
 from __future__ import annotations
 
@@ -111,22 +103,28 @@ def test_the_configuration_is_particles_2p_but_for_its_guarantee():
 
 
 def test_the_cell_is_on_every_sat_list_and_brings_two_metrics():
-    for metric in BENCH["end_to_end"] + BENCH["per_layer"]:
-        cells = metric.get("workloads", [])
-        if metric["name"] in METRICS:
-            assert cells == [CELL]
-            assert metric["moves"] == "session_ticks_per_s"
-        elif TWIN in cells:
-            assert cells[-1] == CELL, metric["name"]
-        else:
-            assert CELL not in cells, metric["name"]
-    assert [m["name"] for m in BENCH["per_layer"][-2:]] == list(METRICS)
-    assert BENCH["workloads"][-1]["name"] == CELL
-    assert BENCH["configs"][-1]["name"] == "particles-2p-detect"
+    """Looked up by name, wherever later entries are appended: the cell and
+    its configuration are entries of ``BENCHMARK.json``, it reports what its
+    twin reports end to end, it is on every per-layer list its twin is on,
+    and its two metrics are read there alone.  Beyond its twin's it reads
+    only metrics that name this cell alone (the checksum exchange's)."""
+    by_name = {m["name"]: m for m in BENCH["end_to_end"] + BENCH["per_layer"]}
+    for name in METRICS:
+        assert by_name[name]["workloads"] == [CELL]
+        assert by_name[name]["moves"] == "session_ticks_per_s"
+    for metric in BENCH["end_to_end"]:
+        assert run._applies(metric, CELL) == run._applies(metric, TWIN)
+    for metric in BENCH["per_layer"]:
+        if run._applies(metric, TWIN):
+            assert run._applies(metric, CELL), metric["name"]
+    assert CELL in {w["name"] for w in BENCH["workloads"]}
+    assert "particles-2p-detect" in {c["name"] for c in BENCH["configs"]}
     spec = run.load_cell(REPO, CELL)
     names = {m["name"] for m in spec["metrics"]["per_layer"]}
     twin = {m["name"] for m in run.load_cell(REPO, TWIN)["metrics"]["per_layer"]}
-    assert names == twin | set(METRICS)
+    alone = {m["name"] for m in BENCH["per_layer"] if m.get("workloads") == [CELL]}
+    assert set(METRICS) <= alone
+    assert names == twin | alone
     assert {m["name"] for m in spec["metrics"]["end_to_end"]} == {
         "session_ticks_per_s", "setup_s"}
 
@@ -213,8 +211,9 @@ def test_a_traced_rehearsal_reads_both_metrics(no_chip_needed, monkeypatch):
     assert result["metrics"]["fast_slot_share.sat"]["value"] == 100.0
     # (at 8 sessions on the CPU the twin cell reads 87% as well)
     assert result["metrics"]["span_coverage_share.sat"]["value"] > 75.0
-    # ten descriptor arrays a dispatch: the fetch is no part of the launch
-    assert result["metrics"]["launch_transfers_per_dispatch.sat"]["value"] == 10
+    # one descriptor buffer a dispatch on one chip: the fetch is no part of
+    # the launch
+    assert result["metrics"]["launch_transfers_per_dispatch.sat"]["value"] == 1.0
 
 
 # --- the reducer and the reference ------------------------------------------

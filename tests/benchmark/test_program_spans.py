@@ -175,10 +175,15 @@ def test_each_quantity_has_one_file_and_two_entries(quantity):
                if m["name"].rpartition(".")[0] == quantity}
     assert set(entries) == {f"{quantity}.sat", f"{quantity}.paced"}
     sat, paced = entries[f"{quantity}.sat"], entries[f"{quantity}.paced"]
+    # each read in the cells of its loop, the first of them among them
+    loops = {w["name"]: run.load_cell(REPO, w["name"])["traffic"]["loop"]
+             for w in BENCH["workloads"]}
     assert sat["moves"] == "session_ticks_per_s"
-    assert sat["workloads"] == ["boxgame-2p.wan-sat", "ecs-4p.wan-sat"]
+    assert "boxgame-2p.wan-sat" in sat["workloads"]
+    assert {loops[cell] for cell in sat["workloads"]} == {"closed"}
     assert paced["moves"] == "tick_ms_p50"
-    assert paced["workloads"] == ["boxgame-2p.wan-60hz"]
+    assert "boxgame-2p.wan-60hz" in paced["workloads"]
+    assert {loops[cell] for cell in paced["workloads"]} == {"open"}
     assert sat["source"] == paced["source"] == "program_counter"
     assert sat["layer"] == paced["layer"]
     assert run._metric_file(REPO, f"{quantity}.sat") == spec
