@@ -5,12 +5,16 @@ frame a ring holds is confirmed, so it equals the reference whatever its age,
 no session's newest save lies more than ``max_prediction`` frames back, and
 no ring holds its last ``ring_length`` frames, as saving every frame leaves it).
 
-No cell of ``BENCHMARK.json`` saves sparsely yet.  These tests rehearse a
-scratch copy of ``particles-2p`` that says so, under a root of their own, as a
-configuration added as data would be.  Until the native bank serves sparse
-saving, its sessions run off the bank and ``native_bank_inactive`` says so.  A
-configuration without the key is held to the harness it had: the same builder
-calls, the same ring draws for a seed, the same check keys.
+These tests rehearse a scratch copy of ``particles-2p`` that says so, under a
+root and a name of their own (``particles-2p-sparse-rehearsal``), as a
+configuration added as data would be.  Sparse saving is held to those promises
+on either side of the native bank: as the program builds the pool, and off the
+bank, where the Python sessions that are the semantic reference run it.  On
+the bank a sparse pool is held to every check an every-frame pool is, so it is
+``correct`` there; off it ``native_bank_inactive`` says so, and the run is not
+correct for that alone.  A configuration without the key is held to the
+harness it had: the same builder calls, the same ring draws for a seed, the
+same check keys.
 """
 
 from __future__ import annotations
@@ -20,7 +24,6 @@ import json
 import shutil
 import sys
 from pathlib import Path
-from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -31,10 +34,12 @@ if str(REPO) not in sys.path:
 
 from benchmark import generator, roofline, run  # noqa: E402
 from ggrs_tpu.core.types import AdvanceFrame, LoadGameState, SaveGameState  # noqa: E402
+from ggrs_tpu.parallel import host_bank  # noqa: E402
 
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 TWIN = "particles-2p.wan-sat"
-CONFIG = "particles-2p-sparse"
+# a name of its own: a real ``particles-2p-sparse`` may stand in BENCHMARK.json
+CONFIG = "particles-2p-sparse-rehearsal"
 CELL = f"{CONFIG}.wan-sat"
 SEED = 2**31 + 39
 # what compare() reported for every cell before a configuration could save
@@ -50,6 +55,9 @@ HELD_TO_ZERO = ("state_mismatch_sessions", "ring_mismatch_samples",
                 "digest_mismatch_samples", "ring_behind_sessions",
                 "ring_every_frame_sessions", "session_ticks_missing", "compiles_in_window",
                 "window_without_rollback")
+# the pool as the program builds it, and the bank's gate closed to every
+# builder: the Python sessions, which stay the semantic reference
+SIDES = ("as_built", "off_bank")
 
 
 def scratch_root(tmp_path: Path, saving="sparse") -> Path:
@@ -72,6 +80,13 @@ def scratch_root(tmp_path: Path, saving="sparse") -> Path:
     bench["workloads"].append(dict(twin["cell"], name=CELL, config=CONFIG))
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root
+
+
+def take_side(side: str, monkeypatch) -> None:
+    """Build the pool on ``side`` of the bank (one of ``SIDES``)."""
+    if side == "off_bank":
+        monkeypatch.setattr(host_bank, "_bank_eligible",
+                            lambda builder, hub_active=False: False)
 
 
 @pytest.fixture
@@ -128,8 +143,10 @@ def test_any_other_saving_is_refused_where_the_cell_is_loaded(tmp_path):
 # --- a sparse pool, rehearsed -----------------------------------------------
 
 
+@pytest.mark.parametrize("side", SIDES)
 def test_a_sparse_rehearsal_holds_every_ring_check_to_zero(
-        tmp_path, no_chip_needed, pools, sparse_calls):
+        side, tmp_path, no_chip_needed, monkeypatch, pools, sparse_calls):
+    take_side(side, monkeypatch)
     result = run.run_cell(CELL, SEED, 0.25, False, root=scratch_root(tmp_path),
                           matches=4)
     checks = {k: v["value"] for k, v in result["checks"].items()}
@@ -137,18 +154,30 @@ def test_a_sparse_rehearsal_holds_every_ring_check_to_zero(
     for name in HELD_TO_ZERO:
         assert checks[name] == 0, checks
     pool, = pools
+    assert pool.sessions == 8
     assert sparse_calls == [True] * pool.sessions
-    # the honest reading until the bank serves sparse saving: every session
-    # runs as a Python session, no tick crosses into the bank, and the run
-    # is not correct for that alone.  (``slot_state`` names a healthy
-    # fallback slot "native", so ``slots_off_bank`` reads 0 here)
-    assert pool.sessions == 8 and not pool.host.native_active
-    assert "8 session(s) outside the bank's scope" in pool.host.native_reason
-    assert checks["native_bank_inactive"] == 1
-    assert checks["bank_crossings_off_ticks"] == checks["plan_ticks_off_ticks"] \
-        == pool.ticks
-    assert checks["slots_off_bank"] == 0
-    assert result["correct"] is False
+    # the check says which side of the bank the sessions ran on; the side
+    # whose gate the test closed runs them off it, so the fallback stays
+    on_bank = pool.host.native_active
+    assert checks["native_bank_inactive"] == int(not on_bank)
+    if side == "off_bank":
+        assert not on_bank
+    if on_bank:
+        # a sparse pool the bank serves is held to all an every-frame one
+        # is: one crossing and one plan a tick, every slot on the bank
+        assert checks["bank_crossings_off_ticks"] == checks["plan_ticks_off_ticks"] == 0
+        assert checks["slots_off_bank"] == 0
+        assert result["correct"] is True, checks
+    else:
+        # every session a Python session: no tick crosses into the bank,
+        # and the run is not correct for that alone.  (``slot_state`` names
+        # a healthy fallback slot "native", so ``slots_off_bank`` reads 0)
+        assert "8 session(s) outside the bank's scope" in pool.host.native_reason
+        assert checks["native_bank_inactive"] == 1
+        assert checks["bank_crossings_off_ticks"] == checks["plan_ticks_off_ticks"] \
+            == pool.ticks
+        assert checks["slots_off_bank"] == 0
+        assert result["correct"] is False
     # the sessions really saved sparsely: a ring that saves every frame holds
     # the last ring_length frames; these hold confirmed frames further apart
     held = run.ring_frames(pool)
@@ -257,41 +286,54 @@ def test_a_configuration_without_the_key_is_compared_as_before(
     assert set(result["checks"]) - {WITNESS} == EVERY_FRAME_CHECKS
 
 
-# --- the roofline's count of a sparse plan ----------------------------------
+# --- the roofline's count of a plan ------------------------------------------
 
 
-def test_a_python_session_plan_of_sparse_sessions_counts_its_requests(
-        no_chip_needed):
-    """Off the bank a sparse pool's plan is its sessions' request lists, all
-    eager rows: counted as those lists say, with far fewer saves than
-    advances, where a session that saves every frame saves once an advance."""
+@pytest.mark.parametrize("side", SIDES)
+@pytest.mark.parametrize("saving", ["sparse", pytest.param(None, id="absent")])
+def test_a_plan_counts_its_own_requests(saving, side, no_chip_needed, monkeypatch):
+    """What ``roofline.plan_counts`` reads of each plan the executor runs is
+    what the plan's own requests ask for, taken before it runs: by its
+    columns, or by the tally it states, wherever the bank hands the executor
+    a plan.  Off the bank the executor gets the sessions' request lists,
+    which ``run.Spans`` leaves out as it does here.  Sparse sessions save
+    far fewer frames than they advance; every-frame ones save every advance
+    but a resim's trailing live one."""
+    take_side(side, monkeypatch)
     spec = run.load_cell(REPO, "boxgame-2p.wan-sat")
-    config, traffic = dict(spec["config"], saving="sparse"), spec["traffic"]
+    config, traffic = dict(spec["config"]), spec["traffic"]
+    if saving is not None:
+        config["saving"] = saving
     pool = run.Pool(config, traffic, 2, SEED)
-    plans = []
+    tallies = []  # (what the roofline reads, what the requests ask) a plan
     execute = pool.executor.run
 
     def counted(plan):
-        lists = [list(reqs or ()) for reqs in plan]
-        plans.append(SimpleNamespace(
-            quiet_rows=None, resim_rows=[], save_only_rows=[],
-            eager_rows=list(range(len(lists))), lists=lists))
+        read = (roofline.plan_counts(plan)
+                if getattr(plan, "quiet_rows", None) is not None else None)
+        asked = dict.fromkeys(roofline.COUNTED, 0)
+        for reqs in plan:  # a RequestPlan materializes each slot
+            for req in reqs or ():
+                for k, kind in (("advances", AdvanceFrame), ("saves", SaveGameState),
+                                ("loads", LoadGameState)):
+                    asked[k] += isinstance(req, kind)
+        tallies.append((read, asked))
         execute(plan)
 
     pool.executor.run = counted
     rows = generator.schedule(traffic, SEED, 2, 2, 120)
     for row in rows:
         pool.tick(row)
-    total = {k: 0 for k in roofline.COUNTED}
-    said = dict(total)
-    for plan in plans:
-        for k, v in roofline.plan_counts(plan).items():
-            total[k] += v
-        for reqs in plan.lists:
-            for req in reqs:
-                for k, kind in (("advances", AdvanceFrame), ("saves", SaveGameState),
-                                ("loads", LoadGameState)):
-                    said[k] += isinstance(req, kind)
-    assert total == said
+    on_bank = pool.host.native_active
+    if side == "off_bank":
+        assert not on_bank
+    assert len(tallies) == 120
+    assert sum(read is not None for read, _ in tallies) == (120 if on_bank else 0)
+    for read, asked in tallies:
+        assert read is None or read == asked, (read, asked)
+    total = {k: sum(asked[k] for _, asked in tallies) for k in roofline.COUNTED}
     assert total["loads"] > 0 and total["advances"] >= 4 * 120
-    assert total["saves"] < total["advances"] / 4
+    if saving == "sparse":
+        assert total["saves"] < total["advances"] / 4
+    else:
+        assert total["saves"] >= total["advances"] - total["loads"]
