@@ -133,6 +133,19 @@ LAYOUT_REQ_FIELDS: Tuple[Tuple[str, str, int], ...] = (
 )
 LAYOUT_REQ_STRIDE = 24
 
+# The tick's outbound table row (after the body records and the wanted
+# tail; kWireStride ↔ _native.BANK_WIRE_FIELDS): one datagram of a
+# record's outbound sections, its bytes addressed in the tick output.
+LAYOUT_WIRE_FIELDS: Tuple[Tuple[str, str, int], ...] = (
+    ("slot", "<u4", 0),
+    ("ep", "<u2", 4),
+    ("section", "<u1", 6),
+    ("off", "<u4", 7),
+    ("len", "<u4", 11),
+    ("crc", "<u4", 15),
+)
+LAYOUT_WIRE_STRIDE = 19
+
 # Batched outbound send record (net_batch.cpp ggrs_net_send_table /
 # kSendStride ↔ _native.NET_SEND_FIELDS).
 LAYOUT_SEND_FIELDS: Tuple[Tuple[str, str, int], ...] = (
@@ -290,6 +303,8 @@ MIRRORED_CONSTANTS: Tuple[Tuple[str, str, str, str], ...] = (
      "ggrs_tpu/net/_native.py", "BANK_STAGE_STRIDE"),
     ("native/session_bank.cpp", "kReqStride",
      "ggrs_tpu/net/_native.py", "BANK_REQ_STRIDE"),
+    ("native/session_bank.cpp", "kWireStride",
+     "ggrs_tpu/net/_native.py", "BANK_WIRE_STRIDE"),
     ("native/session_bank.cpp", "kReqOther",
      "ggrs_tpu/net/_native.py", "REQ_OTHER"),
     ("native/session_bank.cpp", "kReqQuiet",
@@ -536,8 +551,8 @@ def _check_field_table(
 
 
 def _check_descriptor_plane(root: Path) -> List[Finding]:
-    """The §21 structs: staging record, request descriptor record, send
-    record — Python dtypes vs the contract (the C++ strides and pattern
+    """The §21 structs: staging record, request descriptor record, the
+    outbound table's row, send record — Python dtypes vs the contract (the C++ strides and pattern
     codes are pinned by MIRRORED_CONSTANTS)."""
     out: List[Finding] = []
     out += _check_field_table(
@@ -545,6 +560,9 @@ def _check_descriptor_plane(root: Path) -> List[Finding]:
     )
     out += _check_field_table(
         root, "BANK_REQ_FIELDS", LAYOUT_REQ_FIELDS, LAYOUT_REQ_STRIDE
+    )
+    out += _check_field_table(
+        root, "BANK_WIRE_FIELDS", LAYOUT_WIRE_FIELDS, LAYOUT_WIRE_STRIDE
     )
     out += _check_field_table(
         root, "NET_SEND_FIELDS", LAYOUT_SEND_FIELDS, LAYOUT_SEND_STRIDE

@@ -547,6 +547,10 @@ def _load() -> Optional[ctypes.CDLL]:
                     ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
                     ctypes.c_char_p, ctypes.c_size_t,
                 ]
+            if hasattr(lib, "ggrs_bank_wire_stride"):
+                # the tick's outbound table (BANK_WIRE_FIELDS)
+                lib.ggrs_bank_wire_stride.restype = ctypes.c_int
+                lib.ggrs_bank_wire_stride.argtypes = []
             if hasattr(lib, "ggrs_net_send_table"):
                 # one-shot batched outbound over arbitrary fds (§21);
                 # shares the non-Linux stub policy of the NetBatch surface
@@ -751,6 +755,18 @@ REQ_RESIM = 2       # [load frame, adv, (save, adv)*, save] (+ trailing adv)
 REQ_SAVE_ONLY = 3   # [save frame] — the prediction-limit tick
 REQ_EMPTY = 4       # no ops (skip / faulted records)
 REQ_FLAG_TRAILING_ADV = 1  # the tick's last op was an advance ("advanced")
+
+# The tick's outbound table (session_bank.cpp kWireStride): after the body
+# records and the wanted tail, u32 n_rows then one row per datagram of the
+# records' two outbound sections, in record order — the slot, endpoint and
+# section, the datagram's offset (absolute in the tick output) and length,
+# and its zlib.crc32, so the pool sends and taps fast slots' datagrams
+# from columns instead of walking their sections.
+BANK_WIRE_FIELDS = (
+    ("slot", "<u4"), ("ep", "<u2"), ("section", "<u1"), ("off", "<u4"),
+    ("len", "<u4"), ("crc", "<u4"),
+)  # itemsize 19 == ggrs_bank_wire_stride()
+BANK_WIRE_STRIDE = 19
 
 # Batched outbound send record (net_batch.cpp ggrs_net_send_table): per
 # datagram fd + wire address + a jump into the shared payload (usually the

@@ -147,8 +147,8 @@ from ..obs.recorder import (
     EV_FAULT,
     EV_ROLLBACK,
     EV_STATE,
-    EV_WIRE,
     FlightRecorder,
+    WireTaps,
 )
 from ..obs.registry import Registry, default_registry
 from ..obs.trace import Tracer, default_tracer
@@ -196,6 +196,14 @@ _REQ_DTYPE = np.dtype(list(_native.BANK_REQ_FIELDS))
 _STAGE_DTYPE = np.dtype(list(_native.BANK_STAGE_FIELDS))
 _SEND_DTYPE = np.dtype(list(_native.NET_SEND_FIELDS))
 _RECV_DTYPE = np.dtype(list(_native.NET_RECV_FIELDS))
+# the tick's outbound table (session_bank.cpp kWireStride): a row a
+# datagram of the records' outbound sections, after the wanted tail
+_WIRE_DTYPE = np.dtype(list(_native.BANK_WIRE_FIELDS))
+_WIRE_HEAD = struct.Struct("<I")
+# how a slot's outbound leaves the pool (HostSessionPool._send_kind): one
+# send_datagram_batch call, a row of the one ggrs_net_send_table crossing,
+# or send_raw per datagram (the reference's own path)
+_SEND_BATCH, _SEND_TABLE, _SEND_RAW = 0, 1, 2
 # desync detection (DESIGN.md §4): the wanted tail that follows the last
 # body record where a session of the bank detects (session_bank.cpp): four
 # u32 counts, then one row a digest the pool is to fetch from the device
@@ -801,7 +809,12 @@ class HostSessionPool:
         # one-crossing ggrs_net_send_table flush; everything else batches
         # per slot (send_datagram_batch) or keeps the per-datagram path
         self._send_fds: List[Optional[int]] = []
-        self._ep_wire: List[Optional[List[Tuple[int, int]]]] = []
+        # per slot, how its outbound leaves (_SEND_*), and per (slot,
+        # endpoint) the send-table row's fd / address / flags for the
+        # slots whose datagrams ride ggrs_net_send_table
+        self._send_kind = np.zeros(0, np.int8)
+        self._send_rows = np.zeros((0, 1), _SEND_DTYPE)
+        self._ep_addrs: List[Any] = []
         # ---- observability (DESIGN.md §12) ----
         # metrics: explicit Registry for isolation (tests, multi-pool
         # processes) or the process-wide default; Registry(enabled=False)
@@ -811,6 +824,8 @@ class HostSessionPool:
         self._obs_on = m.enabled
         self._flight_capacity = flight_recorder_size
         self._recorders: List[Optional[FlightRecorder]] = []
+        # the recorders' EV_WIRE digests, as columns (None with obs off)
+        self._wire_taps: Optional[WireTaps] = None
         # ---- tracing (DESIGN.md §14) ----
         # tracer: stage / tick -> build_cmd, crossing, decode, supervise
         # spans on the Python side; when the library carries
@@ -954,6 +969,12 @@ class HostSessionPool:
             "ggrs_pool_fastpath_slots_total",
             "slot ticks served by the vectorized quiet path (no per-slot "
             "body parse)")
+        self._m_wire_rows = m.counter(
+            "ggrs_pool_wire_table_rows_total",
+            "outbound datagrams the decode sent and tapped from the tick's "
+            "outbound table (fast slots) rather than a record walk")
+        # the last plan tick's (table rows taken, datagrams of all records)
+        self._wire_tick = (0, 0)
         # datapath gen 2 (§23): the one-crossing inbound drain + GSO
         self._m_drain_crossings = m.counter(
             "ggrs_io_drain_crossings_total",
@@ -1084,10 +1105,16 @@ class HostSessionPool:
         self._net_handles = [None] * len(self._builders)
         self._io_attached = [False] * len(self._builders)
         self._fault_log = [[] for _ in self._builders]
-        self._recorders = [
-            FlightRecorder(self._flight_capacity) if self._obs_on else None
-            for _ in self._builders
-        ]
+        if self._obs_on:
+            self._wire_taps = WireTaps(len(self._builders),
+                                       self._flight_capacity)
+            self._recorders = [
+                FlightRecorder(self._flight_capacity, taps=self._wire_taps,
+                               slot=i)
+                for i in range(len(self._builders))
+            ]
+        else:
+            self._recorders = [None] * len(self._builders)
         if self._builders:
             self._m_slot_state.labels(state=SLOT_NATIVE).inc(
                 len(self._builders)
@@ -1121,19 +1148,26 @@ class HostSessionPool:
                 )
                 lib = None
         if lib is not None and hasattr(lib, "ggrs_bank_req_stride"):
-            # the request descriptor table is emitted unconditionally by a
-            # descriptor-plane library, so a stride mismatch shifts EVERY
-            # body offset — same degradation as a header skew
+            # the request descriptor table and the outbound table are
+            # emitted unconditionally by a descriptor-plane library, so a
+            # stride mismatch shifts EVERY body offset (or the table's
+            # rows) — same degradation as a header skew
+            wire_stride = (
+                int(lib.ggrs_bank_wire_stride())
+                if hasattr(lib, "ggrs_bank_wire_stride") else 0
+            )
             if (
                 int(lib.ggrs_bank_req_stride()) != _REQ_DTYPE.itemsize
                 or int(lib.ggrs_bank_stage_stride()) != _STAGE_DTYPE.itemsize
+                or wire_stride != _WIRE_DTYPE.itemsize
             ):
                 reason = (
                     f"bank descriptor strides (req "
                     f"{int(lib.ggrs_bank_req_stride())}, stage "
-                    f"{int(lib.ggrs_bank_stage_stride())}) != driver "
-                    f"({_REQ_DTYPE.itemsize}, {_STAGE_DTYPE.itemsize}) "
-                    f"(library/driver skew)"
+                    f"{int(lib.ggrs_bank_stage_stride())}, wire "
+                    f"{wire_stride}) != driver "
+                    f"({_REQ_DTYPE.itemsize}, {_STAGE_DTYPE.itemsize}, "
+                    f"{_WIRE_DTYPE.itemsize}) (library/driver skew)"
                 )
                 _logger.warning(
                     "%s; pool falls back to per-session Python sessions",
@@ -1376,9 +1410,20 @@ class HostSessionPool:
         # batched outbound eligibility (§21c) — after the io attach pass,
         # so NetBatch-attached slots (whose sends never re-enter Python)
         # are excluded
-        self._send_fds = [None] * len(self._mirrors)
-        self._ep_wire = [None] * len(self._mirrors)
-        self._send_flags = [0] * len(self._mirrors)
+        n_slots = len(self._mirrors)
+        self._send_fds = [None] * n_slots
+        self._send_flags = [0] * n_slots
+        self._send_kind = np.zeros(n_slots, np.int8)
+        self._send_rows = np.zeros(
+            (n_slots, max([len(m.endpoints) for m in self._mirrors] + [1])),
+            _SEND_DTYPE,
+        )
+        # the endpoints' addresses, flat by slot * _send_rows' width + ep
+        width = self._send_rows.shape[1]
+        self._ep_addrs = [None] * (n_slots * width)
+        for i, m in enumerate(self._mirrors):
+            for e, ep in enumerate(m.endpoints):
+                self._ep_addrs[i * width + e] = ep.addr
         for i in range(len(self._mirrors)):
             self._refresh_send_fd(i)
         # ---- datapath gen 2 (§23) ----
@@ -1410,11 +1455,12 @@ class HostSessionPool:
         batch/per-datagram paths."""
         if not self._send_fds:
             return
-        self._send_fds[index] = None
-        self._ep_wire[index] = None
-        if self._send_flags:
-            self._send_flags[index] = 0
         m = self._mirrors[index]
+        self._send_fds[index] = None
+        self._send_flags[index] = 0
+        self._send_kind[index] = (
+            _SEND_BATCH if m.send_batch is not None else _SEND_RAW
+        )
         lib = self._lib
         if (
             lib is None
@@ -1441,12 +1487,17 @@ class HostSessionPool:
         except (TypeError, ValueError, OSError):
             return
         self._send_fds[index] = fd
-        self._ep_wire[index] = wire
-        if self._send_flags and getattr(m.socket, "is_dispatch", False):
+        if getattr(m.socket, "is_dispatch", False):
             # shared dispatch fd (§23b): a fatal errno on one record must
             # fault only the owning slot, so the native flush skips the
             # record instead of abandoning the co-tenants' run
             self._send_flags[index] = _native.NET_SEND_FLAG_DISPATCH
+        self._send_kind[index] = _SEND_TABLE
+        row = self._send_rows[index]
+        row["fd"] = fd
+        row["flags"] = self._send_flags[index]
+        row["ip"][: len(wire)] = [ip for ip, _ in wire]
+        row["port"][: len(wire)] = [port for _, port in wire]
 
     @staticmethod
     def _resolve_wire_addr(addr) -> Tuple[int, int]:
@@ -2160,12 +2211,15 @@ class HostSessionPool:
                 if self._detects:
                     request_lists.checksum_wanted = self._read_wanted_tail()
                 if tracing:
+                    wire_rows, datagrams = self._wire_tick
                     span.set(
                         fast=self.fast_slot_ticks - fast0,
                         eager=len(request_lists.eager_rows),
                         resim=len(request_lists.resim_rows),
                         save_only=len(request_lists.save_only_rows),
                         slots=len(self._mirrors),
+                        wire_rows=wire_rows,
+                        datagrams=datagrams,
                     )
             self._plan = request_lists
         else:
@@ -2518,6 +2572,18 @@ class HostSessionPool:
                 )
         return request_lists
 
+    def _wire_table(self, pos: int) -> np.ndarray:
+        """The tick's outbound table (``_native.BANK_WIRE_FIELDS``) as a
+        structured view: its count heads what follows the body records
+        ending at ``pos``, and the wanted tail where the bank detects."""
+        out = self._out_buf
+        if self._detects:
+            wanted = _WANTED_HEAD.unpack_from(out, pos)[0]
+            pos += _WANTED_HEAD.size + wanted * _WANTED_DTYPE.itemsize
+        (rows,) = _WIRE_HEAD.unpack_from(out, pos)
+        return np.frombuffer(out, _WIRE_DTYPE, count=rows,
+                             offset=pos + _WIRE_HEAD.size)
+
     def _parse_output_plan(self, ticked: List[bool]):
         """Descriptor-plane tick decode (DESIGN.md §21): classify all B
         slots from the packed header table AND read their request
@@ -2530,13 +2596,16 @@ class HostSessionPool:
         ``BatchedRequestExecutor`` consumes the descriptor columns
         directly instead.
 
-        Outbound is batched (§21c): fast slots' datagrams go out through
-        one ``send_datagram_batch`` call per slot (in-memory / batchable
-        sockets), or ride ONE ``ggrs_net_send_table`` crossing for the
-        whole tick (fd-backed sockets that are not NetBatch-attached) —
-        the send-table payload is the tick output buffer itself, zero
-        copies.  Per-socket send order is unchanged (records stay in slot
-        order); slow slots keep the reference per-datagram path.
+        Outbound is read from the tick's outbound table, never from a
+        walk of the fast slots' sections: their wire taps go into the
+        recorders' columnar store in one scatter, and their datagrams go
+        out through one ``send_datagram_batch`` call per slot (in-memory /
+        batchable sockets), or ride ONE ``ggrs_net_send_table`` crossing
+        for the whole tick (fd-backed sockets that are not
+        NetBatch-attached) — the send-table payload is the tick output
+        buffer itself, zero copies.  Per-socket send order is unchanged
+        (records stay in slot order); slow slots keep the reference
+        per-datagram path.
 
         Returns ``(plan, retire_mask)`` like the legacy fast path."""
         mirrors = self._mirrors
@@ -2561,6 +2630,7 @@ class HostSessionPool:
         if n > 1:
             offs[1:] = base + np.cumsum(rec_len[:-1], dtype=np.int64)
         out_len = self._out_len.value
+        wire = self._wire_table(int(offs[-1] + rec_len[-1]))
         plan.buffer = np.frombuffer(self._out_buf, np.uint8, count=out_len)
         plan.uniform = self._uniform
         m0 = mirrors[0]
@@ -2596,6 +2666,7 @@ class HostSessionPool:
                 plan.lists[idx] = reqs
                 plan.eager_rows.append(idx)
             self.desc_slow_slots += n
+            self._wire_tick = (0, len(wire))
             plan.quiet_rows = np.empty(0, np.int64)
             plan.quiet_frames = np.empty(0, np.int64)
             plan.quiet_offs = np.empty(0, np.int64)
@@ -2640,7 +2711,6 @@ class HostSessionPool:
         flags_l = flags.tolist()
         pattern_l = pattern.tolist()
         trailing_l = trailing.tolist()
-        ops_end_l = req["ops_end"].tolist()
         # plain-int columns once, not per-row structured indexing (resim
         # ticks visit hundreds of rows on a rollback-heavy pool)
         rframe_l = req["frame"].tolist()
@@ -2653,11 +2723,64 @@ class HostSessionPool:
         lists = plan.lists
         eager = plan.eager_rows
 
-        # ---- pass 1: eager slots through the reference decoder; fast
-        # slots' outbound staged/sent + per-slot pass-2 work queued ----
-        table_rows: List[Tuple[int, int, int, int, int]] = []  # native tbl
-        table_slots: List[int] = []
-        pass2: List[Tuple[int, int]] = []  # (slot, pos after out sections)
+        # ---- the tick's outbound table: fast slots' datagrams, as
+        # columns.  Slow slots' rows are left to the reference decoder,
+        # which reads the same bytes from their records ----
+        w_slot = wire["slot"].astype(np.intp)
+        rows = wire[fast[w_slot]]
+        r_slot = w_slot[fast[w_slot]]
+        r_ep = rows["ep"].astype(np.intp)
+        kind = self._send_kind[r_slot]
+        self._wire_tick = (len(rows), len(wire))
+        self._m_wire_rows.inc(len(rows))
+        taps = self._wire_taps
+        if taps is not None:
+            # a resim slot's rollback is recorded ahead of its datagrams,
+            # where the reference decoder meets its load op
+            for idx in np.flatnonzero(resim).tolist():
+                recorders[idx].record(
+                    self._tick_no, EV_ROLLBACK,
+                    f"load frame {rframe_l[idx]} (was at "
+                    f"{mirrors[idx].current_frame})",
+                )
+            # the wire taps of DESIGN.md §12, one scatter for the tick.  A
+            # send_raw slot taps per datagram below, so that a failed send
+            # leaves the datagrams after it untapped, as the reference does.
+            # Forensics caveat: on the batched tiers the flush outcome is
+            # only known after the whole slot staged, so a mid-flush fatal
+            # leaves EV_WIRE entries for datagrams that never hit the wire
+            # — always bounded by the EV_FAULT marker the flush failure
+            # records right after them
+            t = kind != _SEND_RAW
+            taps.scatter(r_slot[t], self._tick_no, rows["ep"][t],
+                         rows["len"][t], rows["crc"][t])
+        # the socket calls that stay in Python, in slot order and
+        # interleaved with the slow slots' sends exactly where the serial
+        # decoder made them: per-socket order, and the seeded fault rng of
+        # an in-memory network, see what they always saw
+        py = kind != _SEND_TABLE
+        p_slot = r_slot[py]
+        p_rows = rows[py]
+        items: List[Tuple[Any, Any]] = []
+        if p_slot.size:
+            # the (bytes, address) item of every row
+            addrs = self._ep_addrs
+            p_len = p_rows["len"].tolist()
+            out = bytes(buf)  # one copy: bytes slice faster than views
+            items = [
+                (out[o : o + ln], addrs[k]) for o, ln, k in zip(
+                    p_rows["off"].tolist(), p_len,
+                    (p_slot * self._send_rows.shape[1]
+                     + r_ep[py]).tolist(),
+                )
+            ]
+        visit = ~fast
+        visit[p_slot] = True
+        visit = np.flatnonzero(visit)
+        # a slot's rows are one run of them: [first, last) (empty for a
+        # slow slot)
+        first = np.searchsorted(p_slot, visit).tolist()
+        last = np.searchsorted(p_slot, visit, side="right").tolist()
         flush_failed: Dict[int, Tuple[int, str]] = {}  # slot -> code, msg
         # parallel decode plane (§24): every slow slot's byte range is
         # known up front (the offs jump chain), so their pure decode fans
@@ -2667,7 +2790,8 @@ class HostSessionPool:
         # side-effect order is untouched because decode is pure
         slow_rows = [idx for idx in range(n) if not fast_l[idx]]
         decs = self._decode_slow_slots(buf, slow_rows, offs_l, ticked)
-        for idx in range(n):
+        tick = self._tick_no
+        for idx, a, b in zip(visit.tolist(), first, last):
             if not fast_l[idx]:
                 if decs is not None:
                     requests, _, _ = self._apply_slot(
@@ -2681,98 +2805,49 @@ class HostSessionPool:
                 eager.append(idx)
                 continue
             m = mirrors[idx]
-            off = offs_l[idx]
-            pos = off + ops_end_l[idx]
-            rec = recorders[idx] if recorders else None
-            fd = self._send_fds[idx]
-            wire = self._ep_wire[idx]
-            batch: Optional[List[Tuple[Any, Any]]] = (
-                [] if (fd is None and m.send_batch is not None) else None
-            )
-            send_raw = m.send_raw
-            endpoints = m.endpoints
-            failed: Optional[str] = None
-            for _section in (0, 1):
-                (n_out,) = unpack_from("<H", buf, pos)
-                pos += 2
-                for _ in range(n_out):
-                    ep_idx, dlen = unpack_from("<HI", buf, pos)
-                    pos += 6
-                    if failed is not None:
-                        pos += dlen
-                        continue
-                    if rec is not None:
-                        # forensics caveat: on the BATCHED tiers the
-                        # flush outcome is only known after the whole
-                        # slot staged, so a mid-flush fatal leaves EV_WIRE
-                        # entries for datagrams that never hit the wire —
-                        # always bounded by the EV_FAULT marker the flush
-                        # failure records right after them
-                        rec.record(
-                            self._tick_no, EV_WIRE,
-                            (ep_idx, dlen,
-                             zlib.crc32(buf[pos : pos + dlen])),
-                        )
-                    if fd is not None:
-                        # native send table: the datagram bytes stay in
-                        # the output buffer; only (fd, addr, off, len) is
-                        # recorded — flushed once for the whole tick
-                        ip, port = wire[ep_idx]
-                        table_rows.append((fd, ip, port, pos, dlen))
-                        table_slots.append(idx)
-                    elif batch is not None:
-                        batch.append(
-                            (buf[pos : pos + dlen], endpoints[ep_idx].addr)
-                        )
-                    else:
-                        try:
-                            send_raw(bytes(buf[pos : pos + dlen]),
-                                     endpoints[ep_idx].addr)
-                        except Exception as e:
-                            failed = f"socket send failed: {e!r}"
-                    pos += dlen
-            if failed is None and batch:
-                # one batched call per slot per tick (§21c): the socket
-                # walks the list internally — same per-socket send order
-                try:
-                    m.send_batch(batch)
-                except Exception as e:
-                    failed = f"socket send failed: {e!r}"
-            if failed is not None:
-                flush_failed[idx] = (0, failed)
-            pass2.append((idx, pos))
+            try:
+                if m.send_batch is not None:
+                    # one batched call per slot per tick (§21c): the
+                    # socket walks the list internally
+                    m.send_batch(items[a:b])
+                else:
+                    rec = recorders[idx] if recorders else None
+                    send_raw = m.send_raw
+                    for k in range(a, b):
+                        data, addr = items[k]
+                        if rec is not None:
+                            rec.record_wire(tick, int(p_rows["ep"][k]),
+                                            p_len[k], int(p_rows["crc"][k]))
+                        send_raw(data, addr)
+            except Exception as e:  # a send fault is THIS slot's fault
+                flush_failed[idx] = (0, f"socket send failed: {e!r}")
 
-        # ---- the one native outbound crossing for fd-backed slots ----
-        if table_rows:
-            desc = np.empty(len(table_rows), _SEND_DTYPE)
-            cols = list(zip(*table_rows))
-            desc["fd"] = cols[0]
-            desc["ip"] = cols[1]
-            desc["port"] = cols[2]
-            # dispatch-mode rows carry kSendFlagDispatch: a fatal errno on
-            # the SHARED fd faults only the owning record's slot, the run
-            # continues for co-tenants (§23b)
-            send_flags = self._send_flags
-            desc["flags"] = [send_flags[s] for s in table_slots]
-            desc["off"] = cols[3]
-            desc["len"] = cols[4]
+        # ---- the one native outbound crossing for fd-backed slots:
+        # the send-table rows of (slot, endpoint) take the table's offsets
+        # and lengths; the payload is the tick output buffer itself ----
+        tsel = kind == _SEND_TABLE
+        if tsel.any():
+            table_slots = r_slot[tsel]
+            desc = self._send_rows[table_slots, r_ep[tsel]]
+            desc["off"] = rows["off"][tsel]
+            desc["len"] = rows["len"][tsel]
             stats3 = (ctypes.c_uint64 * _native.NET_SEND_STATS)()
             fatal = (ctypes.c_int32 * 32)()
             rc = self._lib.ggrs_net_send_table(
-                desc.ctypes.data, len(table_rows), self._out_buf, out_len,
+                desc.ctypes.data, len(desc), self._out_buf, out_len,
                 stats3, fatal, 16,
             )
             if rc < 0:
                 # table refused whole (corrupt offsets = builder bug):
                 # fault every participating slot rather than lose sends
                 # silently (dict.fromkeys: deterministic slot order)
-                for idx in dict.fromkeys(table_slots):
+                for idx in dict.fromkeys(table_slots.tolist()):
                     flush_failed.setdefault(
                         idx, (0, f"ggrs_net_send_table failed: {rc}")
                     )
             else:
                 for k in range(min(rc, 16)):
-                    slot = table_slots[fatal[2 * k]]
+                    slot = int(table_slots[fatal[2 * k]])
                     flush_failed.setdefault(
                         slot,
                         (_native.BANK_ERR_IO,
@@ -2785,7 +2860,7 @@ class HostSessionPool:
                     # slots' datagrams were abandoned too — fault them
                     # ALL rather than let ~B-16 slots run policy on
                     # sends that never happened
-                    for idx in dict.fromkeys(table_slots):
+                    for idx in dict.fromkeys(table_slots.tolist()):
                         flush_failed.setdefault(
                             idx,
                             (_native.BANK_ERR_IO,
@@ -2804,7 +2879,18 @@ class HostSessionPool:
                     self._m_gso_segments.inc(int(stats3[4]))
 
         # ---- pass 2: journal taps, policy, frame mirrors, forensics ----
-        for idx, pos in pass2:
+        sections_end: List[int] = []
+        if np.any(fast & ((flags & CONF) != 0)):
+            # where each record's outbound sections end: two u16 counts,
+            # then a u16 ep / u32 len head and the bytes per datagram
+            w_len = wire["len"]
+            sections_end = (
+                offs + req["ops_end"] + 4
+                + 6 * np.bincount(w_slot, minlength=n)
+                + np.bincount(w_slot, weights=w_len, minlength=n).astype(
+                    np.int64)
+            ).tolist()
+        for idx in np.flatnonzero(fast).tolist():
             m = mirrors[idx]
             failed = idx in flush_failed
             if failed:
@@ -2845,7 +2931,8 @@ class HostSessionPool:
                 # journal tap: read the confirmed-record section directly
                 # (no spectators on a fast slot, so the intervening
                 # sections are fixed-size)
-                pos += 2 + m.mirror_len  # n_events(=0) + status mirrors
+                # past n_events (=0) and the status mirrors
+                pos = sections_end[idx] + 2 + m.mirror_len
                 (next_spec,) = unpack_from("<q", buf, pos)
                 m.next_spec_frame = next_spec
                 pos += 9 + 4  # + n_specs(=0) + n_spec_out/evts(=0)
@@ -2873,12 +2960,6 @@ class HostSessionPool:
                         idx, lf, n_adv_l[idx], trailing_l[idx],
                         offs_l[idx] + adv_off_l[idx], adv_stride_l[idx],
                     ))
-                    rec = recorders[idx] if recorders else None
-                    if rec is not None:
-                        rec.record(
-                            self._tick_no, EV_ROLLBACK,
-                            f"load frame {lf} (was at {m.current_frame})",
-                        )
                 elif pat == _native.REQ_SAVE_ONLY:
                     plan.save_only_rows.append((idx, rframe_l[idx]))
                 # ---- policy (the fast-slot subset: no events, no
@@ -3108,10 +3189,10 @@ class HostSessionPool:
             if send_failed is not None:
                 continue  # slot already faulted; keep consuming bytes
             if rec is not None:
-                # wire digest: a tuple of scalars, formatted lazily by
-                # dump() — cheap enough to leave on for healthy slots
-                rec.record(self._tick_no, EV_WIRE,
-                           (ep_idx, dlen, zlib.crc32(data)))
+                # wire digest: scalars in the recorders' columnar store,
+                # formatted lazily by dump()
+                rec.record_wire(self._tick_no, ep_idx, dlen,
+                                zlib.crc32(data))
             try:
                 send_raw(data, m.endpoints[ep_idx].addr)
             except Exception as e:  # a send fault is THIS slot's fault
@@ -3258,11 +3339,8 @@ class HostSessionPool:
                         if send_failed is not None:
                             continue
                         if rec is not None:
-                            rec.record(
-                                self._tick_no, EV_WIRE,
-                                (f"spec{e}", len(data),
-                                 zlib.crc32(data)),
-                            )
+                            rec.record_wire(self._tick_no, f"spec{e}",
+                                            len(data), zlib.crc32(data))
                         if spec_rows is not None:
                             # same forensics caveat as §21c: the flush
                             # outcome lands after the whole stage, so
@@ -3297,8 +3375,8 @@ class HostSessionPool:
             if send_failed is not None:
                 continue
             if rec is not None:
-                rec.record(self._tick_no, EV_WIRE,
-                           (ep_idx, len(data), zlib.crc32(data)))
+                rec.record_wire(self._tick_no, ep_idx, len(data),
+                                zlib.crc32(data))
             try:
                 send_raw(data, m.endpoints[ep_idx].addr)
             except Exception as e:
@@ -3442,8 +3520,8 @@ class HostSessionPool:
             if send_failed is not None:
                 continue
             if rec is not None:
-                rec.record(self._tick_no, EV_WIRE,
-                           (ep_idx, len(data), zlib.crc32(data)))
+                rec.record_wire(self._tick_no, ep_idx, len(data),
+                                zlib.crc32(data))
             try:
                 send_raw(data, m.endpoints[ep_idx].addr)
             except Exception as e:
@@ -3500,11 +3578,8 @@ class HostSessionPool:
                         if send_failed is not None:
                             continue
                         if rec is not None:
-                            rec.record(
-                                self._tick_no, EV_WIRE,
-                                (f"spec{e}", len(data),
-                                 zlib.crc32(data)),
-                            )
+                            rec.record_wire(self._tick_no, f"spec{e}",
+                                            len(data), zlib.crc32(data))
                         if spec_rows is not None:
                             ip, port = spec_wire[e]
                             spec_rows.append((ip, port, data))
@@ -3528,8 +3603,8 @@ class HostSessionPool:
             if send_failed is not None:
                 continue
             if rec is not None:
-                rec.record(self._tick_no, EV_WIRE,
-                           (ep_idx, len(data), zlib.crc32(data)))
+                rec.record_wire(self._tick_no, ep_idx, len(data),
+                                zlib.crc32(data))
             try:
                 send_raw(data, m.endpoints[ep_idx].addr)
             except Exception as e:

@@ -194,6 +194,19 @@ constexpr uint8_t kReqSaveOnly = 3;
 constexpr uint8_t kReqEmpty = 4;
 constexpr uint8_t kReqFlagTrailingAdv = 1;
 
+// ---- the tick's outbound table (DESIGN.md §21) ---------------------------
+// After the body records (and the wanted tail where one is emitted) the tick
+// output carries u32 n_rows, then one kWireStride row per datagram of every
+// record's two outbound sections, in record order: the pool sends and taps
+// a fast slot's datagrams from these columns instead of walking its
+// sections.  The sections keep their bytes, so the reference decoder reads
+// what it always read.  Attached-socket slots (sections empty) add no row.
+// Fields (offsets mirrored by _native.BANK_WIRE_FIELDS):
+//   u32 slot, u16 ep, u8 section (0 poll / 1 input sends), u32 off (of the
+//   datagram's bytes, absolute in the tick output), u32 len, u32 crc32
+//   (IEEE, zlib.crc32 of the bytes: the flight recorder's wire digest)
+constexpr size_t kWireStride = 19;
+
 // ---- packed per-tick output header (DESIGN.md §19) ----------------------
 // The tick output now LEADS with one fixed-stride record per session — a
 // flat little-endian table the pool reads with a handful of NumPy ops to
@@ -621,6 +634,10 @@ struct Bank {
   size_t cs_sessions = 0;
   std::vector<std::pair<uint32_t, int64_t>> cs_wanted;
   uint32_t cs_sent = 0, cs_compares = 0, cs_desyncs = 0;
+  // this tick's outbound table (kWireStride rows, emitted after the body
+  // records): one row per datagram of the records' outbound sections
+  std::vector<uint8_t> wire;
+  uint32_t wire_rows = 0;
 };
 
 // ---- little-endian put/get over byte vectors -----------------------------
@@ -642,6 +659,28 @@ void put_u64(std::vector<uint8_t>* b, uint64_t u) {
 }
 void put_raw(std::vector<uint8_t>* b, const uint8_t* p, size_t n) {
   b->insert(b->end(), p, p + n);
+}
+
+// CRC-32/IEEE (reflected polynomial 0xEDB88320), byte-wise from a table:
+// bit for bit zlib.crc32, the flight recorder's wire digest.
+struct Crc32Table {
+  uint32_t t[256];
+  Crc32Table() {
+    for (uint32_t i = 0; i < 256; ++i) {
+      uint32_t c = i;
+      for (int k = 0; k < 8; ++k) {
+        c = (c & 1) ? 0xEDB88320u ^ (c >> 1) : c >> 1;
+      }
+      t[i] = c;
+    }
+  }
+};
+
+uint32_t crc32_ieee(const uint8_t* p, size_t n) {
+  static const Crc32Table table;
+  uint32_t c = 0xFFFFFFFFu;
+  for (size_t i = 0; i < n; ++i) c = table.t[(c ^ p[i]) & 0xFF] ^ (c >> 8);
+  return c ^ 0xFFFFFFFFu;
 }
 
 struct CmdReader {
@@ -1230,13 +1269,33 @@ void patch_u16(std::vector<uint8_t>* o, size_t pos, uint32_t v) {
 }
 
 // One outbound-datagram section (u16 count, then u16 ep / u32 len / bytes
-// per datagram) for one phase's streams, in endpoint order.
+// per datagram) for one phase's streams, in endpoint order, and one row of
+// the bank's outbound table per datagram (`o` is the whole tick output, so
+// a row's offset is absolute in it).
 void emit_out_section(std::vector<uint8_t>* o,
-                      std::vector<BankEndpoint>& endpoints, int phase) {
+                      std::vector<BankEndpoint>& endpoints, int phase,
+                      Bank* bank, uint32_t slot) {
   uint32_t count = 0;
   size_t count_pos = o->size();
   put_u16(o, 0);  // patched below
+  size_t pos = o->size();
   emit_out_records(o, endpoints, phase, false, &count);
+  for (uint32_t i = 0; i < count; ++i) {
+    const uint16_t ep = (*o)[pos] | ((*o)[pos + 1] << 8);
+    uint32_t dlen = 0;
+    for (int k = 0; k < 4; ++k) {
+      dlen |= static_cast<uint32_t>((*o)[pos + 2 + k]) << (8 * k);
+    }
+    pos += 6;
+    put_u32(&bank->wire, slot);
+    put_u16(&bank->wire, ep);
+    put_u8(&bank->wire, static_cast<uint8_t>(phase));
+    put_u32(&bank->wire, static_cast<uint32_t>(pos));
+    put_u32(&bank->wire, dlen);
+    put_u32(&bank->wire, crc32_ieee(o->data() + pos, dlen));
+    pos += dlen;
+  }
+  bank->wire_rows += count;
   patch_u16(o, count_pos, count);
 }
 
@@ -1809,6 +1868,10 @@ int ggrs_bank_set_timing(void* ptr, int enabled) {
 // desyncs (ggrs_bank_set_desync_detection), the wanted tail:
 //   u32 n_wanted, u32 reports_sent, u32 reports_compared, u32 desyncs;
 //   per wanted row: u32 slot, u32 0, i64 frame
+// Then the outbound table, always (kWireStride above):
+//   u32 n_rows;  per datagram of the records' n_out_poll / n_out_adv
+//     sections, in record order: u32 slot, u16 ep, u8 section, u32 off,
+//     u32 len, u32 crc32
 // Then, ONLY when ggrs_bank_set_timing armed the
 // phase timers (DESIGN.md §14):
 //   kNumPhases * u64 phase_ns, u64 tick_t0_ns, u8 n_phases   [timing tail:
@@ -1838,6 +1901,8 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
   // lead the output so the pool can classify all B slots AND build the
   // device dispatch (NumPy over the tables) before touching body bytes.
   bank->out.resize(bank->sessions.size() * (kHdrStride + kReqStride), 0);
+  bank->wire.clear();
+  bank->wire_rows = 0;
   size_t hdr_off = 0;
   size_t req_off = bank->sessions.size() * kHdrStride;
   std::vector<uint8_t> ops;
@@ -2281,8 +2346,9 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
           break;
         }
       }
-      emit_out_section(o, s->endpoints, 0);
-      emit_out_section(o, s->endpoints, 1);
+      const uint32_t slot = static_cast<uint32_t>(my_hdr / kHdrStride);
+      emit_out_section(o, s->endpoints, 0, bank, slot);
+      emit_out_section(o, s->endpoints, 1, bank, slot);
     }
     put_u16(o, n_out_events);
     put_raw(o, out_events.data(), out_events.size());
@@ -2355,6 +2421,9 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
     bank->cs_wanted.clear();
     bank->cs_sent = bank->cs_compares = bank->cs_desyncs = 0;
   }
+  // the outbound table: u32 n_rows, then the kWireStride rows
+  put_u32(&bank->out, bank->wire_rows);
+  put_raw(&bank->out, bank->wire.data(), bank->wire.size());
   if (pt.on) {
     // timing tail (count byte LAST so Python can parse from the end
     // without knowing the phase count up front): kNumPhases u64 ns then
@@ -2490,6 +2559,8 @@ int ggrs_bank_hdr_stride(void) { return static_cast<int>(kHdrStride); }
 // back to per-session Python sessions, like a header-stride mismatch.
 int ggrs_bank_req_stride(void) { return static_cast<int>(kReqStride); }
 int ggrs_bank_stage_stride(void) { return static_cast<int>(kStageStride); }
+// The outbound table's row stride (a library without it emits no table).
+int ggrs_bank_wire_stride(void) { return static_cast<int>(kWireStride); }
 
 // Batched input staging (descriptor plane, §21): stage MANY slots' local
 // inputs in ONE crossing.  `desc` is n records of kStageStride bytes

@@ -188,7 +188,10 @@ def both_tiers(monkeypatch, ticks, alter_at=None, **kw):
 @pytest.mark.parametrize("loss", [0.0, 0.05], ids=["lossless", "loss5"])
 def test_the_bank_sends_the_reports_the_python_tier_sends(monkeypatch, loss):
     interval = 5
-    python, bank = both_tiers(monkeypatch, 90, interval=interval, loss=loss)
+    # a registry of its own: the rollback count below is this bank's, not
+    # the process default's sum over every pool the process built before
+    python, bank = both_tiers(monkeypatch, 90, interval=interval, loss=loss,
+                              registry=Registry())
     assert bank.host.native_active and not python.host.native_active
     assert all(bank.host.slot_state(s) == "native"
                for s in range(bank.sessions))
@@ -444,7 +447,8 @@ def test_with_detection_off_the_tick_is_what_it_was(monkeypatch):
     """A pool whose sessions do not detect: no wanted tail in the tick
     output, no wanted rows on the plan, no fetch, no span, the ten
     descriptor arrays.  And until the first interval frame is confirmed a
-    detecting pool's output IS the other's, plus the empty tail."""
+    detecting pool's output IS the other's, plus the empty tail between
+    the body records and the outbound table."""
     tracer = default_tracer()
     tracer.switch(False)
     tracer.clear()
@@ -461,16 +465,26 @@ def test_with_detection_off_the_tick_is_what_it_was(monkeypatch):
         tracer.clear()
     tail = 10 * 8 + 8 + 1  # the timing tail of a traced tick: ten phases
     empty = bytes(16)  # no wanted row, nothing sent, compared or differing
-    for a, b in zip(outputs_off, outputs_on):
-        assert b[:-tail] == a[:-tail] + empty
-    assert "device.checksum_fetch" not in spans_off
-    assert "bank.checksum" not in spans_off  # the phase reads 0: no span
     host = off.host
     n = len(host._mirrors)
-    hdr = np.frombuffer(outputs_off[-1], np.dtype(
-        [("flags", "<u4"), ("rec_len", "<u4"), ("rest", "V40")]), count=n)
-    assert (len(outputs_off[-1]) - tail
-            == n * (48 + 24) + int(hdr["rec_len"].sum()))
+
+    def records_end(out):
+        hdr = np.frombuffer(out, np.dtype(
+            [("flags", "<u4"), ("rec_len", "<u4"), ("rest", "V40")]),
+            count=n)
+        return n * (48 + 24) + int(hdr["rec_len"].sum())
+
+    for a, b in zip(outputs_off, outputs_on):
+        end = records_end(a)
+        assert b[:-tail] == a[:end] + empty + a[end:-tail]
+    assert "device.checksum_fetch" not in spans_off
+    assert "bank.checksum" not in spans_off  # the phase reads 0: no span
+    # the records, then the outbound table (u32 rows, 19 bytes a row)
+    last = outputs_off[-1]
+    end = records_end(last)
+    rows = int.from_bytes(last[end:end + 4], "little")
+    assert rows > 0
+    assert len(last) - tail == end + 4 + 19 * rows
     assert host._plan.checksum_wanted is None
     assert not off.executor._digest_fetches
     off.run(40)

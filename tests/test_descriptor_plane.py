@@ -32,6 +32,7 @@ import ctypes
 import random
 import socket as pysocket
 import struct
+import zlib
 
 import numpy as np
 import pytest
@@ -644,13 +645,32 @@ class TestSendTable:
     def test_pool_outbound_rides_send_table_bit_identical(self):
         """A non-attached UDP pool's outbound goes through the one-
         crossing send table (descriptor leg) — the peer-observed byte
-        stream must equal the per-datagram reference leg
-        (GGRS_TPU_NO_FASTPATH)."""
+        stream, and the pool's flight recorder past the point where its
+        ring drops the oldest, must equal the per-datagram reference leg
+        (GGRS_TPU_NO_FASTPATH), through a transient send failure (the
+        datagram counts as lost on both legs)."""
+        import errno
         import os
 
         from ggrs_tpu.net.sockets import UdpNonBlockingSocket
 
         cfg = Config.for_uint(16)
+        lib = _native.net_lib()
+        drop_at = 60
+
+        class DroppingSocket(UdpNonBlockingSocket):
+            """The reference leg's twin of the table's injected transient
+            errno: once armed, the next datagram is lost as a transient
+            send error would lose it."""
+
+            armed = False
+
+            def send_datagram(self, data, addr):
+                if self.armed:
+                    self.armed = False
+                    self.stats.send_errors += 1
+                    return
+                super().send_datagram(data, addr)
 
         class TeeSocket:
             """Records every datagram the peer RECEIVES (the pool's
@@ -681,7 +701,7 @@ class TestSendTable:
             try:
                 clock = [0]
                 pool = HostSessionPool()
-                host_sock = UdpNonBlockingSocket(0)
+                host_sock = DroppingSocket(0)
                 peer_inner = UdpNonBlockingSocket(0)
                 peer_sock = TeeSocket(peer_inner)
                 peer_addr = ("127.0.0.1", peer_inner.local_port())
@@ -706,7 +726,7 @@ class TestSendTable:
                     assert pool._send_fds[0] is not None, (
                         "send table did not engage for a plain UDP socket"
                     )
-                for i in range(120):
+                for i in range(200):
                     clock[0] += 16
                     # the peer polls first (loopback delivery of last
                     # tick's pool sends is already complete), then the
@@ -714,18 +734,166 @@ class TestSendTable:
                     peer.add_local_input(1, i % 16)
                     fulfill_saves(peer.advance_frame())
                     pool.stage_inputs([(0, 0, i % 16)])
+                    if i == drop_at:
+                        if fastpath:
+                            # the tick's first send-table row fails
+                            lib.ggrs_net_inject_table_errno(
+                                errno.ENOBUFS, 0, 1)
+                        else:
+                            host_sock.armed = True
                     for reqs in pool.advance_all():
                         fulfill_saves(reqs)
-                return list(peer_sock.tape), pool.current_frame(0)
+                rec = pool.flight_recorder(0)
+                return (list(peer_sock.tape), pool.current_frame(0),
+                        (rec.events(), rec.dump(last=len(rec)),
+                         rec.recorded, len(rec)))
             finally:
+                lib.ggrs_net_inject_table_errno(0, 0, 0)
                 os.environ.pop("GGRS_TPU_NO_FASTPATH", None)
                 if prev is not None:
                     os.environ["GGRS_TPU_NO_FASTPATH"] = prev
 
-        ref_stream, ref_frame = leg(False)
-        fast_stream, fast_frame = leg(True)
+        ref_stream, ref_frame, ref_rec = leg(False)
+        fast_stream, fast_frame, fast_rec = leg(True)
         assert fast_stream == ref_stream, (
             f"peer-observed streams diverged ({len(fast_stream)} vs "
             f"{len(ref_stream)} datagrams)"
         )
-        assert fast_frame == ref_frame >= 100
+        assert fast_frame == ref_frame >= 180
+        assert fast_rec == ref_rec
+        assert ref_rec[2] > 256, "the recorder's ring never dropped"
+
+
+def _walk_sections(out, n, players, isize):
+    """The reference walk the outbound table replaces: every body record's
+    two outbound sections, positionally, as table rows.  Returns the rows
+    and where the body records end."""
+    hdr_dtype = np.dtype(list(_native.BANK_HDR_FIELDS))
+    hdr = np.frombuffer(out, hdr_dtype, count=n)
+    pos = n * (hdr_dtype.itemsize + _native.BANK_REQ_STRIDE)
+    rows = []
+    for slot in range(n):
+        start = pos
+        (n_ops,) = struct.unpack_from("<H", out, pos + 33)
+        p = pos + 35
+        for _ in range(n_ops):
+            p += 1 + (players * (1 + isize) if out[p] == 2 else 8)
+        for section in (0, 1):
+            (n_out,) = struct.unpack_from("<H", out, p)
+            p += 2
+            for _ in range(n_out):
+                ep, dlen = struct.unpack_from("<HI", out, p)
+                p += 6
+                rows.append((slot, ep, section, p, dlen,
+                             zlib.crc32(out[p : p + dlen])))
+                p += dlen
+        pos = start + int(hdr["rec_len"][slot])
+    return rows, pos
+
+
+@needs_native
+class TestOutboundTable:
+    @pytest.mark.parametrize("detect", [False, True],
+                             ids=["plain", "detecting"])
+    def test_rows_are_the_sections_walked(self, detect):
+        """Over fuzzed ticks, traced so the timing tail rides too: the
+        outbound table's rows are what a walk of every record's sections
+        finds, their crc32 is zlib's, and the wanted tail (where the bank
+        detects) and the timing tail still parse around the table."""
+        from ggrs_tpu.core.types import DesyncDetection
+        from ggrs_tpu.obs import default_tracer
+
+        clock = [0]
+        net = InMemoryNetwork(loss=0.08, duplicate=0.04, reorder=0.15,
+                              seed=77)
+        builders = two_peer_builders(net, clock, 3)
+        pool = HostSessionPool()
+        for b, s in builders:
+            if detect:
+                b = b.with_desync_detection_mode(DesyncDetection.on(5))
+            pool.add_session(b, s)
+        assert pool.native_active and pool._vectorized
+        n = len(pool)
+        m0 = pool._mirrors[0]
+        wire_dtype = np.dtype(list(_native.BANK_WIRE_FIELDS))
+        tracer = default_tracer()
+        tracer.switch(False)
+        tracer.clear()
+        tracer.switch(True)
+        seen = asked = 0
+        try:
+            for i in range(120):
+                clock[0] += 16
+                pool.stage_inputs(
+                    [(idx, idx % 2, (i + idx) % 16) for idx in range(n)]
+                )
+                for reqs in pool.advance_all():
+                    fulfill_saves(reqs)
+                net.tick()
+                out = bytes(pool._out_buf.raw[: pool._out_len.value])
+                walked, pos = _walk_sections(
+                    out, n, m0.num_players, m0.input_size
+                )
+                if detect:
+                    (wanted,) = struct.unpack_from("<I", out, pos)
+                    pos += 16 + 16 * wanted
+                    asked += wanted
+                (count,) = struct.unpack_from("<I", out, pos)
+                table = np.frombuffer(out, wire_dtype, count=count,
+                                      offset=pos + 4)
+                assert table.tolist() == walked, f"tick {i}"
+                end = n * (48 + 24) + int(np.frombuffer(
+                    out, np.dtype(list(_native.BANK_HDR_FIELDS)), count=n
+                )["rec_len"].sum())
+                assert pool._wire_table(end).tolist() == walked
+                phases, _ = pool._parse_timing_tail()
+                assert [p for p, _ in phases] == list(_native.BANK_PHASES)
+                assert pos + 4 + 19 * count + 8 * len(phases) + 9 == len(out)
+                seen += count
+        finally:
+            tracer.switch(False)
+            tracer.clear()
+        assert seen > 2 * 120
+        assert pool.desc_slow_slots > 0 and pool.fast_slot_ticks > 0
+        assert (asked > 0) == detect  # wanted rows rode ahead of the table
+
+    @needs_io
+    def test_attached_slots_add_no_rows(self):
+        """Slots whose sockets the bank drives itself send inside the
+        crossing: their sections are empty and the table holds no row of
+        theirs, while their datagrams still reach the peers."""
+        from ggrs_tpu.net.sockets import UdpNonBlockingSocket
+
+        cfg = Config.for_uint(16)
+        clock = [0]
+        pool = HostSessionPool(native_io=True)
+        peers = []
+        for m in range(2):
+            host = UdpNonBlockingSocket(0)
+            peer_sock = UdpNonBlockingSocket(0)
+            peer_addr = ("127.0.0.1", peer_sock.local_port())
+            pool.add_session(
+                SessionBuilder(cfg).with_clock(lambda: clock[0])
+                .with_rng(random.Random(3 + m))
+                .add_player(Local(), 0).add_player(Remote(peer_addr), 1),
+                host,
+            )
+            peers.append(
+                SessionBuilder(cfg).with_clock(lambda: clock[0])
+                .with_rng(random.Random(30 + m))
+                .add_player(Local(), 1)
+                .add_player(Remote(("127.0.0.1", host.local_port())), 0)
+                .start_p2p_session(peer_sock)
+            )
+        assert pool.native_io_active
+        for i in range(60):
+            clock[0] += 16
+            for peer in peers:
+                peer.add_local_input(1, i % 16)
+                fulfill_saves(peer.advance_frame())
+            pool.stage_inputs([(m, 0, i % 16) for m in range(2)])
+            for reqs in pool.advance_all():
+                fulfill_saves(reqs)
+            assert pool._wire_tick == (0, 0), f"tick {i}"
+        assert pool.fast_slot_ticks > 0
+        assert all(peer.current_frame > 50 for peer in peers)

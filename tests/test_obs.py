@@ -168,6 +168,53 @@ class TestFlightRecorder:
         rec.record(3, "wire", (1, 53, 0xAB12CD34))
         assert "ep=1 len=53B crc=ab12cd34" in rec.dump()
 
+    @pytest.mark.parametrize("capacity", [8, 1, 0])
+    def test_columnar_taps_read_as_one_ring(self, capacity):
+        """A recorder whose wire digests live in a pool's WireTaps store —
+        one at a time or scattered a tick at once, more than the ring
+        holds in one tick included — reads exactly what a recorder that
+        keeps them in its own deque reads: events, dump, recorded, len."""
+        import numpy as np
+
+        from ggrs_tpu.obs.recorder import WireTaps
+
+        taps = WireTaps(3, capacity)
+        cols = FlightRecorder(capacity, taps=taps, slot=1)
+        other = FlightRecorder(capacity, taps=taps, slot=2)
+        plain = FlightRecorder(capacity)
+        rng = random.Random(capacity)
+        for tick in range(60):
+            if rng.random() < 0.3:
+                cols.record(tick, "rollback", f"load frame {tick}")
+                plain.record(tick, "rollback", f"load frame {tick}")
+            for _ in range(rng.randrange(3)):
+                ep = rng.choice([0, 1, "spec0", "spec3"])
+                n, crc = rng.randrange(1, 99), rng.getrandbits(32)
+                cols.record_wire(tick, ep, n, crc)
+                plain.record(tick, "wire", (ep, n, crc))
+            k = rng.choice([0, 1, 2, 11])
+            eps = [rng.randrange(3) for _ in range(k)]
+            lens = [rng.randrange(1, 99) for _ in range(k)]
+            crcs = [rng.getrandbits(32) for _ in range(k)]
+            other.record(tick, "state", "a slot of its own")
+            taps.scatter(
+                np.array([1] * k + [2] * 2, np.intp), tick,
+                np.array(eps + [0, 0]), np.array(lens + [5, 6], np.uint32),
+                np.array(crcs + [7, 8], np.uint32),
+            )
+            for row in zip(eps, lens, crcs):
+                plain.record(tick, "wire", row)
+            if rng.random() < 0.2:
+                cols.record(tick, "state", "native -> quarantined")
+                plain.record(tick, "state", "native -> quarantined")
+            assert cols.events() == plain.events(), f"tick {tick}"
+            assert cols.events(last=5) == plain.events(last=5)
+            assert cols.dump(last=capacity) == plain.dump(last=capacity)
+            assert cols.recorded == plain.recorded
+            assert len(cols) == len(plain)
+        assert plain.recorded > capacity + 60
+        assert other.recorded == 60 * 3 and len(other) == min(capacity, 180)
+
 
 # ---------------------------------------------------------------------------
 # 2. metrics across the supervision state machine

@@ -7,7 +7,9 @@ here pins that path bit-identical to the legacy per-slot parser (the
 reference decoder, forced via ``GGRS_TPU_NO_FASTPATH=1``): request values,
 events, wire bytes, journal streams, frames — under seeded
 loss/dup/reorder, on the event-heavy blackout path, and across the
-eviction/export seams.  Plus: the crossing budget is untouched (still one
+eviction/export seams — and every slot's flight recorder (the wire taps
+the fast leg takes from the tick's outbound table), on the per-datagram
+and the batched send tiers, through send faults.  Plus: the crossing budget is untouched (still one
 tick crossing + one stats crossing per pool tick), the fast path actually
 engages, the B=256 scrape stays allocation-free (tracemalloc), and the
 supervision transition feed drains incrementally.
@@ -56,16 +58,81 @@ def _make_pool(builders, fastpath: bool, metrics=None):
     return pool
 
 
+class BatchRecordingSocket(RecordingSocket):
+    """A RecordingSocket on the batched send tier: it offers
+    ``send_datagram_batch`` (the fast leg's one call a slot a tick) and
+    ``send_datagram`` (the reference leg's call a datagram), and records
+    what both send in the same form."""
+
+    def send_datagram(self, data, addr):
+        self.sent.append((addr, bytes(data)))
+        self.inner.send_datagram(data, addr)
+
+    def send_datagram_batch(self, items):
+        for data, addr in items:
+            self.send_datagram(data, addr)
+
+
+class RawBomb(RecordingSocket):
+    """Per-datagram tier; once armed, the first datagram sent raises."""
+
+    armed = False
+
+    def send_to(self, msg, addr):
+        if self.armed:
+            self.armed = False
+            raise OSError("boom")
+        super().send_to(msg, addr)
+
+
+class BatchBomb(BatchRecordingSocket):
+    """Batched tier; once armed, the next input message raises before it
+    is sent.  With one remote endpoint the input message is the last
+    datagram of its slot's tick, so the fast leg, which taps the whole
+    slot before its one batched call, taps what the reference leg taps
+    before its failing call."""
+
+    armed = False
+
+    def send_datagram(self, data, addr):
+        if self.armed and data[2] == 0:  # u16 magic, then the input tag
+            self.armed = False
+            raise OSError("boom")
+        super().send_datagram(data, addr)
+
+
+_SOCKETS = {"raw": (RecordingSocket, RawBomb),
+            "batch": (BatchRecordingSocket, BatchBomb)}
+
+
+def assert_recorders_equal(pool_a, pool_b, where):
+    """Every slot's flight recorder reads the same on both legs: events,
+    dump, the count recorded and the count retained."""
+    for idx in range(len(pool_a)):
+        ra, rb = pool_a.flight_recorder(idx), pool_b.flight_recorder(idx)
+        assert ra.recorded == rb.recorded, f"{where} slot {idx}"
+        assert len(ra) == len(rb), f"{where} slot {idx}"
+        assert ra.events() == rb.events(), f"{where} slot {idx}"
+        assert ra.dump(last=len(ra)) == rb.dump(last=len(rb))
+
+
 def _drive_both(faults, ticks, n_matches=3, journals=None, blackout=None,
-                scrape_every=0):
+                scrape_every=0, sockets="raw", bomb=None):
     """Drive a vectorized and a legacy pool with identical seeded traffic;
-    compare requests, events, frames, and wire bytes every tick.  Returns
-    (fast_pool, legacy_pool)."""
+    compare requests, events, frames, wire bytes and flight recorders
+    every tick.  ``sockets`` picks the send tier (``_SOCKETS``); ``bomb``
+    ``(tick, slot)`` arms that slot's socket to fail a send at that tick.
+    Returns (fast_pool, legacy_pool, events seen)."""
     clock = [0]
     net_a = InMemoryNetwork(**faults)
     net_b = InMemoryNetwork(**faults)
     builders_a = two_peer_builders(net_a, clock, n_matches)
     builders_b = two_peer_builders(net_b, clock, n_matches)
+    plain, fused = _SOCKETS[sockets]
+    for builders in (builders_a, builders_b):
+        for k, (b, s) in enumerate(builders):
+            kind = fused if bomb is not None and k == bomb[1] else plain
+            builders[k] = (b, kind(s.inner))
     pool_a = _make_pool(builders_a, fastpath=True)
     pool_b = _make_pool(builders_b, fastpath=False)
     if journals is not None:
@@ -86,8 +153,13 @@ def _drive_both(faults, ticks, n_matches=3, journals=None, blackout=None,
             # the event-heavy slow path
             clock[0] += 300
         clock[0] += 16
+        if bomb is not None and i == bomb[0]:
+            builders_a[bomb[1]][1].armed = True
+            builders_b[bomb[1]][1].armed = True
         for idx in range(n):
             v = (i + idx) % 16
+            if pool_a.slot_state(idx) in ("dead", "migrated"):
+                continue
             pool_a.add_local_input(idx, idx % 2, v)
             pool_b.add_local_input(idx, idx % 2, v)
         reqs_a = pool_a.advance_all()
@@ -121,24 +193,39 @@ def _drive_both(faults, ticks, n_matches=3, journals=None, blackout=None,
                 f"tick {i} slot {idx}: wire bytes diverged "
                 f"({len(sa)} vs {len(sb)} datagrams)"
             )
+        assert_recorders_equal(pool_a, pool_b, f"tick {i}")
     return pool_a, pool_b, saw_events
 
 
 @needs_native
 class TestVectorizedParity:
-    @pytest.mark.parametrize("seed", [11, 23])
-    def test_fuzzed_traffic_bit_identical(self, seed):
+    @pytest.mark.parametrize("seed,sockets,bomb", [
+        (11, "raw", None), (23, "raw", None),
+        (11, "batch", None), (23, "batch", None),
+        (11, "raw", (70, 1)), (23, "batch", (70, 2)),
+    ], ids=["11", "23", "11-batch", "23-batch", "11-raw-fault",
+            "23-batch-fault"])
+    def test_fuzzed_traffic_bit_identical(self, seed, sockets, bomb):
         """Seeded loss/dup/reorder: the vectorized decode is bit-identical
-        to the legacy per-slot parser — and the fast path actually served
-        slots (the quiet majority)."""
+        to the legacy per-slot parser — flight recorders included, past
+        the point where each ring drops its oldest, with slow slots among
+        the fast ones, on both send tiers and through a send fault — and
+        the fast path actually served slots (the quiet majority)."""
         rng = random.Random(seed)
         faults = dict(
             loss=0.08, duplicate=0.04, reorder=0.15,
             seed=rng.randrange(1 << 30),
         )
-        pool_a, pool_b, _ = _drive_both(faults, ticks=180)
+        pool_a, pool_b, _ = _drive_both(faults, ticks=180, sockets=sockets,
+                                        bomb=bomb)
         assert pool_a.fast_slot_ticks > 0, "fast path never engaged"
         assert pool_b.fast_slot_ticks == 0, "legacy leg took the fast path"
+        assert pool_a.desc_slow_slots > 0, "no slow slot among the fast"
+        assert max(pool_a.flight_recorder(i).recorded
+                   for i in range(len(pool_a))) > 256, "no ring dropped"
+        if bomb is not None:
+            assert pool_a.slot_state(bomb[1]) != "native"
+            assert "boom" in pool_a.fault_log(bomb[1])[0].detail
 
     def test_event_heavy_blackout_path(self):
         """Clock-jump blackouts force interrupt/resume events and retry

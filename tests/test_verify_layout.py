@@ -40,6 +40,8 @@ from ggrs_tpu.analysis.layout import (
     LAYOUT_SEND_STRIDE,
     LAYOUT_STAGE_FIELDS,
     LAYOUT_STAGE_STRIDE,
+    LAYOUT_WIRE_FIELDS,
+    LAYOUT_WIRE_STRIDE,
     MIRRORED_CONSTANTS,
     _check_field_table,
     _check_header,
@@ -235,6 +237,29 @@ class TestDeliberateSkew:
         assert any("stride" in f.rule or "fields" in f.rule
                    for f in findings)
 
+    WIRE_GOOD = (
+        'BANK_WIRE_FIELDS = (\n'
+        '    ("slot", "<u4"), ("ep", "<u2"), ("section", "<u1"),\n'
+        '    ("off", "<u4"), ("len", "<u4"), ("crc", "<u4"),\n'
+        ')\n'
+    )
+
+    @pytest.mark.parametrize("drift", [
+        None,
+        ('("section", "<u1")', '("section", "<u2")'),
+        ('("off", "<u4"), ("len", "<u4")', '("len", "<u4"), ("off", "<u4")'),
+    ], ids=["clean", "one-byte", "order"])
+    def test_wire_table_row(self, tmp_path, drift):
+        """The outbound table's row: clean passes; a widened field (stride
+        20) or two swapped fields (same stride, offsets moved) fire."""
+        text = self.WIRE_GOOD if drift is None else \
+            self.WIRE_GOOD.replace(*drift)
+        findings = _check_field_table(
+            self._table_tree(tmp_path, text), "BANK_WIRE_FIELDS",
+            LAYOUT_WIRE_FIELDS, LAYOUT_WIRE_STRIDE,
+        )
+        assert bool(findings) == (drift is not None)
+
     def test_stage_big_endian_fires(self, tmp_path):
         root = self._table_tree(
             tmp_path,
@@ -393,6 +418,8 @@ class TestTreeIsClean:
              LAYOUT_REQ_STRIDE),
             (_native.BANK_STAGE_FIELDS, LAYOUT_STAGE_FIELDS,
              LAYOUT_STAGE_STRIDE),
+            (_native.BANK_WIRE_FIELDS, LAYOUT_WIRE_FIELDS,
+             LAYOUT_WIRE_STRIDE),
             (_native.NET_SEND_FIELDS, LAYOUT_SEND_FIELDS,
              LAYOUT_SEND_STRIDE),
             (_native.NET_RECV_FIELDS, LAYOUT_RECV_FIELDS,
@@ -412,6 +439,7 @@ class TestTreeIsClean:
             pytest.skip("no descriptor-plane library on this platform")
         assert int(lib.ggrs_bank_req_stride()) == LAYOUT_REQ_STRIDE
         assert int(lib.ggrs_bank_stage_stride()) == LAYOUT_STAGE_STRIDE
+        assert int(lib.ggrs_bank_wire_stride()) == LAYOUT_WIRE_STRIDE
 
     def test_gen2_tables_match_runtime_probes(self):
         """The §23 drain/route/fd strides and stat-table widths equal the
