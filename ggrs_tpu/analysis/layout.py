@@ -315,8 +315,14 @@ MIRRORED_CONSTANTS: Tuple[Tuple[str, str, str, str], ...] = (
      "ggrs_tpu/net/_native.py", "REQ_SAVE_ONLY"),
     ("native/session_bank.cpp", "kReqEmpty",
      "ggrs_tpu/net/_native.py", "REQ_EMPTY"),
+    ("native/session_bank.cpp", "kReqAdvOnly",
+     "ggrs_tpu/net/_native.py", "REQ_ADV_ONLY"),
+    ("native/session_bank.cpp", "kReqSparseResim",
+     "ggrs_tpu/net/_native.py", "REQ_SPARSE_RESIM"),
     ("native/session_bank.cpp", "kReqFlagTrailingAdv",
      "ggrs_tpu/net/_native.py", "REQ_FLAG_TRAILING_ADV"),
+    ("native/session_bank.cpp", "kReqFlagForced",
+     "ggrs_tpu/net/_native.py", "REQ_FLAG_FORCED"),
     ("native/net_batch.cpp", "kSendStride",
      "ggrs_tpu/net/_native.py", "NET_SEND_STRIDE"),
     # datapath gen 2 (§23): drain/route/fd strides, dispatch flag, stat

@@ -519,6 +519,11 @@ def _load() -> Optional[ctypes.CDLL]:
             lib.ggrs_bank_set_desync_detection.argtypes = [
                 ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
             ]
+            # GGRS's sparse saving inside the crossing (DESIGN.md §4)
+            lib.ggrs_bank_set_sparse_saving.restype = ctypes.c_int
+            lib.ggrs_bank_set_sparse_saving.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int,
+            ]
             if hasattr(lib, "ggrs_bank_set_timing"):
                 # in-crossing phase timers (tracing, DESIGN.md §14);
                 # absent on a prebuilt pre-trace .so — the pool then runs
@@ -754,7 +759,12 @@ REQ_QUIET = 1       # ops are exactly [save frame, advance]
 REQ_RESIM = 2       # [load frame, adv, (save, adv)*, save] (+ trailing adv)
 REQ_SAVE_ONLY = 3   # [save frame] — the prediction-limit tick
 REQ_EMPTY = 4       # no ops (skip / faulted records)
+REQ_ADV_ONLY = 5    # [advance] — a sparse session's quiet tick
+REQ_SPARSE_RESIM = 6  # [load frame, advance^n] with at most one save, of
+                      # frame + the advances before it; its frame is the
+                      # HEADER's save_frame (NULL_FRAME: none)
 REQ_FLAG_TRAILING_ADV = 1  # the tick's last op was an advance ("advanced")
+REQ_FLAG_FORCED = 2  # sparse saving's check_last_saved_state resimulated
 
 # The tick's outbound table (session_bank.cpp kWireStride): after the body
 # records and the wanted tail, u32 n_rows then one row per datagram of the
@@ -827,9 +837,9 @@ NET_RECV_TABLE_STATS = 14
 # events/spectator/consensus/dirty activity take the fast path — ops
 # decoded through pooled request objects, the events/mirror/spectator
 # sections JUMPED via rec_len.  The QUIET bit and save_frame field label
-# the canonical [save, advance] tick shape; they are classification
-# metadata (diagnostics, future specialized decoders) — the current fast
-# path decodes every op shape generically and does not read them.
+# the canonical [save, advance] tick shape (classification metadata); on a
+# REQ_SPARSE_RESIM row save_frame is the frame of its one save, which the
+# descriptor plane reads to place that save.
 BANK_HDR_LIVE = 1        # stepped this tick and err == 0
 BANK_HDR_QUIET = 2       # ops are exactly [save, advance]
 BANK_HDR_EVENTS = 4      # protocol events present

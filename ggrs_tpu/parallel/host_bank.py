@@ -193,6 +193,9 @@ _HDR_DTYPE = np.dtype(list(_native.BANK_HDR_FIELDS))
 # send record — all mirrored from session_bank.cpp / net_batch.cpp and
 # pinned by the ggrs-verify layout contract.
 _REQ_DTYPE = np.dtype(list(_native.BANK_REQ_FIELDS))
+# a plan's empty row column (read-only: every plan shares it)
+_NO_ROWS = np.empty(0, np.int64)
+_NO_ROWS.flags.writeable = False
 _STAGE_DTYPE = np.dtype(list(_native.BANK_STAGE_FIELDS))
 _SEND_DTYPE = np.dtype(list(_native.NET_SEND_FIELDS))
 _RECV_DTYPE = np.dtype(list(_native.NET_RECV_FIELDS))
@@ -400,8 +403,12 @@ def _bank_eligible(builder, hub_active: bool = False) -> bool:
 
     if not _native_sync_semantics_ok(cfg):
         return False
-    if builder._sparse_saving or builder._max_prediction < 1:
-        return False  # sparse saving / lockstep: fallback policy paths
+    if builder._max_prediction < 1:
+        return False  # lockstep: the fallback policy path
+    if builder._sparse_saving and builder._desync_detection.enabled:
+        # sparse saving runs inside the crossing (DESIGN.md §4), but the
+        # reference reports digests of frames it may never have saved
+        return False
     if builder._sync_handshake:
         return False  # (desync detection runs inside the crossing: §4)
     if builder._local_players < 1 or builder._num_players > 64:
@@ -456,22 +463,34 @@ class RequestPlan:
 
     ``quiet_rows``/``quiet_frames``  slot indices whose tick is exactly
         [save f, advance], and f per row;
+    ``adv_only_rows``/``adv_only_at``  slot indices whose tick is exactly
+        [advance] (a sparse session's quiet tick), and the absolute buffer
+        offset of that advance's status bytes per row;
     ``resim_rows``  ``(slot, load_frame, n_adv, trailing, adv_off,
         adv_stride)`` per rollback-resim slot (absolute buffer offsets);
+    ``sparse_resim_rows``  ``(slot, load_frame, n_adv, trailing, adv_off,
+        adv_stride, save_frame)`` per sparse-saving resim slot: the load,
+        ``n_adv`` advances ``adv_stride`` apart, and at most one save, of
+        ``save_frame`` (NULL_FRAME: none) before advance ``save_frame -
+        load_frame`` (the 9 bytes of its op shift the advances after it);
     ``save_only_rows``  ``(slot, frame)`` per prediction-limit slot;
     ``eager_rows``  slots whose lists were materialized at build time
         (slow/other/skip slots) — consume via ``plan[i]``;
     ``checksum_wanted``  ``(slots, frames)`` of the saved frames whose
         device digests the bank asked for (desync detection), else None;
-    ``gather_quiet()``  the quiet rows' advance payloads as
-        ``(statuses [k, players] u8, blobs [k, players, isize] u8)``,
-        one fancy-index gather, uniform pools only.
+    ``counts``  the plan's tally: advances, saves and loads over every
+        row kind;
+    ``gather_quiet()`` / ``gather_at(at)``  the quiet rows' (or the
+        advances at buffer offsets ``at``) payloads as ``(statuses [k,
+        players] u8, blobs [k, players, isize] u8)``, one fancy-index
+        gather, uniform pools only.
     """
 
     __slots__ = (
         "pool", "tick_no", "lists", "buffer", "players", "input_size",
         "uniform", "quiet_rows", "quiet_frames", "quiet_offs",
-        "quiet_adv_off", "resim_rows", "save_only_rows", "eager_rows",
+        "quiet_adv_off", "adv_only_rows", "adv_only_at", "resim_rows",
+        "sparse_resim_rows", "save_only_rows", "eager_rows",
         "offs_l", "live_l", "checksum_wanted",
     )
 
@@ -491,7 +510,11 @@ class RequestPlan:
         self.quiet_frames: Optional[np.ndarray] = None
         self.quiet_offs: Optional[np.ndarray] = None
         self.quiet_adv_off: Optional[np.ndarray] = None
+        self.adv_only_rows: np.ndarray = _NO_ROWS
+        self.adv_only_at: np.ndarray = _NO_ROWS
         self.resim_rows: List[Tuple[int, int, int, bool, int, int]] = []
+        self.sparse_resim_rows: List[
+            Tuple[int, int, int, bool, int, int, int]] = []
         self.save_only_rows: List[Tuple[int, int]] = []
         self.eager_rows: List[int] = []
         self.offs_l: List[int] = []
@@ -521,17 +544,45 @@ class RequestPlan:
         descriptor path fulfills save cells without request objects."""
         return self.pool._mirrors[i].saved_states
 
-    def gather_quiet(self) -> Tuple[np.ndarray, np.ndarray]:
-        """All quiet rows' advance payloads in one fancy-index gather."""
-        rows = self.quiet_rows
-        k = int(rows.size)
+    @property
+    def counts(self) -> Dict[str, int]:
+        """Advances, saves and loads the plan asks for, over every row
+        kind: the columns' shapes, and the eager rows' request lists by
+        type (``benchmark/roofline.py`` reads it)."""
+        quiet = int(self.quiet_rows.size) if self.quiet_rows is not None else 0
+        advances = quiet + int(self.adv_only_rows.size)
+        saves = quiet + len(self.save_only_rows)
+        loads = len(self.resim_rows) + len(self.sparse_resim_rows)
+        for _, _, n_adv, trailing, _, _ in self.resim_rows:
+            advances += n_adv
+            saves += n_adv - 1 if trailing else n_adv
+        for row in self.sparse_resim_rows:
+            advances += row[2]
+            saves += row[6] != NULL_FRAME
+        for b in self.eager_rows:
+            for req in self.lists[b] or ():
+                if isinstance(req, AdvanceFrame):
+                    advances += 1
+                elif isinstance(req, SaveGameState):
+                    saves += 1
+                elif isinstance(req, LoadGameState):
+                    loads += 1
+        return {"advances": advances, "saves": saves, "loads": loads}
+
+    def gather_at(self, at: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """The payloads of the advances whose status bytes start at buffer
+        offsets ``at``, in one fancy-index gather."""
+        k = int(at.size)
         players, isize = self.players, self.input_size
-        base = self.quiet_offs + self.quiet_adv_off
         span = players * (1 + isize)
-        flat = self.buffer[base[:, None] + np.arange(span)]
+        flat = self.buffer[at[:, None] + np.arange(span)]
         statuses = flat[:, :players]
         blobs = flat[:, players:].reshape(k, players, isize)
         return statuses, blobs
+
+    def gather_quiet(self) -> Tuple[np.ndarray, np.ndarray]:
+        """All quiet rows' advance payloads in one fancy-index gather."""
+        return self.gather_at(self.quiet_offs + self.quiet_adv_off)
 
 
 class _EndpointMirror:
@@ -910,6 +961,15 @@ class HostSessionPool:
         self._m_rollbacks = m.counter(
             "ggrs_pool_rollbacks_total",
             "rollback decisions executed by pooled slots")
+        self._m_rollback_frames = m.counter(
+            "ggrs_pool_rollback_frames_total",
+            "frames pooled slots resimulated (the bank's "
+            "stat_rollback_frames, counted from each tick's loads)")
+        self._m_forced_resims = m.counter(
+            "ggrs_pool_forced_resims_total",
+            "rollbacks sparse saving's check_last_saved_state forced "
+            "(the last save about to leave the prediction window), not a "
+            "misprediction")
         # desync detection inside the bank: counted in the crossing, read
         # from the wanted tail (one report a remote endpoint a frame)
         self._m_cs_sent = m.counter(
@@ -1071,6 +1131,10 @@ class HostSessionPool:
         # ctrl op 4)
         self._detects = False
         self._digests: Dict[int, List[Tuple[int, int, int]]] = {}
+        # a session of the bank saves sparsely (GGRS's sparse saving inside
+        # the crossing, DESIGN.md §4): the tick reads the request table's
+        # forced-resim flags
+        self._sparse = False
         # ---- broadcast subsystem seams (ggrs_tpu/broadcast) ----
         # _spectator_hub: the SpectatorHub that owns relay policy for this
         # pool (set by SpectatorHub.__init__, must precede finalization);
@@ -1295,6 +1359,13 @@ class HostSessionPool:
                         f"ggrs_bank_set_desync_detection failed: {rc}"
                     )
                 self._detects = True
+            if builder._sparse_saving:
+                rc = lib.ggrs_bank_set_sparse_saving(self._bank, idx, 1)
+                if rc != 0:
+                    raise RuntimeError(
+                        f"ggrs_bank_set_sparse_saving failed: {rc}"
+                    )
+                self._sparse = True
             mirror = _SessionMirror(
                 cfg, socket, builder._num_players, builder._max_prediction,
                 local_handles,
@@ -2198,6 +2269,15 @@ class HostSessionPool:
             # (a bug in THIS builder, no per-session blame possible)
             self._invalid = f"ggrs_bank_tick failed: {rc}"
             raise RuntimeError(self._invalid)
+        if self._sparse and self._has_req:
+            # the resimulations sparse saving forced this tick, whichever
+            # decoder reads the slots: one flag a row of the request table
+            n = len(self._mirrors)
+            rflags = np.frombuffer(self._out_buf, _REQ_DTYPE, count=n,
+                                   offset=n * self._hdr_stride)["rflags"]
+            forced = int(np.count_nonzero(rflags & _native.REQ_FLAG_FORCED))
+            if forced:
+                self._m_forced_resims.inc(forced)
         # decode: the descriptor plane's lazy RequestPlan by default
         # (DESIGN.md §21 — classification AND request programs read from
         # the two flat tables, request objects only materialized on
@@ -2217,6 +2297,8 @@ class HostSessionPool:
                         eager=len(request_lists.eager_rows),
                         resim=len(request_lists.resim_rows),
                         save_only=len(request_lists.save_only_rows),
+                        adv_only=int(request_lists.adv_only_rows.size),
+                        sparse_resim=len(request_lists.sparse_resim_rows),
                         slots=len(self._mirrors),
                         wire_rows=wire_rows,
                         datagrams=datagrams,
@@ -2683,19 +2765,26 @@ class HostSessionPool:
         plan.quiet_frames = req["frame"][quiet]
         plan.quiet_offs = offs[quiet]
         plan.quiet_adv_off = req["adv_off"][quiet].astype(np.int64)
+        adv_only = fast & (pattern == _native.REQ_ADV_ONLY)
+        if adv_only.any():
+            plan.adv_only_rows = np.flatnonzero(adv_only)
+            plan.adv_only_at = offs[adv_only] + req["adv_off"][adv_only]
 
         # request-kind metrics, vectorized from the descriptor columns
         # (eager slots count inside _parse_slot as before)
         resim = fast & (pattern == _native.REQ_RESIM)
+        sparse_resim = fast & (pattern == _native.REQ_SPARSE_RESIM)
         save_only = fast & (pattern == _native.REQ_SAVE_ONLY)
         trailing = (req["rflags"] & _native.REQ_FLAG_TRAILING_ADV) != 0
         n_adv_col = req["n_adv"].astype(np.int64)
         n_quiet = int(plan.quiet_rows.size)
-        n_resim = int(np.count_nonzero(resim))
+        loading = resim | sparse_resim
+        n_resim = int(np.count_nonzero(loading))
         n_save = n_quiet + int(np.count_nonzero(save_only)) + int(
             (n_adv_col[resim] - trailing[resim]).sum()
-        )
-        n_adv_total = n_quiet + int(n_adv_col[resim].sum())
+        ) + int(np.count_nonzero(hdr["save_frame"][sparse_resim] != NULL_FRAME))
+        n_adv_total = (n_quiet + int(np.count_nonzero(adv_only))
+                       + int(n_adv_col[loading].sum()))
         if n_save:
             self._m_req_save.inc(n_save)
         if n_resim:
@@ -2714,6 +2803,10 @@ class HostSessionPool:
         # plain-int columns once, not per-row structured indexing (resim
         # ticks visit hundreds of rows on a rollback-heavy pool)
         rframe_l = req["frame"].tolist()
+        # a sparse resim's one save frame rides the header (every-frame
+        # pools hold none: no column taken)
+        save_frame_l = (hdr["save_frame"].tolist() if sparse_resim.any()
+                        else None)
         n_adv_l = req["n_adv"].tolist()
         adv_off_l = req["adv_off"].tolist()
         adv_stride_l = req["adv_stride"].tolist()
@@ -2737,7 +2830,7 @@ class HostSessionPool:
         if taps is not None:
             # a resim slot's rollback is recorded ahead of its datagrams,
             # where the reference decoder meets its load op
-            for idx in np.flatnonzero(resim).tolist():
+            for idx in np.flatnonzero(loading).tolist():
                 recorders[idx].record(
                     self._tick_no, EV_ROLLBACK,
                     f"load frame {rframe_l[idx]} (was at "
@@ -2890,6 +2983,12 @@ class HostSessionPool:
                 + np.bincount(w_slot, weights=w_len, minlength=n).astype(
                     np.int64)
             ).tolist()
+        # the frames the tick's resims went back over: from where each slot
+        # stood, the frame its mirror holds until the walk below
+        resimulated = sum(mirrors[idx].current_frame - rframe_l[idx]
+                          for idx in np.flatnonzero(loading).tolist())
+        if resimulated:
+            self._m_rollback_frames.inc(resimulated)
         for idx in np.flatnonzero(fast).tolist():
             m = mirrors[idx]
             failed = idx in flush_failed
@@ -2913,8 +3012,14 @@ class HostSessionPool:
                 if m.staged_native and trailing_l[idx]:
                     isize = m.input_size
                     po = offs_l[idx] + adv_off_l[idx]
-                    if pattern_l[idx] == _native.REQ_RESIM:
-                        po += (n_adv_l[idx] - 1) * adv_stride_l[idx]
+                    if pattern_l[idx] in (_native.REQ_RESIM,
+                                          _native.REQ_SPARSE_RESIM):
+                        last = n_adv_l[idx] - 1
+                        po += last * adv_stride_l[idx]
+                        if (pattern_l[idx] == _native.REQ_SPARSE_RESIM
+                                and 0 < save_frame_l[idx] - rframe_l[idx]
+                                <= last):
+                            po += 9  # the save op before the last advance
                     bo = po + m.num_players
                     for h in m.local_handles:
                         m.staged_inputs[h] = bytes(
@@ -2960,6 +3065,12 @@ class HostSessionPool:
                         idx, lf, n_adv_l[idx], trailing_l[idx],
                         offs_l[idx] + adv_off_l[idx], adv_stride_l[idx],
                     ))
+                elif pat == _native.REQ_SPARSE_RESIM:
+                    plan.sparse_resim_rows.append((
+                        idx, rframe_l[idx], n_adv_l[idx], trailing_l[idx],
+                        offs_l[idx] + adv_off_l[idx], adv_stride_l[idx],
+                        save_frame_l[idx],
+                    ))
                 elif pat == _native.REQ_SAVE_ONLY:
                     plan.save_only_rows.append((idx, rframe_l[idx]))
                 # ---- policy (the fast-slot subset: no events, no
@@ -2998,6 +3109,9 @@ class HostSessionPool:
             plan.quiet_frames = plan.quiet_frames[keep]
             plan.quiet_offs = plan.quiet_offs[keep]
             plan.quiet_adv_off = plan.quiet_adv_off[keep]
+            keep = ~np.isin(plan.adv_only_rows, dead)
+            plan.adv_only_rows = plan.adv_only_rows[keep]
+            plan.adv_only_at = plan.adv_only_at[keep]
             plan.eager_rows.extend(flush_failed)
 
         self.fast_slot_ticks += n_fast
@@ -3161,6 +3275,7 @@ class HostSessionPool:
                     advanced = False
                     self._m_req_load.inc()
                     self._m_rollbacks.inc()
+                    self._m_rollback_frames.inc(m.current_frame - frame)
                     if rec is not None:
                         rec.record(
                             self._tick_no, EV_ROLLBACK,
@@ -3507,6 +3622,7 @@ class HostSessionPool:
                     advanced = False
                     self._m_req_load.inc()
                     self._m_rollbacks.inc()
+                    self._m_rollback_frames.inc(m.current_frame - frame)
                     if rec is not None:
                         rec.record(
                             self._tick_no, EV_ROLLBACK,

@@ -75,6 +75,7 @@ from ..core.types import (
     Frame,
     GgrsRequest,
     LoadGameState,
+    NULL_FRAME,
     SaveGameState,
 )
 from ..obs.registry import default_registry
@@ -1029,18 +1030,11 @@ class BatchedRequestExecutor:
             frame, ref, cs if self._with_checksums else None
         )
 
-    def _fill_resim(self, plan, desc: Descriptor, b: int,
-                    lf: int, n_adv: int, trailing: bool, adv_off: int,
-                    adv_stride: int) -> None:
-        """One rollback-resim slot straight from its descriptor row:
-        load→advance^N with interleaved saves, inputs gathered from the
-        tick output buffer — the ``load→advance^N→save`` program selection
-        of DESIGN.md §21, no request objects."""
-        cells = plan.saved_states(b)
-        cell = cells.get_cell(lf)
-        data = cell.data()
-        # the same two guards the request path applies through the
-        # LoadGameState cell: ownership and ring capacity
+    def _check_load(self, cells, b: int, lf: int, n_adv: int) -> None:
+        """The guards the request path applies through a LoadGameState
+        cell, for a resim filled from its descriptor row: ownership and
+        ring capacity; and the burst's depth."""
+        data = cells.get_cell(lf).data()
         if not (
             isinstance(data, _BatchSlotRef)
             and data.owner is self
@@ -1063,6 +1057,16 @@ class BatchedRequestExecutor:
                 f"session {b}: tick carries more than max_burst="
                 f"{self.max_burst} advances"
             )
+
+    def _fill_resim(self, plan, desc: Descriptor, b: int,
+                    lf: int, n_adv: int, trailing: bool, adv_off: int,
+                    adv_stride: int) -> None:
+        """One rollback-resim slot straight from its descriptor row:
+        load→advance^N with interleaved saves, inputs gathered from the
+        tick output buffer — the ``load→advance^N→save`` program selection
+        of DESIGN.md §21, no request objects."""
+        cells = plan.saved_states(b)
+        self._check_load(cells, b, lf, n_adv)
         desc["do_load"][b] = True
         desc["load_frame"][b] = lf
         desc["n_adv"][b] = n_adv
@@ -1083,6 +1087,38 @@ class BatchedRequestExecutor:
                 desc["save_frame"][b, j] = f
                 self._fulfill_fast(cells, b, f)
 
+    def _fill_sparse_resims(self, plan, desc: Descriptor) -> None:
+        """The plan's sparse-saving resim rows straight from their
+        descriptors: a load, ``n_adv`` advances, and at most one save, of
+        ``save_frame``, which sits before advance ``k = save_frame - lf``:
+        right after the load for k = 0 (the post-load save), else after
+        advance k - 1.  Every row's advances are gathered from the tick
+        output in one go: such rows are up to ``max_burst`` deep."""
+        rows, steps, at = [], [], []
+        for (b, lf, n_adv, _, adv_off, adv_stride,
+             save_frame) in plan.sparse_resim_rows:
+            cells = plan.saved_states(b)
+            self._check_load(cells, b, lf, n_adv)
+            desc["do_load"][b] = True
+            desc["load_frame"][b] = lf
+            desc["n_adv"][b] = n_adv
+            k = save_frame - lf if save_frame != NULL_FRAME else -1
+            for j in range(n_adv):
+                rows.append(b)
+                steps.append(j)
+                # the save's op (9 bytes) shifts the advances after it
+                at.append(adv_off + j * adv_stride + (9 if 0 < k <= j else 0))
+            if k == 0:
+                desc["postload_save"][b] = True
+                desc["postload_frame"][b] = save_frame
+            elif k > 0:
+                desc["save_mask"][b, k - 1] = True
+                desc["save_frame"][b, k - 1] = save_frame
+            if k >= 0:
+                self._fulfill_fast(cells, b, save_frame)
+        statuses, blobs = plan.gather_at(np.asarray(at, np.int64))
+        desc["inputs"][rows, steps] = self._raw_inputs(blobs, statuses)
+
     def _run_plan(self, plan) -> None:
         """The descriptor-plane run() (DESIGN.md §21): consume a
         ``HostSessionPool`` RequestPlan's flat columns directly — quiet
@@ -1099,10 +1135,12 @@ class BatchedRequestExecutor:
                 "the next advance_all"
             )
         rows = plan.quiet_rows
+        adv_only = plan.adv_only_rows
         eager = list(plan.eager_rows)
         vector = self._raw_inputs is not None and plan.uniform
         any_work = bool(
-            rows.size or plan.resim_rows or plan.save_only_rows
+            rows.size or adv_only.size or plan.resim_rows
+            or plan.sparse_resim_rows or plan.save_only_rows
         ) or any(plan.lists[b] for b in eager)
         if not any_work:
             _OBS_EMPTY_TICKS.inc()
@@ -1129,6 +1167,15 @@ class BatchedRequestExecutor:
                         # no bulk converter / non-uniform pool: quiet slots
                         # materialize like any other (reference semantics)
                         eager.extend(rows.tolist())
+                    if vector and adv_only.size:
+                        # a sparse session's quiet tick: advance, no save
+                        desc["n_adv"][adv_only] = 1
+                        statuses, blobs = plan.gather_at(plan.adv_only_at)
+                        desc["inputs"][adv_only, 0] = self._raw_inputs(
+                            blobs, statuses
+                        )
+                    else:
+                        eager.extend(adv_only.tolist())
                     for (b, lf, n_adv, trailing, adv_off,
                          adv_stride) in plan.resim_rows:
                         if vector:
@@ -1136,10 +1183,19 @@ class BatchedRequestExecutor:
                                              trailing, adv_off, adv_stride)
                         else:
                             eager.append(b)
+                    if vector and plan.sparse_resim_rows:
+                        self._fill_sparse_resims(plan, desc)
+                    elif plan.sparse_resim_rows:
+                        eager.extend(row[0] for row in plan.sparse_resim_rows)
                     for b in eager:
                         reqs = plan[b]
                         if reqs:
                             self._parse(b, reqs, desc)
+                    # the ring writes and the rows, with the save-only rows
+                    # the fulfill below adds (each its one pre-save)
+                    saves, rows_carried = self._ring_writes(desc)
+                    only = len(plan.save_only_rows)
+                    writes = (saves + only, rows_carried + only)
                 # then the one Python call a quiet or save-only row
                 # (ROADMAP A1 (iii))
                 with tracer.span(
@@ -1155,15 +1211,28 @@ class BatchedRequestExecutor:
                         desc["pre_save"][b] = True
                         desc["pre_frame"][b] = f
                         self._fulfill_fast(plan.saved_states(b), b, f)
-                self._count_dispatch(desc, fill)
+                self._count_dispatch(desc, fill, writes)
             self._launch(desc)
         except BaseException as e:  # incl. KeyboardInterrupt mid-fill
             self._invalid = f"{type(e).__name__}: {e}"
             raise
 
-    def _count_dispatch(self, desc: Descriptor, fill) -> None:
+    @staticmethod
+    def _ring_writes(desc: Descriptor) -> Tuple[int, int]:
+        """``(saves, rows)``: the ring writes a descriptor carries
+        (pre-save, post-load and per-step) and the sessions it carries."""
+        pre = desc["pre_save"]
+        saves = (np.count_nonzero(pre) + np.count_nonzero(desc["postload_save"])
+                 + np.count_nonzero(desc["save_mask"]))
+        rows = np.count_nonzero(pre | desc["do_load"]
+                                | desc["n_adv"].astype(bool))
+        return int(saves), int(rows)
+
+    def _count_dispatch(self, desc: Descriptor, fill,
+                        writes: Tuple[int, int]) -> None:
         """The filled descriptor's counts, on the registry and on the
-        ``device.fill`` span that ends here."""
+        ``device.fill`` span that ends here; ``writes`` is its
+        ``_ring_writes``."""
         loads = int(desc["do_load"].sum())
         max_burst = int(desc["n_adv"].max())
         _OBS_DISPATCHES.inc()
@@ -1171,8 +1240,12 @@ class BatchedRequestExecutor:
         _OBS_BURST_DEPTH.observe(max_burst)
         # max_burst is the tick program's trip count, burst_cap the most it
         # can be: their ratio says how much of the burst loop the traffic
-        # left out (benchmark: burst_steps_share)
-        fill.set(loads=loads, max_burst=max_burst, burst_cap=self.max_burst)
+        # left out (benchmark: burst_steps_share).  saves over rows: how
+        # many of the sessions it carries write the ring, and how often
+        # (benchmark: save_rows_share)
+        saves, rows = writes
+        fill.set(loads=loads, max_burst=max_burst, burst_cap=self.max_burst,
+                 saves=saves, rows=rows)
 
     def _launch(self, desc: Descriptor) -> None:
         """The call of the tick program: transfer of the descriptor and
@@ -1300,7 +1373,7 @@ class BatchedRequestExecutor:
                 for b, reqs in enumerate(request_lists):
                     if reqs:
                         self._parse(b, reqs, desc)
-                self._count_dispatch(desc, fill)
+                self._count_dispatch(desc, fill, self._ring_writes(desc))
             self._launch(desc)
         except BaseException as e:  # incl. KeyboardInterrupt mid-parse
             self._invalid = f"{type(e).__name__}: {e}"
@@ -1370,6 +1443,13 @@ class BatchedRequestExecutor:
         assert self._with_checksums, "pool was built with with_checksums=False"
         _, lanes = self._slot_probe(index, frame)
         return checksum_to_u128(lanes)
+
+    def ring_frames(self) -> np.ndarray:
+        """``[B, ring_length]``: the frame each ring slot holds, as the
+        device tags it (negative: never written), fetched to host — the
+        tags ``ring_state`` and ``ring_checksum`` validate a slot against."""
+        self._check_valid()
+        return np.asarray(jax.device_get(self._carry["ring"]["frames"]))
 
     def block_until_ready(self) -> None:
         with self.tracer.root_span("device.fence", tick=self._dispatched_tick):

@@ -88,6 +88,17 @@ _OBS_ROLLBACK_DEPTH = default_registry().histogram(
     "frames resimulated per Python-path rollback",
     buckets=(1, 2, 4, 8, 16, 32),
 )
+# the twins of the session bank's ggrs_pool_rollback_frames_total and
+# ggrs_pool_forced_resims_total, so the two sides of the bank read alike
+_OBS_ROLLBACK_FRAMES = default_registry().counter(
+    "ggrs_session_rollback_frames_total",
+    "frames Python-path sessions resimulated",
+)
+_OBS_FORCED_RESIMS = default_registry().counter(
+    "ggrs_session_forced_resims_total",
+    "rollbacks sparse saving's check_last_saved_state forced in "
+    "Python-path sessions, not a misprediction",
+)
 
 
 class PlayerRegistry(Generic[I, A]):
@@ -700,6 +711,7 @@ class P2PSession(ThreadOwned, Generic[I, S, A]):
             self._stat_max_rollback = count
         _OBS_ROLLBACKS.inc()
         _OBS_ROLLBACK_DEPTH.observe(count)
+        _OBS_ROLLBACK_FRAMES.inc(count)
 
         requests.append(self._sync_layer.load_frame(frame_to_load))
         assert self._sync_layer.current_frame == frame_to_load
@@ -808,6 +820,7 @@ class P2PSession(ThreadOwned, Generic[I, S, A]):
             if confirmed_frame >= self._sync_layer.current_frame:
                 requests.append(self._sync_layer.save_current_state())
             else:
+                _OBS_FORCED_RESIMS.inc()
                 self._adjust_gamestate(last_saved, confirmed_frame, requests)
 
             assert confirmed_frame == NULL_FRAME or self._sync_layer.last_saved_frame == min(

@@ -177,22 +177,37 @@ constexpr size_t kStageStride = 24;
 //                with sequential save frames f+1.. — the rollback resim
 //   kReqSaveOnly ops are exactly [save f]                   (prediction limit)
 //   kReqEmpty    no ops (skip / faulted records)
-//   kReqOther    anything else (frame-0 double save, future shapes):
-//                consumers fall back to the generic op decoder
+//   kReqAdvOnly  ops are exactly [advance]         (a sparse session's quiet
+//                tick: it saves nothing)
+//   kReqSparseResim  [load f, advance^n] with at most ONE save among them,
+//                of the frame the advances before it reach: f + k for a
+//                save before the k-th advance (k = 0: the post-load save,
+//                k = n: after the last) — the sparse-saving rollback resim,
+//                a trailing live advance included in n.  The save's frame
+//                rides the HEADER's save_frame (kNullFrame: no save)
+//   kReqOther    anything else (frame-0 double save, a tick with two loads,
+//                future shapes): consumers fall back to the generic op
+//                decoder
 // Fields (offsets mirrored by _native.BANK_REQ_FIELDS):
-//   u8 pattern, u8 rflags (bit0 = the tick ended on an advance op),
+//   u8 pattern, u8 rflags (bit0 = the tick ended on an advance op; bit1 =
+//   sparse saving's check_last_saved_state forced a resimulation this tick),
 //   u16 n_adv, u32 adv_off (record-relative offset of the FIRST advance
 //   op's status bytes), u32 adv_stride (byte distance between consecutive
-//   advances' status bytes), u32 ops_end (record-relative offset just past
+//   advances' status bytes; in a sparse resim between two advances with no
+//   save between them), u32 ops_end (record-relative offset just past
 //   the ops section — where the outbound sections start), i64 frame (save
-//   frame for quiet/save-only, load frame for resim, kNullFrame otherwise)
+//   frame for quiet/save-only, load frame for both resims, kNullFrame
+//   otherwise)
 constexpr size_t kReqStride = 24;
 constexpr uint8_t kReqOther = 0;
 constexpr uint8_t kReqQuiet = 1;
 constexpr uint8_t kReqResim = 2;
 constexpr uint8_t kReqSaveOnly = 3;
 constexpr uint8_t kReqEmpty = 4;
+constexpr uint8_t kReqAdvOnly = 5;
+constexpr uint8_t kReqSparseResim = 6;
 constexpr uint8_t kReqFlagTrailingAdv = 1;
+constexpr uint8_t kReqFlagForced = 2;
 
 // ---- the tick's outbound table (DESIGN.md §21) ---------------------------
 // After the body records (and the wanted tail where one is emitted) the tick
@@ -215,8 +230,9 @@ constexpr size_t kWireStride = 19;
 // pool's vectorized fast path: pooled request objects refilled from the
 // ops section, the events/mirror/spectator sections jumped via rec_len.
 // kHdrQuiet + save_frame label the canonical [save, advance] tick shape —
-// classification metadata for diagnostics and future specialized
-// decoders; the current fast path decodes op shapes generically.  Stride
+// classification metadata; on a kReqSparseResim row save_frame is the
+// frame of its one save (kNullFrame: none), which the descriptor plane
+// reads to place that save.  Stride
 // and flag values are mirrored by _native.BANK_HDR_*;
 // ggrs_bank_hdr_stride() is the presence/version probe (absent symbol =
 // pre-header layout).
@@ -262,6 +278,9 @@ struct ReqDesc {
   uint32_t adv_stride = 0;
   uint32_t ops_end = 35;    // record-relative end of the ops section
   int64_t frame = kNullFrame;
+  // kReqSparseResim only: the frame of its one save (kNullFrame: none).
+  // Not a field of the table: it rides the header's save_frame
+  int64_t save_frame = kNullFrame;
 };
 
 void req_patch(std::vector<uint8_t>* o, size_t off, const ReqDesc& d) {
@@ -317,6 +336,13 @@ ReqDesc classify_ops(const std::vector<uint8_t>& ops, uint16_t n_ops,
     d.frame = ops_i64_at(ops, 1);
     d.n_adv = 1;
     d.adv_off = 35 + 10;
+    d.rflags |= kReqFlagTrailingAdv;
+    return d;
+  }
+  if (n_ops == 1 && ops[0] == 2 && ops.size() == adv_size) {
+    d.pattern = kReqAdvOnly;  // [advance]: a sparse session's quiet tick
+    d.n_adv = 1;
+    d.adv_off = 35 + 1;
     d.rflags |= kReqFlagTrailingAdv;
     return d;
   }
@@ -390,6 +416,32 @@ ReqDesc classify_ops(const std::vector<uint8_t>& ops, uint16_t n_ops,
     if (ok) {
       d.pattern = kReqResim;
       d.frame = lf;
+      return d;
+    }
+  }
+  // [load f, advance^n] with at most one save of f + (advances before it):
+  // the sparse resim.  Advances with no save between them sit adv_size
+  // apart; the save (9 bytes) shifts the advances after it.
+  if (shape[0].first == 1 && n_ops >= 2 && !adv_offs.empty()) {
+    int64_t lf = shape[0].second;
+    int64_t save_frame = kNullFrame;
+    int64_t advanced = 0;
+    bool ok = true;
+    for (size_t i = 1; ok && i < shape.size(); ++i) {
+      if (shape[i].first == 2) {
+        advanced += 1;
+      } else if (shape[i].first == 0 && save_frame == kNullFrame &&
+                 shape[i].second == lf + advanced) {
+        save_frame = shape[i].second;
+      } else {
+        ok = false;
+      }
+    }
+    if (ok) {
+      d.pattern = kReqSparseResim;
+      d.frame = lf;
+      d.adv_stride = static_cast<uint32_t>(adv_size);
+      d.save_frame = save_frame;
       return d;
     }
   }
@@ -567,6 +619,10 @@ struct BankSession {
   int64_t cs_interval = 0;
   int64_t cs_last_asked = kNullFrame, cs_last_sent = kNullFrame;
   int64_t last_saved = kNullFrame;  // sync_layer.last_saved_frame
+  // GGRS's sparse saving (p2p.py _sparse_saving, set at attach by
+  // ggrs_bank_set_sparse_saving): save only confirmed frames, roll back to
+  // the last save
+  bool sparse = false;
   std::vector<FrameDigest> cs_local;      // reported digests, frame order
   std::vector<FrameDigest> cs_delivered;  // this tick's ctrl op 4 records
   // ---- observability accumulators (ggrs_bank_stats) ----
@@ -1415,10 +1471,52 @@ void emit_status_mirrors(std::vector<uint8_t>* o, const BankSession* s) {
   }
 }
 
+// p2p.py _adjust_gamestate: load `frame_to_load` and resimulate forward to
+// the current frame.  Saving every frame saves each resimulated frame but
+// the one just loaded; sparse saving saves exactly the `min_confirmed`
+// frame on the way forward, which may be the frame just loaded.
+int resimulate(BankSession* s, int64_t frame_to_load, int64_t min_confirmed,
+               std::vector<uint8_t>* ops, uint16_t* n_ops) {
+  const int players = s->num_players;
+  int64_t count = s->current_frame - frame_to_load;
+  s->stat_rollbacks += 1;
+  s->stat_rollback_frames += static_cast<uint64_t>(count);
+  if (static_cast<uint64_t>(count) > s->stat_max_rollback) {
+    s->stat_max_rollback = static_cast<uint64_t>(count);
+  }
+  put_u8(ops, 1);
+  put_i64(ops, frame_to_load);
+  ++*n_ops;
+  s->current_frame = frame_to_load;
+  ggrs_sync_reset_prediction(s->sync);
+  for (int64_t i = 0; i < count; ++i) {
+    if (s->sparse ? s->current_frame == min_confirmed : i > 0) {
+      put_u8(ops, 0);
+      put_i64(ops, s->current_frame);
+      ++*n_ops;
+      s->last_saved = s->current_frame;
+    }
+    int rc = ggrs_sync_synchronized_inputs(
+        s->sync, s->current_frame, s->local_disc.data(),
+        s->local_last.data(), s->sync_buf.data(), s->status_buf.data());
+    if (rc != kOk) return kBankErrSyncInputs;
+    s->current_frame += 1;
+    put_u8(ops, 2);
+    for (int p = 0; p < players; ++p) {
+      put_u8(ops, static_cast<uint8_t>(s->status_buf[p]));
+    }
+    put_raw(ops, s->sync_buf.data(),
+            static_cast<size_t>(players) * s->input_size);
+    ++*n_ops;
+  }
+  return kBankOk;
+}
+
 int advance_session(Bank* bank, BankSession* s, int64_t now,
                     const uint8_t* local_inputs, std::vector<uint8_t>* ops,
                     uint16_t* n_ops, int64_t* landed_out,
-                    int64_t* frames_ahead_out, PhaseTimer* pt) {
+                    int64_t* frames_ahead_out, bool* forced_out,
+                    PhaseTimer* pt) {
   const int players = s->num_players;
   const int isize = s->input_size;
   pt->skip();
@@ -1428,6 +1526,7 @@ int advance_session(Bank* bank, BankSession* s, int64_t now,
     put_u8(ops, 0);
     put_i64(ops, 0);
     ++*n_ops;
+    s->last_saved = 0;
   }
 
   // confirmed frame: min last-received over connected players
@@ -1444,47 +1543,35 @@ int advance_session(Bank* bank, BankSession* s, int64_t now,
       ggrs_sync_check_consistency(s->sync, s->disconnect_frame);
   if (first_incorrect != kNullFrame) {
     if (first_incorrect < s->current_frame) {
-      // _adjust_gamestate, non-sparse: load first_incorrect, resim forward
-      int64_t frame_to_load = first_incorrect;
-      int64_t count = s->current_frame - frame_to_load;
-      s->stat_rollbacks += 1;
-      s->stat_rollback_frames += static_cast<uint64_t>(count);
-      if (static_cast<uint64_t>(count) > s->stat_max_rollback) {
-        s->stat_max_rollback = static_cast<uint64_t>(count);
-      }
-      put_u8(ops, 1);
-      put_i64(ops, frame_to_load);
-      ++*n_ops;
-      s->current_frame = frame_to_load;
-      ggrs_sync_reset_prediction(s->sync);
-      for (int64_t i = 0; i < count; ++i) {
-        if (i > 0) {
-          put_u8(ops, 0);
-          put_i64(ops, s->current_frame);
-          ++*n_ops;
-        }
-        int rc = ggrs_sync_synchronized_inputs(
-            s->sync, s->current_frame, s->local_disc.data(),
-            s->local_last.data(), s->sync_buf.data(), s->status_buf.data());
-        if (rc != kOk) return kBankErrSyncInputs;
-        s->current_frame += 1;
-        put_u8(ops, 2);
-        for (int p = 0; p < players; ++p) {
-          put_u8(ops, static_cast<uint8_t>(s->status_buf[p]));
-        }
-        put_raw(ops, s->sync_buf.data(),
-                static_cast<size_t>(players) * isize);
-        ++*n_ops;
-      }
+      // _adjust_gamestate: under sparse saving only the last save survives
+      int rc = resimulate(s, s->sparse ? s->last_saved : first_incorrect,
+                          confirmed, ops, n_ops);
+      if (rc != kBankOk) return rc;
     }
     s->disconnect_frame = kNullFrame;
   }
 
-  // per-frame save of the current state (non-sparse mode)
-  put_u8(ops, 0);
-  put_i64(ops, s->current_frame);
-  ++*n_ops;
-  s->last_saved = s->current_frame;
+  if (!s->sparse) {
+    // per-frame save of the current state
+    put_u8(ops, 0);
+    put_i64(ops, s->current_frame);
+    ++*n_ops;
+    s->last_saved = s->current_frame;
+  } else if (s->current_frame - s->last_saved >= s->max_prediction) {
+    // _check_last_saved_state: before the last save slides out of the
+    // prediction window, save the current frame if it is confirmed, else
+    // resimulate from the last save, saving the confirmed frame on the way
+    if (confirmed >= s->current_frame) {
+      put_u8(ops, 0);
+      put_i64(ops, s->current_frame);
+      ++*n_ops;
+      s->last_saved = s->current_frame;
+    } else {
+      *forced_out = true;
+      int rc = resimulate(s, s->last_saved, confirmed, ops, n_ops);
+      if (rc != kBankOk) return rc;
+    }
+  }
   pt->lap(kPhRollback);
 
   // broadcast fan-out + journal tap: BEFORE set_last_confirmed discards the
@@ -1495,10 +1582,12 @@ int advance_session(Bank* bank, BankSession* s, int64_t now,
   }
   pt->lap(kPhFanout);
 
-  // confirmed-frame watermark (policy minimums applied: non-sparse, so only
-  // the never-past-current clamp)
-  int64_t watermark =
-      confirmed < s->current_frame ? confirmed : s->current_frame;
+  // confirmed-frame watermark (sync_layer.py set_last_confirmed_frame's
+  // minimums: never past the last save under sparse saving, whose rollback
+  // target it would discard, and never past the current frame)
+  int64_t watermark = confirmed;
+  if (s->sparse && s->last_saved < watermark) watermark = s->last_saved;
+  if (s->current_frame < watermark) watermark = s->current_frame;
   if (ggrs_sync_set_last_confirmed(s->sync, watermark) != kOk) {
     return kBankErrConfirm;
   }
@@ -1803,6 +1892,20 @@ int ggrs_bank_set_desync_detection(void* ptr, int64_t session,
     bank->cs_sessions += interval > 0 ? 1 : -1;
   }
   s->cs_interval = interval;
+  return kBankOk;
+}
+
+// GGRS's sparse saving for one session (p2p.py _sparse_saving): set at
+// attach, before the session's first tick, from the builder.
+int ggrs_bank_set_sparse_saving(void* ptr, int64_t session, int enabled) {
+  Bank* bank = static_cast<Bank*>(ptr);
+  if (session < 0 ||
+      static_cast<size_t>(session) >= bank->sessions.size()) {
+    return kBankErrCmd;
+  }
+  BankSession* s = bank->sessions[static_cast<size_t>(session)];
+  if (s->current_frame != 0) return kBankErrCmd;
+  s->sparse = enabled != 0;
   return kBankOk;
 }
 
@@ -2164,6 +2267,7 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
     int64_t landed = kNullFrame;
     int64_t frames_ahead = 0;
     bool pending_consensus = false;
+    bool forced = false;  // check_last_saved_state resimulated (sparse)
     ops.clear();
     uint16_t n_ops = 0;
     if (err == kBankOk) {
@@ -2266,7 +2370,7 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
           pt.lap(kPhChecksum);
         }
         int rc = advance_session(bank, s, now, local_inputs, &ops, &n_ops,
-                                 &landed, &frames_ahead, &pt);
+                                 &landed, &frames_ahead, &forced, &pt);
         if (rc != kBankOk) err = rc;
       } else {
         frames_ahead = max_frame_advantage(s);
@@ -2372,6 +2476,12 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
       }
       save_frame = static_cast<int64_t>(u);
     }
+    // request descriptor (§21): classified from the ops the record carries
+    // (a faulted slot's ops were cleared above, so it classifies kReqEmpty).
+    // A sparse resim's one save frame rides the header's save_frame
+    ReqDesc rd = classify_ops(ops, n_ops, s->num_players, s->input_size);
+    if (rd.pattern == kReqSparseResim) save_frame = rd.save_frame;
+    if (forced && err == kBankOk) rd.rflags |= kReqFlagForced;
     uint32_t hflags = 0;
     if (err == kBankOk) hflags |= kHdrLive;
     if (quiet) hflags |= kHdrQuiet;
@@ -2385,9 +2495,6 @@ static int bank_tick_impl(Bank* bank, int64_t now, const uint8_t* cmd,
               static_cast<uint32_t>(o->size() - rec_start),
               static_cast<int32_t>(err), static_cast<int32_t>(frames_ahead),
               landed, s->current_frame, s->last_confirmed, save_frame);
-    // request descriptor (§21): classified from the ops the record carries
-    // (a faulted slot's ops were cleared above, so it classifies kReqEmpty)
-    ReqDesc rd = classify_ops(ops, n_ops, s->num_players, s->input_size);
     req_patch(o, my_req, rd);
     if ((flags & kFlagStaged) && err == kBankOk &&
         (rd.rflags & kReqFlagTrailingAdv)) {

@@ -314,12 +314,16 @@ def test_a_slot_evicted_from_the_bank_keeps_reporting_on_the_grid(
     (lambda b: b.with_desync_detection_mode(DesyncDetection.on(1)), True),
     (lambda b: b, True),
     (lambda b: b.with_sync_handshake(True), False),
-    (lambda b: b.with_sparse_saving_mode(True), False),
+    (lambda b: b.with_sparse_saving_mode(True), True),
+    (lambda b: b.with_sparse_saving_mode(True).with_desync_detection_mode(
+        DesyncDetection.on(10)), False),
     (lambda b: b.with_max_prediction_window(0), False),
-], ids=["detect10", "detect1", "plain", "handshake", "sparse", "lockstep"])
+], ids=["detect10", "detect1", "plain", "handshake", "sparse",
+        "sparse_detect", "lockstep"])
 def test_eligibility(tweak, native):
-    """Detection is inside the bank's scope; the handshake, sparse saving
-    and lockstep still are not."""
+    """Detection and sparse saving are inside the bank's scope, each
+    alone; the handshake, lockstep, and detection under sparse saving (the
+    reference reports digests of frames it may never have saved) are not."""
     net = InMemoryNetwork()
     pool = HostSessionPool()
     for me in (0, 1):

@@ -121,3 +121,167 @@ class TestParticleWorld:
         # only if the resimulation did: both sessions go on alike
         pool.run([[adv(masks[60])], [adv(masks[60])]])
         assert not _differ(pool.live_state(0), pool.live_state(1))
+
+
+# ---- sparse saving through the served path ---------------------------------
+
+SPARSE_MATCHES, DELAY, WINDOW = 4, 2, 8
+# masks change until STEADY, then every player holds its last: frames past
+# it take that mask whatever the tick that pressed it, so the reference
+# needs no account of the frames a stalled session dropped
+STEADY, BLACKOUT, SPARSE_TICKS = 40, range(50, 62), 100
+
+
+def _held_rows(seed):
+    """``[tick, match, player]`` masks, each held for 4-11 ticks."""
+    rng = np.random.default_rng(seed)
+    rows = np.zeros((SPARSE_TICKS, SPARSE_MATCHES, PLAYERS), np.uint8)
+    for m in range(SPARSE_MATCHES):
+        for p in range(PLAYERS):
+            t = 0
+            while t < STEADY:
+                hold = int(rng.integers(4, 12))
+                rows[t : t + hold, m, p] = rng.integers(0, 16)
+                t += hold
+    rows[STEADY:] = rows[STEADY - 1]
+    return rows
+
+
+def _frame_input(rows, f):
+    """What frame ``f`` is simulated with: the row pressed ``DELAY`` ticks
+    earlier, the blank input before any could arrive."""
+    if f < DELAY:
+        return np.zeros(rows.shape[1:], np.uint8)
+    return rows[min(f - DELAY, STEADY)]
+
+
+def _serve_sparse(rows):
+    """``SPARSE_MATCHES`` particle matches saving sparsely, both peers of a
+    match in one ``HostedPool`` over one link of 3 ticks that goes dark for
+    ``BLACKOUT`` (the sessions stall at the prediction limit, and the
+    resimulation check_last_saved_state forces saves right after its load).
+    Returns the host pool, the executor and the plans the executor ran."""
+    from ggrs_tpu.core import Local, Remote
+    from ggrs_tpu.games import boxgame_config
+    from ggrs_tpu.net import InMemoryNetwork
+    from ggrs_tpu.obs import Registry
+    from ggrs_tpu.parallel import HostedPool, HostSessionPool
+    from ggrs_tpu.sessions import SessionBuilder
+    import random
+
+    world = _world()
+    clock = [0]
+    net = InMemoryNetwork(seed=5, latency_ticks=3)
+    host = HostSessionPool(metrics=Registry())
+    for m in range(SPARSE_MATCHES):
+        for k in range(PLAYERS):
+            b = (
+                SessionBuilder(boxgame_config())
+                .with_num_players(PLAYERS)
+                .with_clock(lambda: clock[0])
+                .with_rng(random.Random(7 * m + k))
+                .with_max_prediction_window(WINDOW)
+                .with_input_delay(DELAY)
+                .with_sparse_saving_mode(True)
+            )
+            for j in range(PLAYERS):
+                b = b.add_player(Local() if j == k else Remote(f"m{m}p{j}"), j)
+            host.add_session(b, net.socket(f"m{m}p{k}"))
+    executor = BatchedRequestExecutor(
+        world.advance, world.init_state(),
+        lambda pairs: np.asarray([p[0] for p in pairs], np.uint8),
+        batch_size=SPARSE_MATCHES * PLAYERS, ring_length=WINDOW + 2,
+        max_burst=WINDOW + 1,
+        raw_inputs_to_array=lambda blobs, statuses: blobs[:, :, 0],
+    )
+    executor.warmup(np.zeros((PLAYERS,), np.uint8))
+    hosted = HostedPool(host, executor)
+    plans = []
+    run = executor.run
+
+    def recording(plan):
+        if getattr(plan, "quiet_rows", None) is not None:
+            plans.append((plan.tick_no, len(plan.eager_rows),
+                          int(plan.adv_only_rows.size),
+                          list(plan.sparse_resim_rows)))
+        run(plan)
+
+    executor.run = recording
+    for t in range(len(rows)):
+        clock[0] = (t * 1000) // 60
+        net.loss = 1.0 if t in BLACKOUT else 0.0
+        hosted.tick([(m * PLAYERS + k, k, int(rows[t, m, k]))
+                     for m in range(SPARSE_MATCHES) for k in range(PLAYERS)])
+        net.tick()
+    hosted.block_until_ready()
+    return host, executor, plans
+
+
+class TestSparseSavingPool:
+    def test_sparse_sessions_on_the_bank_match_the_oracle_and_the_python_sessions(
+            self, monkeypatch):
+        """Bank -> plan -> executor for sessions that save sparsely: every
+        live state and every ring slot equals the NumPy oracle's replay, and
+        the same pool run off the bank (its Python sessions, the policy's
+        reference) holds the same frames in the same slots.  The plans take
+        the sparse row kinds, none goes to the eager decoder once the
+        sessions run, and a save right after a load occurs."""
+        from ggrs_tpu.parallel import host_bank
+
+        world = _world()
+        rows = np.concatenate(
+            [_held_rows(6), np.repeat(_held_rows(6)[-1:], 3 * WINDOW, 0)])
+        host, on_bank, plans = _serve_sparse(rows)
+        assert host.native_active
+        assert all(host.slot_state(s) == "native" for s in range(len(host)))
+        running = [p for p in plans if p[0] > 8]
+        assert running and all(eager == 0 for _, eager, _, _ in running)
+        assert sum(adv for _, _, adv, _ in running) > 0
+        sparse = [row for *_, resims in running for row in resims]
+        assert sparse
+        assert any(save == load for _, load, *_, save in sparse)  # post-load
+
+        monkeypatch.setattr(host_bank, "_bank_eligible",
+                            lambda builder, hub_active=False: False)
+        off, off_bank, _ = _serve_sparse(rows)
+        assert not off.native_active
+
+        frames = on_bank.ring_frames()
+        np.testing.assert_array_equal(frames, off_bank.ring_frames())
+        last = max(host.current_frame(s) for s in range(len(host)))
+        ref = [world.init_state_np()]
+        for f in range(last):
+            ref.append(world.advance_np(ref[-1], _frame_input(rows, f)[0]))
+        for s in range(len(host)):
+            m, f = s // PLAYERS, host.current_frame(s)
+            assert f == off.current_frame(s)
+            want = _replay_np(world, [_frame_input(rows, g)[m] for g in range(f)])
+            for ex in (on_bank, off_bank):
+                assert not _differ(ex.live_state(s), want), s
+            held = sorted(int(g) for g in frames[s] if g >= 0)
+            # a sparse ring: confirmed frames further apart than a window
+            assert held and held[-1] - held[0] > WINDOW + 1, held
+            for g in held:
+                want = _replay_np(world, [_frame_input(rows, h)[m]
+                                          for h in range(g)])
+                for ex in (on_bank, off_bank):
+                    assert not _differ(ex.ring_state(s, g), want), (s, g)
+                    assert ex.ring_checksum(s, g) == pytree_checksum(want)
+
+    def test_ring_frames_are_the_carrys_frame_tags(self):
+        world = _world()
+        pool = BatchedRequestExecutor(
+            world.advance, world.init_state(),
+            lambda pairs: np.asarray([p[0] for p in pairs], np.uint8),
+            batch_size=2, ring_length=4, max_burst=3,
+        )
+        pool.warmup(np.zeros((PLAYERS,), np.uint8))
+        cells = [GameStateCell() for _ in range(4)]
+        row = AdvanceFrame([(0, InputStatus.CONFIRMED)] * PLAYERS)
+        for f in range(6):
+            pool.run([[SaveGameState(cells[f % 4], f), row], [row]])
+        frames = pool.ring_frames()
+        np.testing.assert_array_equal(
+            frames, np.asarray(jax.device_get(pool._carry["ring"]["frames"])))
+        assert sorted(frames[0].tolist()) == [2, 3, 4, 5]
+        assert (frames[1] < 0).all()

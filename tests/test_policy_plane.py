@@ -117,17 +117,18 @@ def assert_recorders_equal(pool_a, pool_b, where):
 
 
 def _drive_both(faults, ticks, n_matches=3, journals=None, blackout=None,
-                scrape_every=0, sockets="raw", bomb=None):
+                scrape_every=0, sockets="raw", bomb=None, sparse=False):
     """Drive a vectorized and a legacy pool with identical seeded traffic;
     compare requests, events, frames, wire bytes and flight recorders
     every tick.  ``sockets`` picks the send tier (``_SOCKETS``); ``bomb``
-    ``(tick, slot)`` arms that slot's socket to fail a send at that tick.
+    ``(tick, slot)`` arms that slot's socket to fail a send at that tick;
+    ``sparse`` builds sessions that save sparsely.
     Returns (fast_pool, legacy_pool, events seen)."""
     clock = [0]
     net_a = InMemoryNetwork(**faults)
     net_b = InMemoryNetwork(**faults)
-    builders_a = two_peer_builders(net_a, clock, n_matches)
-    builders_b = two_peer_builders(net_b, clock, n_matches)
+    builders_a = two_peer_builders(net_a, clock, n_matches, sparse=sparse)
+    builders_b = two_peer_builders(net_b, clock, n_matches, sparse=sparse)
     plain, fused = _SOCKETS[sockets]
     for builders in (builders_a, builders_b):
         for k, (b, s) in enumerate(builders):
@@ -226,6 +227,22 @@ class TestVectorizedParity:
         if bomb is not None:
             assert pool_a.slot_state(bomb[1]) != "native"
             assert "boom" in pool_a.fault_log(bomb[1])[0].detail
+
+    @pytest.mark.parametrize("seed,sockets,bomb", [
+        (11, "raw", None), (23, "batch", (70, 2)),
+    ], ids=["11", "23-batch-fault"])
+    def test_sparse_saving_bit_identical(self, seed, sockets, bomb):
+        """Sessions that save sparsely: the fast plane's advance-only and
+        sparse resim rows decode to what the legacy parser reads, through
+        a send fault on a slot whose staged inputs it re-feeds."""
+        faults = dict(loss=0.08, duplicate=0.04, reorder=0.15,
+                      seed=random.Random(seed).randrange(1 << 30))
+        pool_a, pool_b, _ = _drive_both(faults, ticks=180, sockets=sockets,
+                                        bomb=bomb, sparse=True)
+        assert pool_a.fast_slot_ticks > 0, "fast path never engaged"
+        assert pool_b.fast_slot_ticks == 0, "legacy leg took the fast path"
+        if bomb is not None:
+            assert pool_a.slot_state(bomb[1]) != "native"
 
     def test_event_heavy_blackout_path(self):
         """Clock-jump blackouts force interrupt/resume events and retry

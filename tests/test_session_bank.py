@@ -47,9 +47,11 @@ class RecordingSocket:
         return self.inner.receive_all_messages()
 
 
-def two_peer_builders(net, clock, n_matches, input_delay=0, bits=16):
+def two_peer_builders(net, clock, n_matches, input_delay=0, bits=16,
+                      sparse=False):
     """2·n_matches sessions (n_matches 2-peer matches) over ``net``; the
-    SAME construction for the bank and the reference sessions."""
+    SAME construction for the bank and the reference sessions.  ``sparse``:
+    every session saves sparsely (GGRS's sparse saving)."""
     out = []
     for m in range(n_matches):
         names = (f"A{m}", f"B{m}")
@@ -62,11 +64,13 @@ def two_peer_builders(net, clock, n_matches, input_delay=0, bits=16):
                 .add_player(Local(), me)
                 .add_player(Remote(names[1 - me]), 1 - me)
             )
+            if sparse:
+                b = b.with_sparse_saving_mode(True)
             out.append((b, RecordingSocket(net.socket(names[me]))))
     return out
 
 
-def four_peer_builders(net, clock):
+def four_peer_builders(net, clock, sparse=False):
     """One 4-peer match: 4 sessions, 3 remote endpoints each."""
     names = [f"N{h}" for h in range(4)]
     out = []
@@ -77,6 +81,8 @@ def four_peer_builders(net, clock):
             .with_clock(lambda: clock[0])
             .with_rng(random.Random(40 + h))
         )
+        if sparse:
+            b = b.with_sparse_saving_mode(True)
         for o in range(4):
             b = b.add_player(Local() if o == h else Remote(names[o]), o)
         out.append((b, RecordingSocket(net.socket(names[h]))))
@@ -101,7 +107,7 @@ def assert_requests_equal(py_reqs, bank_reqs, context):
             assert a.frame == b.frame, (context, a.frame, b.frame)
 
 
-def run_parity(builders_fn, faults, ticks, local_of, sched):
+def run_parity(builders_fn, faults, ticks, local_of, sched, metrics=None):
     """Drive the bank and the per-session Python reference with identical
     traffic on identically-seeded fault networks; compare everything."""
     clock = [0]
@@ -110,7 +116,7 @@ def run_parity(builders_fn, faults, ticks, local_of, sched):
     bank_builders = builders_fn(net_bank, clock)
     py_builders = builders_fn(net_py, clock)
 
-    pool = HostSessionPool()
+    pool = HostSessionPool(metrics=metrics)
     for b, s in bank_builders:
         pool.add_session(b, s)
     py_sessions = [b.start_p2p_session(s) for b, s in py_builders]
@@ -128,8 +134,17 @@ def run_parity(builders_fn, faults, ticks, local_of, sched):
             fulfill_saves(r)
             py_reqs.append(r)
         bank_reqs = pool.advance_all()
+        # the tally a plan states is what its requests ask for
+        counts = getattr(bank_reqs, "counts", None)
+        asked = dict(advances=0, saves=0, loads=0)
         for r in bank_reqs:
             fulfill_saves(r)
+            for req in r:
+                kind = type(req).__name__
+                asked["advances"] += kind == "AdvanceFrame"
+                asked["saves"] += kind == "SaveGameState"
+                asked["loads"] += kind == "LoadGameState"
+        assert counts is None or counts == asked, (i, counts, asked)
         net_bank.tick()
         net_py.tick()
         for idx in range(n):
@@ -261,6 +276,136 @@ class TestCrossCoreParityFuzz:
         assert all(pool.current_frame(i) >= 150 for i in range(n))
 
 
+# the Python sessions' twins of the bank's rollback counters
+_SESSION_TWINS = {
+    "ggrs_pool_rollbacks_total": "ggrs_session_rollbacks_total",
+    "ggrs_pool_rollback_frames_total": "ggrs_session_rollback_frames_total",
+    "ggrs_pool_forced_resims_total": "ggrs_session_forced_resims_total",
+}
+
+
+def run_sparse_parity(builders_fn, faults, ticks, local_of, sched):
+    """``run_parity`` over sessions that save sparsely, which must also
+    count their rollbacks alike on both sides of the bank: the pool's
+    counters against the Python sessions' twins, and the frames resimulated
+    against the bank's own per-slot stats."""
+    from ggrs_tpu.obs import Registry
+    from ggrs_tpu.obs.registry import default_registry
+
+    sessions = default_registry()
+    before = {k: sessions.value(k) or 0 for k in _SESSION_TWINS.values()}
+    metrics = Registry()
+    pool = run_parity(builders_fn, faults, ticks, local_of, sched, metrics)
+    counted = {k: metrics.value(k) or 0 for k in _SESSION_TWINS}
+    for pool_name, twin in _SESSION_TWINS.items():
+        assert counted[pool_name] == (sessions.value(twin) or 0) - before[twin], (
+            pool_name, counted)
+    assert counted["ggrs_pool_rollback_frames_total"] == sum(
+        rec["rollback_frames"] for rec in pool.scrape())
+    return pool, counted
+
+
+@needs_native
+class TestSparseSavingParity:
+    """GGRS's sparse saving inside the crossing (DESIGN.md §4): the bank
+    emits the op stream ``P2PSession`` emits — the rollback to the last
+    save, the one save of the confirmed frame on the way forward, no save
+    on a quiet tick, check_last_saved_state's forced resimulation, the
+    watermark held at the last save — bit for bit, as the pins above hold
+    the every-frame sessions."""
+
+    def test_two_peer_matches_faultless(self):
+        # inputs held 12 ticks under 2 ticks of latency: the last save ages
+        # out of the window between mispredictions, so forced resims run
+        pool, counted = run_sparse_parity(
+            lambda net, clock: two_peer_builders(net, clock, n_matches=2,
+                                                 sparse=True),
+            dict(latency_ticks=2),
+            ticks=300,
+            local_of=lambda idx: idx % 2,
+            sched=lambda i, idx: ((i + 5 * idx) // 12) % 16,
+        )
+        assert counted["ggrs_pool_forced_resims_total"] > 0
+        # a forced resim goes back the whole prediction window
+        assert counted["ggrs_pool_rollback_frames_total"] > \
+            4 * counted["ggrs_pool_rollbacks_total"]
+        assert pool.fast_slot_ticks > 0
+
+    @pytest.mark.parametrize("seed", [1, 7, 23])
+    def test_two_peer_matches_under_faults(self, seed):
+        run_sparse_parity(
+            lambda net, clock: two_peer_builders(net, clock, n_matches=3,
+                                                 sparse=True),
+            dict(seed=seed, loss=0.05, duplicate=0.03, reorder=0.03,
+                 latency_ticks=1),
+            ticks=300,
+            local_of=lambda idx: idx % 2,
+            sched=lambda i, idx: ((i + 2 * idx) // (2 + idx % 3)) % 16,
+        )
+
+    def test_four_peer_match_under_faults(self):
+        run_sparse_parity(
+            lambda net, clock: four_peer_builders(net, clock, sparse=True),
+            dict(seed=99, loss=0.04, duplicate=0.02, reorder=0.04,
+                 latency_ticks=1),
+            ticks=300,
+            local_of=lambda idx: idx,
+            sched=lambda i, idx: ((i * 7 + idx) // 9) % 16,
+        )
+
+
+    @pytest.mark.parametrize("delay", [0, 2])
+    def test_an_evicted_sparse_slot_resumes_saving_sparsely(self, delay):
+        """A sparse slot faulted at any point of its save cycle (the last
+        save 0-8 frames back) resumes on the Python fallback from its last
+        committed frame, still saving sparsely, and its record of its own
+        inputs equals what its peer holds."""
+        from ggrs_tpu.chaos import fulfill
+        from ggrs_tpu.parallel.host_bank import SLOT_EVICTED
+
+        for at in range(40, 49):
+            clock = [0]
+            net = InMemoryNetwork(latency_ticks=2)
+            pool = HostSessionPool()
+            made = []
+            for me, name in ((0, "L"), (1, "R")):
+                made.append(
+                    SessionBuilder(Config.for_uint(16))
+                    .with_clock(lambda: clock[0])
+                    .with_rng(random.Random(1 + me))
+                    .with_input_delay(delay)
+                    .with_sparse_saving_mode(True)
+                    .add_player(Local(), me)
+                    .add_player(Remote("RL"[me]), 1 - me)
+                )
+            pool.add_session(made[0], net.socket("L"))
+            peer = made[1].start_p2p_session(net.socket("R"))
+            assert pool.native_active
+
+            def tick(i):
+                clock[0] += 16
+                peer.add_local_input(1, (i // 9) % 16)
+                fulfill(peer.advance_frame())
+                pool.add_local_input(0, 0, (i // 7) % 16)
+                for reqs in pool.advance_all():
+                    fulfill(reqs)
+                net.tick()
+
+            for i in range(at):
+                tick(i)
+            pool.inject_slot_error(0)
+            for i in range(at, 200):
+                tick(i)
+            assert pool.slot_state(0) == SLOT_EVICTED
+            sess = pool.session(0)
+            assert sess._sparse_saving
+            horizon = peer._sync_layer.last_confirmed_frame
+            assert horizon > 150 and pool.current_frame(0) > 190
+            for f in range(horizon - 60, horizon):
+                assert (peer._sync_layer.confirmed_input(0, f).input
+                        == sess._sync_layer.confirmed_input(0, f).input)
+
+
 @needs_native
 class TestOneCrossingPerTick:
     def test_crossing_count_is_exactly_ticks(self):
@@ -369,7 +514,7 @@ class TestFallback:
     def test_ineligible_shapes_fall_back(self):
         """Session shapes outside the bank's mechanism must use the Python
         sessions even when the native library is present; desync detection
-        is inside it."""
+        and sparse saving are inside it."""
         from ggrs_tpu.core.types import DesyncDetection
 
         def make(builder_tweak):
@@ -389,7 +534,8 @@ class TestFallback:
                 pool.add_session(b, net.socket(names[me]))
             return pool
 
-        assert not make(lambda b: b.with_sparse_saving_mode(True)).native_active
+        # sparse saving runs inside the bank (DESIGN.md §4); lockstep not
+        assert make(lambda b: b.with_sparse_saving_mode(True)).native_active
         assert not make(lambda b: b.with_max_prediction_window(0)).native_active
         # desync detection runs inside the bank since PR 35 (DESIGN.md §4):
         # a pool whose builders have it on is served by the native tier
